@@ -90,8 +90,13 @@ def run_elastic(*, q=512, capacity0=1024, phase_steps=10, quiet=False,
         np.setdiff1d(rng.integers(1, UNIVERSE, 40_000).astype(np.int32),
                      base),
         size=phase_steps * q, replace=False).astype(np.int32)
+    # never inserted: the population-neutral phases delete these (refused)
+    absent = np.setdiff1d(rng.integers(1, UNIVERSE, 4 * q).astype(np.int32),
+                          np.concatenate([base, burst]))[:q]
+    assert absent.size == q
     none_k = np.zeros(q, np.int32)
     none_m = np.zeros(q, bool)
+    all_m = np.ones(q, bool)
 
     # resize event log: "G"/"S" in firing order (poll_every=1 -> exact).
     # A FLAP is a direction reversal beyond the one expected grow->shrink
@@ -133,14 +138,27 @@ def run_elastic(*, q=512, capacity0=1024, phase_steps=10, quiet=False,
         lk = rng.choice(base, q).astype(np.int32)
         return (lk, none_k, none_k, none_k, none_m, none_m)
 
+    # steady, burst and recovered each carry q lookups, q inserts and q
+    # deletes, all unmasked (a masked-off batch costs nothing, and the rate
+    # counts 3q operations), so they differ only in the table's state; where
+    # the population must hold, the inserts are of present keys and the
+    # deletes of absent ones, and both are refused.  No rehash runs then:
+    # mid-epoch an insert only refuses keys of its target table.
+    def neutral(present):
+        def batch(s):
+            ik = rng.choice(present, q).astype(np.int32)
+            return (rng.choice(base, q).astype(np.int32), ik, ik, absent,
+                    all_m, all_m)
+        return batch
+
     phases = {}
-    mops, dt = phase(phase_steps, lookups_only)          # steady state
+    mops, dt = phase(phase_steps, neutral(base))         # steady state
     phases["steady"] = {"mops": mops}
 
     def burst_batch(s):
         ik = burst[s * q:(s + 1) * q]
-        return (rng.choice(base, q).astype(np.int32), ik, ik, none_k,
-                np.ones(q, bool), none_m)
+        return (rng.choice(base, q).astype(np.int32), ik, ik, absent,
+                all_m, all_m)
 
     mops, dt = phase(phase_steps, burst_batch)           # insert burst
     phases["burst"] = {"mops": mops}
@@ -150,7 +168,7 @@ def run_elastic(*, q=512, capacity0=1024, phase_steps=10, quiet=False,
     def drain_batch(s):
         dk = burst[s * q:(s + 1) * q]
         return (rng.choice(base, q).astype(np.int32), none_k, none_k, dk,
-                none_m, np.ones(q, bool))
+                none_m, all_m)
 
     mops, dt = phase(phase_steps, drain_batch)           # delete the burst
     # drain base too, down to a population far below the low watermark
@@ -170,7 +188,7 @@ def run_elastic(*, q=512, capacity0=1024, phase_steps=10, quiet=False,
                 jax.device_get(eng.state.rebuilding)):
             break
     shrinks = eng.stats.shrinks
-    mops, dt = phase(phase_steps, lookups_only)          # recovered steady
+    mops, dt = phase(phase_steps, neutral(base[512:]))   # recovered steady
     phases["recovered"] = {"mops": mops}
 
     turns = sum(1 for a, b in zip(events, events[1:]) if a != b)

@@ -51,8 +51,9 @@ def run_fused_probe(batch=4096, n_items=3_000, *, iters=5, quiet=False,
     """fused=on|off rebuild-epoch lookup comparison for the linear backend.
 
     The hot-path claim under test: with a rebuild in flight, the FUSED path
-    executes ONE argsort + ONE pallas_call per batch where the unfused path
-    pays one sort + one pallas_call per table plus a separate hazard pass.
+    executes ONE pallas_call per batch (the windowed linear kernels need no
+    sort) where the unfused path pays one pallas_call per table plus a
+    separate hazard pass.
     In interpret mode (no real TPU) the pass-count reduction is the
     acceptance metric (wall clock of interpreted Pallas is not meaningful);
     both are recorded in BENCH_fused_probe.json for the perf trajectory.
@@ -106,95 +107,13 @@ def run_fused_probe(batch=4096, n_items=3_000, *, iters=5, quiet=False,
     result = {"batch": batch, "n_items": n_items, "interpret": True,
               "fused": passes["fused"], "unfused": passes["unfused"],
               "pass_ratio": ratio}
-    assert passes["fused"]["sort"] == 1 and passes["fused"]["pallas_call"] == 1
+    assert passes["fused"]["sort"] == 0 and passes["fused"]["pallas_call"] == 1
     assert ratio >= 1.5, f"pass-count reduction regressed: {ratio:.2f}x"
     out = pathlib.Path(out_path) if out_path else _REPO_ROOT / "BENCH_fused_probe.json"
     out.write_text(json.dumps(result, indent=2) + "\n")
     if not quiet:
         print(f"[summary] fused pass-count reduction {ratio:.2f}x "
               f"(>=1.5x required) -> {out}")
-    return result
-
-
-def run_growth_escape(batch=4096, n_items=3_000, growths=(1, 4, 16), *,
-                      iters=5, quiet=False, out_path=None):
-    """Fallback-escape rate of the fused rebuild-epoch probe vs new-table
-    GROWTH factor — the two-level tile-map acceptance.
-
-    The fused probe's one sort is keyed on the old table's start slots, so a
-    grown new table scatters each query tile's new-table windows across many
-    slabs.  Before the tile map the per-tile slab was anchored at the tile's
-    min ``h0_new`` and growth-heavy rebuilds sent a MAJORITY of rebuild-epoch
-    queries to the gated jnp fallback; with the map (per-tile resident
-    blocks, ``ops.NRES_CAP`` of them) the acceptance bar is <5% escapes at
-    16x growth.  The structural 1-sort/1-pallas_call budget is asserted at
-    every growth factor; escape rates and wall clock land in
-    BENCH_growth_escape.json and the CI perf gate fails if a rate creeps
-    back up (``check_regression`` treats ``escape_rate`` as
-    lower-is-better with an absolute band).
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core import buckets, dhash, hashing
-    from repro.kernels import ops
-
-    rng = np.random.default_rng(0)
-    d = dhash.make("linear", capacity=n_items, chunk=256, seed=1)
-    c_old = d.old.capacity
-    present = rng.choice(UNIVERSE, size=n_items, replace=False).astype(np.int32)
-    keys = jnp.asarray(present)
-    ins = jax.jit(dhash.insert)
-    for i in range(0, n_items, 4096):
-        d, _ = ins(d, keys[i:i + 4096], keys[i:i + 4096])
-    # a populated hazard window, shared across growth factors
-    d = dhash.rebuild_start(d, seed=9)
-    d = jax.jit(dhash.rebuild_extract)(d)
-
-    qs = jnp.asarray(np.concatenate([
-        rng.choice(present, batch // 2),
-        rng.integers(1, UNIVERSE, batch - batch // 2)]).astype(np.int32))
-    h0o = hashing.bucket_of(d.old.hfn, qs, c_old)
-    mp = d.old.max_probes
-    old_t = (d.old.key, d.old.val, d.old.state)
-
-    result = {"batch": batch, "n_items": n_items, "c_old": c_old,
-              "interpret": True}
-    for g in growths:
-        c_new = c_old * g
-        tnew = buckets.linear_make(c_new, hashing.fresh("mix32", 100 + g),
-                                   max_probes=mp)
-        landed = jnp.asarray(rng.choice(
-            np.arange(UNIVERSE, UNIVERSE + 10 * n_items), n_items // 4,
-            replace=False).astype(np.int32))
-        tnew, _ = jax.jit(buckets.linear_insert)(
-            tnew, landed, landed * 3, jnp.ones(landed.shape, bool))
-        h0n = hashing.bucket_of(tnew.hfn, qs, c_new)
-        args = (old_t, (tnew.key, tnew.val, tnew.state), d.hazard_key,
-                d.hazard_val, d.hazard_live, h0o, h0n, qs)
-        rate = float(ops.rebuild_escape_rate(*args, max_probes=mp))
-        fn = lambda *a: ops.ordered_lookup_fused(*a, max_probes=mp)  # noqa: E731
-        counts = count_primitives(jax.make_jaxpr(fn)(*args),
-                                  ("sort", "pallas_call"))
-        assert counts == {"sort": 1, "pallas_call": 1}, counts
-        dt = timeit(fn, *args, warmup=1, iters=iters)
-        result[f"growth_{g}x"] = dict(escape_rate=rate, **counts,
-                                      wall_us=dt * 1e6)
-        if not quiet:
-            print(f"growth_escape/{g:2d}x Q={batch} C_new={c_new:<8d} "
-                  f"escape={rate:7.4f} {dt*1e6:9.0f} us")
-
-    top = max(growths)
-    assert result[f"growth_{top}x"]["escape_rate"] < 0.05, \
-        f"escape rate at {top}x growth regressed: " \
-        f"{result[f'growth_{top}x']['escape_rate']:.3f}"
-    out = (pathlib.Path(out_path) if out_path
-           else _REPO_ROOT / "BENCH_growth_escape.json")
-    out.write_text(json.dumps(result, indent=2) + "\n")
-    if not quiet:
-        print(f"[summary] escape at {top}x growth "
-              f"{result[f'growth_{top}x']['escape_rate']:.4f} "
-              f"(<0.05 required) -> {out}")
     return result
 
 
@@ -268,13 +187,6 @@ def run_fused_writes(batch=4096, n_items=3_000, *, iters=5, quiet=False,
     trajectory but not asserted (interpret mode is not representative).
     Results land in BENCH_fused_writes.json; exactness of the fused arm is
     cross-checked against the jnp arm in-run.
-
-    Baseline note: the two-level tile map costs the fused arm 2 extra
-    proxy passes (43 -> 45): the rebuild-epoch lookup AND delete each
-    gained the level-1 histogram scatter of ``ops._resident_blockmap``.
-    That is the deliberate price of keeping grown new tables fused (see
-    BENCH_growth_escape.json) — the committed baseline was refreshed with
-    the same change, and the gate pins the new count exactly.
     """
     import jax
     import jax.numpy as jnp
@@ -620,7 +532,7 @@ def run_table_stack(n_tables=8, capacity=2048, batch=512, *, iters=5,
     under this artifact's own wall band (``"band"`` key — the per-artifact
     calibration hook of check_regression) but the gate is structural.
     The fused per-table-step budget is asserted exactly: the vmapped
-    rebuild-epoch ordered lookup stays ONE sort + ONE pallas_call for the
+    rebuild-epoch ordered lookup stays ONE pallas_call (no sort) for the
     whole stack.
     """
     import jax
@@ -671,13 +583,13 @@ def run_table_stack(n_tables=8, capacity=2048, batch=512, *, iters=5,
     ratio = launches_looped / launches_stacked
 
     # fused per-table-step budget, unchanged under vmap: the whole stack's
-    # rebuild-epoch ordered lookup is ONE sort + ONE pallas_call
+    # rebuild-epoch ordered lookup is ONE pallas_call and no sort
     be = backend.get("linear")
     ordered = jax.vmap(lambda d, k: be.ordered_lookup_fused(
         d.old, d.new, d.hazard_key, d.hazard_val, d.hazard_live, k,
         nres_cap=d.nres_cap))
     c_ordered = count_primitives(jax.make_jaxpr(ordered)(st, lk), names)
-    assert c_ordered == {"sort": 1, "pallas_call": 1}, c_ordered
+    assert c_ordered == {"sort": 0, "pallas_call": 1}, c_ordered
 
     def run_stacked():
         _d, out = jstack(st, lk, ik, ik * 3, dk)
@@ -797,12 +709,12 @@ def run_routed_stack(batch=1024, capacity=1024, cap_factor=2.0, *, iters=5,
                     rt.dropped)
 
         # the acceptance budget: slab router + fused stack lookup = ONE
-        # sort + ONE pallas_call total (the kernel's own bucket sort is
-        # the only sort in the whole routed op; no cond retry exists)
+        # pallas_call and no sort total (the router and the windowed kernel
+        # sort nothing; no cond retry exists)
         budget = count_primitives(
             jax.make_jaxpr(lambda s, k, tn: routed(s, k, tn, spill_cap))(
                 st, keys, tenant), names)
-        assert budget == {"sort": 1, "pallas_call": 1}, (t, budget)
+        assert budget == {"sort": 0, "pallas_call": 1}, (t, budget)
 
         jrouted = jax.jit(routed, static_argnums=3)
         wall = timeit(lambda: jrouted(st, keys, tenant, spill_cap),
@@ -827,12 +739,12 @@ def run_routed_stack(batch=1024, capacity=1024, cap_factor=2.0, *, iters=5,
             f"zipf arm must not drop: {dropped_rate:.4f}"
 
         # adversarial arm: 100% one-tenant skew through the overflow-proof
-        # slab — same 1 sort + 1 pallas_call, bit-identical to full width
+        # slab — same single pallas_call, bit-identical to full width
         atn = jnp.zeros((batch,), jnp.int32)
         adv_budget = count_primitives(
             jax.make_jaxpr(lambda s, k, tn: routed(s, k, tn, batch - cap))(
                 st, keys, atn), names)
-        assert adv_budget == {"sort": 1, "pallas_call": 1}, (t, adv_budget)
+        assert adv_budget == {"sort": 0, "pallas_call": 1}, (t, adv_budget)
         fa, va, sa, _, da = (np.asarray(x) for x in jax.device_get(
             jrouted(st, keys, atn, batch - cap)))
         assert sa.all() and int(da.sum()) == 0
@@ -867,7 +779,7 @@ def run_routed_stack(batch=1024, capacity=1024, cap_factor=2.0, *, iters=5,
         print(f"[summary] spill-slab routing: "
               f"{result['t8']['send_bytes_ratio']:.2f}x fewer wire bytes "
               f"at T=8, {result['t64']['send_bytes_ratio']:.2f}x at T=64, "
-              f"0 drops, 1 sort + 1 pallas_call per routed op (adversarial "
+              f"0 drops, 1 pallas_call per routed op (adversarial "
               f"skew included, no retry) -> {out}")
     return result
 
@@ -878,11 +790,10 @@ def main(argv=None):
     ap.add_argument("--alpha", type=int, default=20)
     ap.add_argument("--fused", action="store_true",
                     help="also run the fused=on|off rebuild-epoch probe, "
-                         "write-path, chain-backend, growth-escape, "
-                         "table-stack, and routed-stack comparisons (writes "
+                         "write-path, chain-backend, table-stack, and "
+                         "routed-stack comparisons (writes "
                          "BENCH_fused_probe.json + BENCH_fused_writes.json "
                          "+ BENCH_chain_fused.json + "
-                         "BENCH_growth_escape.json + "
                          "BENCH_table_stack.json + "
                          "BENCH_routed_stack.json)")
     args = ap.parse_args(argv)
@@ -891,7 +802,6 @@ def main(argv=None):
         run_fused_probe()
         run_fused_writes()
         run_chain_fused()
-        run_growth_escape()
         run_table_stack()
         run_routed_stack()
     return rows

@@ -1,7 +1,7 @@
 """CI perf-regression gate: fresh BENCH_*.json vs the committed baselines.
 
 Every benchmark that writes a ``BENCH_*.json`` artifact (bench_rebuild's
-fused-probe, fused-writes, chain-fused, and growth-escape comparisons
+fused-probe, fused-writes, and chain-fused comparisons
 today) commits its result at the repo root; CI snapshots those committed
 files, re-runs ``benchmarks.run --quick``, and calls this script to diff
 the fresh artifacts against the snapshot.
@@ -23,7 +23,7 @@ Gate semantics, per leaf key:
   is a layout regression, exact by construction.  ``adversarial_sorts`` /
   ``adversarial_pallas_calls`` (routed-stack bench) pin the single-pass
   spill-slab guarantee where it matters most: a 100%-one-tenant batch
-  must still lower to 1 sort + 1 pallas_call — an increase means the
+  must still lower to 1 pallas_call and no sort — an increase means the
   full-width retry (or any second pass) crept back in.
   A gated key that is MISSING from the fresh artifact, or present with a
   non-numeric type, is itself a failure: a gate that silently skips what
@@ -49,8 +49,7 @@ Gate semantics, per leaf key:
   shared runners, which no fixed tolerance separates from regression.
 * **escape rates** (``escape_rate``, ``overflow_rate``, ``miss_rate``,
   ``alloc_fail_rate``, ``dropped_rate``) are lower-is-better fractions —
-  rebuild-epoch queries overflowing to the jnp fallback (growth-escape
-  bench), zipf-batch keys past their tenant's primary cap (routed-stack
+  zipf-batch keys past their tenant's primary cap (routed-stack
   bench; slab pressure, deterministic for the pinned seed), keys past
   primary AND spill slab (``dropped_rate``, baseline 0.0: the slab is
   sized to serve the whole zipf spill — nonzero means the slab shrank or

@@ -120,15 +120,6 @@ def fused_writes():
          f"{r['pass_ratio']:.2f}x_fewer_passes")
 
 
-def growth_escape():
-    from benchmarks.bench_rebuild import run_growth_escape
-    r = run_growth_escape(batch=4096, n_items=3_000, quiet=True)
-    for g in (1, 4, 16):
-        row = r[f"growth_{g}x"]
-        _row(f"growth_escape/{g}x/q{r['batch']}", row["wall_us"],
-             f"{row['escape_rate']:.4f}_escape_rate")
-
-
 def chain_fused():
     from benchmarks.bench_rebuild import run_chain_fused
     r = run_chain_fused(batch=4096, n_items=3_000, quiet=True)
@@ -167,13 +158,12 @@ def routed_stack():
 
 TABLES = [fig2_throughput, fig3_rebuild, fig4_portability, s62_oversubscribe,
           elastic, s1_attack, moe_router, kvcache_rehash, serve_macro,
-          fused_probe, fused_writes, chain_fused, growth_escape, table_stack,
-          routed_stack]
+          fused_probe, fused_writes, chain_fused, table_stack, routed_stack]
 
 
 def quick() -> None:
     """CI smoke mode: exercises the perf harness end-to-end in minutes —
-    the fused-probe, fused-writes, chain-fused, growth-escape, table-stack,
+    the fused-probe, fused-writes, chain-fused, table-stack,
     routed-stack (zipf + adversarial 100%-skew slab arms), elastic-burst,
     collision-attack, and serving-macro
     acceptance checks (pass counts + escape rates + resize/flap counts +
@@ -185,7 +175,6 @@ def quick() -> None:
     fused_probe()
     fused_writes()
     chain_fused()
-    growth_escape()
     table_stack()
     routed_stack()
     elastic()

@@ -50,11 +50,8 @@ from repro.core.buckets import (ChainTable, CuckooTable, LinearTable,
                                 TwoChoiceTable, _chain_parts, _ck_rows,
                                 _tc_rows, batch_winners, chain_dirty)
 from repro.core.struct_utils import replace
-# Eager (not in-function like the adapters' ops imports): the registry
-# entries below need the cap values at registration time.  Cost is ~0.2s of
-# pallas machinery on top of jax's own import — paid once by anything that
-# touches repro.core.
-from repro.kernels.ops import DIRTY_CAP, NRES_CAP
+# The registry entries below need the cap values at registration time.
+from repro.kernels.probe import DIRTY_CAP, NRES_CAP
 
 
 # ---------------------------------------------------------------------------
@@ -151,6 +148,11 @@ class BucketBackend:
     # optional hooks
     freeze_old: Callable[..., Any] | None = None
     lookup_fwd: Callable[..., Any] | None = None
+    # the TPU compiler's refusal of this backend's fused kernels (kernel and
+    # error, as tests/test_tpu_compile.py records it), None when they
+    # compile; dhash.make raises it instead of building a fused table on a
+    # TPU
+    tpu_refusal: str | None = None
 
     @property
     def fused(self) -> bool:
@@ -202,94 +204,87 @@ def of_table(t) -> BucketBackend:
 # linear: fused adapters (kernels/ops.py probe/claim/extract kernels)
 # ---------------------------------------------------------------------------
 
-def linear_lookup_fused(t: LinearTable, keys: jax.Array, *,
-                        interpret: bool = True):
+def linear_lookup_fused(t: LinearTable, keys: jax.Array):
     """Kernel-backed lookup.  Returns (found, vals)."""
     from repro.kernels import ops
     h0 = hashing.bucket_of(t.hfn, keys, t.capacity)
     return ops.probe_lookup(t.key, t.val, t.state, h0, keys,
-                            max_probes=t.max_probes, interpret=interpret)
+                            max_probes=t.max_probes)
 
 
-def linear_lookup_fused_loc(t: LinearTable, keys: jax.Array, *,
-                            interpret: bool = True):
+def linear_lookup_fused_loc(t: LinearTable, keys: jax.Array):
     """Kernel-backed lookup keeping the kernel's loc output: the SAME single
-    sort + pallas_call as ``linear_lookup_fused``, returning
-    (found, vals, loc) for probe telemetry (core/policy.py)."""
+    pallas_call as ``linear_lookup_fused``, returning (found, vals, loc) for
+    probe telemetry (core/policy.py)."""
     from repro.kernels import ops
     h0 = hashing.bucket_of(t.hfn, keys, t.capacity)
     return ops.probe_lookup(t.key, t.val, t.state, h0, keys,
-                            max_probes=t.max_probes, with_loc=True,
-                            interpret=interpret)
+                            max_probes=t.max_probes, with_loc=True)
 
 
 def linear_insert_fused(t: LinearTable, keys: jax.Array, vals: jax.Array,
-                        mask: jax.Array, *, interpret: bool = True):
+                        mask: jax.Array):
     """Kernel-backed insert: batch_winners dedup (the kernel's caller
-    contract), then one claim pass + one scatter."""
+    contract), then one claim pass + one claim-settling sort + one
+    scatter."""
     from repro.kernels import ops
     winner = batch_winners(keys, mask)
     h0 = hashing.bucket_of(t.hfn, keys, t.capacity)
     tk, tv, ts, ok = ops.probe_insert(t.key, t.val, t.state, h0, keys, vals,
-                                      winner, max_probes=t.max_probes,
-                                      interpret=interpret)
+                                      winner, max_probes=t.max_probes)
     return replace(t, key=tk, val=tv, state=ts), ok
 
 
-def linear_delete_fused(t: LinearTable, keys: jax.Array, mask: jax.Array, *,
-                        interpret: bool = True):
-    """Kernel-backed delete: the location-emitting probe kernel tombstones
-    in ONE pass (one sort + one pallas_call + one scatter)."""
+def linear_delete_fused(t: LinearTable, keys: jax.Array, mask: jax.Array):
+    """Kernel-backed delete: the lookup kernel's hit slot is tombstoned in
+    ONE pass (one pallas_call + one scatter)."""
     from repro.kernels import ops
     winner = batch_winners(keys, mask)
     h0 = hashing.bucket_of(t.hfn, keys, t.capacity)
     state, ok = ops.probe_delete(t.key, t.val, t.state, h0, keys, winner,
-                                 max_probes=t.max_probes, interpret=interpret)
+                                 max_probes=t.max_probes)
     return replace(t, state=state), ok
 
 
-def linear_extract_chunk_fused(t: LinearTable, cursor: jax.Array, n: int, *,
-                               interpret: bool = True):
-    """Kernel-backed rebuild chunk scan: one pallas_call over the resident
-    slab window + one MIGRATED scatter; hazard entries come back COMPACTED
-    (live entries first) — identical as a set, which is all the hazard
-    protocol observes."""
+def linear_extract_chunk_fused(t: LinearTable, cursor: jax.Array, n: int):
+    """Kernel-backed rebuild chunk scan: one pallas_call over the chunk +
+    one MIGRATED scatter; hazard entries come back COMPACTED (live entries
+    first) — identical as a set, which is all the hazard protocol
+    observes."""
     from repro.kernels import ops
-    if n > ops.SLAB:   # window contract; fall back to the jnp scan
-        return buckets.linear_extract_chunk(t, cursor, n)
     state, hk, hv, hl, cur = ops.extract_chunk_fused(
-        t.key, t.val, t.state, cursor, chunk=n, interpret=interpret)
+        t.key, t.val, t.state, cursor, chunk=n)
     return replace(t, state=state), hk, hv, hl, cur
 
 
 def linear_ordered_lookup_fused(t_old: LinearTable, t_new: LinearTable,
                                 hazard_key: jax.Array, hazard_val: jax.Array,
                                 hazard_live: jax.Array, keys: jax.Array, *,
-                                nres_cap: int = NRES_CAP,
-                                interpret: bool = True):
+                                nres_cap: int = NRES_CAP):
     """Kernel-backed rebuild-epoch lookup: the whole ordered check
-    (old -> hazard -> new, Lemma 4.1) in ONE argsort + ONE probe2
-    pallas_call, the two-level tile map (up to ``nres_cap`` resident blocks
-    per tile) covering grown new tables.  Returns (found, vals)."""
+    (old -> hazard -> new, Lemma 4.1) in ONE probe2 pallas_call, for any
+    old/new table sizes (``nres_cap`` sizes the sorted-slab backends' tile
+    map; the windowed linear kernels have none).  Returns (found, vals)."""
     from repro.kernels import ops
+    del nres_cap
     h0_old = hashing.bucket_of(t_old.hfn, keys, t_old.capacity)
     h0_new = hashing.bucket_of(t_new.hfn, keys, t_new.capacity)
     return ops.ordered_lookup_fused(
         (t_old.key, t_old.val, t_old.state),
         (t_new.key, t_new.val, t_new.state),
         hazard_key, hazard_val, hazard_live, h0_old, h0_new, keys,
-        max_probes=t_old.max_probes, nres_cap=nres_cap, interpret=interpret)
+        max_probes=t_old.max_probes)
 
 
 def linear_ordered_delete_fused(t_old: LinearTable, t_new: LinearTable,
                                 hazard_key: jax.Array, hazard_val: jax.Array,
                                 hazard_live: jax.Array, keys: jax.Array,
-                                mask: jax.Array, *, nres_cap: int = NRES_CAP,
-                                interpret: bool = True):
+                                mask: jax.Array, *, nres_cap: int = NRES_CAP):
     """Kernel-backed rebuild-epoch delete (paper Alg. 5): the SAME single
     probe2 pass resolves old-slot / hazard-index / new-slot; three scatters
     land the result.  Returns (old_state', new_state', hazard_live', ok)."""
     from repro.kernels import ops
+    del nres_cap
     winner = batch_winners(keys, mask)
     h0_old = hashing.bucket_of(t_old.hfn, keys, t_old.capacity)
     h0_new = hashing.bucket_of(t_new.hfn, keys, t_new.capacity)
@@ -297,27 +292,24 @@ def linear_ordered_delete_fused(t_old: LinearTable, t_new: LinearTable,
         (t_old.key, t_old.val, t_old.state),
         (t_new.key, t_new.val, t_new.state),
         hazard_key, hazard_val, hazard_live, h0_old, h0_new, keys, winner,
-        max_probes=t_old.max_probes, nres_cap=nres_cap, interpret=interpret)
+        max_probes=t_old.max_probes)
 
 
 # ---------------------------------------------------------------------------
 # twochoice: fused adapters (2Q-entry one-sort row-gather kernels)
 # ---------------------------------------------------------------------------
 
-def twochoice_lookup_fused(t: TwoChoiceTable, keys: jax.Array, *,
-                           interpret: bool = True):
+def twochoice_lookup_fused(t: TwoChoiceTable, keys: jax.Array):
     """Kernel-backed 2-choice lookup.  Returns (found, vals, loc) — the same
     triple as ``buckets.twochoice_lookup`` so the delete path can reuse
     ``loc``."""
     from repro.kernels import ops
     ba, bb = _tc_rows(t, keys)
-    return ops.twochoice_lookup(t.key, t.val, t.state, ba, bb, keys,
-                                interpret=interpret)
+    return ops.twochoice_lookup(t.key, t.val, t.state, ba, bb, keys)
 
 
 def twochoice_insert_fused(t: TwoChoiceTable, keys: jax.Array,
-                           vals: jax.Array, mask: jax.Array, *,
-                           interpret: bool = True):
+                           vals: jax.Array, mask: jax.Array):
     """Kernel-backed 2-choice insert: batch_winners dedup, then one claim
     pass + one scatter (a-row claims shadow b-row claims of the same
     query)."""
@@ -326,20 +318,19 @@ def twochoice_insert_fused(t: TwoChoiceTable, keys: jax.Array,
     ba, bb = _tc_rows(t, keys)
     tk, tv, ts, ok = ops.twochoice_insert(t.key, t.val, t.state, ba, bb,
                                           keys, vals, winner,
-                                          max_rounds=t.max_rounds,
-                                          interpret=interpret)
+                                          max_rounds=t.max_rounds)
     return replace(t, key=tk, val=tv, state=ts), ok
 
 
 def twochoice_delete_fused(t: TwoChoiceTable, keys: jax.Array,
-                           mask: jax.Array, *, interpret: bool = True):
+                           mask: jax.Array):
     """Kernel-backed 2-choice delete: reuses the fused lookup's location
     output — one kernel pass + one tombstone scatter."""
     from repro.kernels import ops
     winner = batch_winners(keys, mask)
     ba, bb = _tc_rows(t, keys)
     state, ok = ops.twochoice_delete(t.key, t.val, t.state, ba, bb, keys,
-                                     winner, interpret=interpret)
+                                     winner)
     return replace(t, state=state), ok
 
 
@@ -349,8 +340,7 @@ def twochoice_ordered_lookup_fused(t_old: TwoChoiceTable,
                                    hazard_val: jax.Array,
                                    hazard_live: jax.Array,
                                    keys: jax.Array, *,
-                                   nres_cap: int = NRES_CAP,
-                                   interpret: bool = True):
+                                   nres_cap: int = NRES_CAP):
     """Kernel-backed twochoice rebuild-epoch lookup: the whole ordered check
     in ONE argsort + ONE tc_probe2 pallas_call.  Returns (found, vals)."""
     from repro.kernels import ops
@@ -360,7 +350,7 @@ def twochoice_ordered_lookup_fused(t_old: TwoChoiceTable,
         (t_old.key, t_old.val, t_old.state),
         (t_new.key, t_new.val, t_new.state),
         hazard_key, hazard_val, hazard_live,
-        ba_o, bb_o, ba_n, bb_n, keys, nres_cap=nres_cap, interpret=interpret)
+        ba_o, bb_o, ba_n, bb_n, keys, nres_cap=nres_cap)
 
 
 def twochoice_ordered_delete_fused(t_old: TwoChoiceTable,
@@ -369,8 +359,7 @@ def twochoice_ordered_delete_fused(t_old: TwoChoiceTable,
                                    hazard_val: jax.Array,
                                    hazard_live: jax.Array,
                                    keys: jax.Array, mask: jax.Array, *,
-                                   nres_cap: int = NRES_CAP,
-                                   interpret: bool = True):
+                                   nres_cap: int = NRES_CAP):
     """Kernel-backed twochoice rebuild-epoch delete (paper Alg. 5): the SAME
     single tc_probe2 pass resolves old-slot / hazard-index / new-slot.
     Returns the raw (old_state', new_state', hazard_live', ok[Q])."""
@@ -382,20 +371,17 @@ def twochoice_ordered_delete_fused(t_old: TwoChoiceTable,
         (t_old.key, t_old.val, t_old.state),
         (t_new.key, t_new.val, t_new.state),
         hazard_key, hazard_val, hazard_live,
-        ba_o, bb_o, ba_n, bb_n, keys, winner, nres_cap=nres_cap,
-        interpret=interpret)
+        ba_o, bb_o, ba_n, bb_n, keys, winner, nres_cap=nres_cap)
 
 
 def twochoice_extract_chunk_fused(t: TwoChoiceTable, cursor: jax.Array,
-                                  n: int, *, interpret: bool = True):
+                                  n: int):
     """Kernel-backed 2-choice rebuild chunk scan: the extract kernel runs on
     the row-major flattened arrays (the scan order is identical)."""
     from repro.kernels import ops
-    if n > ops.SLAB:
-        return buckets.twochoice_extract_chunk(t, cursor, n)
     state, hk, hv, hl, cur = ops.extract_chunk_fused(
         t.key.reshape(-1), t.val.reshape(-1), t.state.reshape(-1), cursor,
-        chunk=n, interpret=interpret)
+        chunk=n)
     return replace(t, state=state.reshape(t.nbuckets, t.width)), \
         hk, hv, hl, cur
 
@@ -407,18 +393,16 @@ def twochoice_extract_chunk_fused(t: TwoChoiceTable, cursor: jax.Array,
 # cond-gated bounded kick-out (pure jnp — zero extra kernel launches)
 # ---------------------------------------------------------------------------
 
-def cuckoo_lookup_fused(t: CuckooTable, keys: jax.Array, *,
-                        interpret: bool = True):
+def cuckoo_lookup_fused(t: CuckooTable, keys: jax.Array):
     """Kernel-backed cuckoo lookup via the twochoice row-gather kernel over
     side-offset rows.  Returns (found, vals, loc)."""
     from repro.kernels import ops
     ra, rb = _ck_rows(t, keys)
-    return ops.twochoice_lookup(t.key, t.val, t.state, ra, rb, keys,
-                                interpret=interpret)
+    return ops.twochoice_lookup(t.key, t.val, t.state, ra, rb, keys)
 
 
 def cuckoo_insert_fused(t: CuckooTable, keys: jax.Array, vals: jax.Array,
-                        mask: jax.Array, *, interpret: bool = True):
+                        mask: jax.Array):
     """Kernel-backed cuckoo insert: the twochoice claim kernel places every
     key whose candidate rows have room (max_rounds=2 — one try per side);
     anything still unplaced escapes to the cond-gated bounded kick-out
@@ -428,7 +412,7 @@ def cuckoo_insert_fused(t: CuckooTable, keys: jax.Array, vals: jax.Array,
     ra, rb = _ck_rows(t, keys)
     tk, tv, ts, ok = ops.twochoice_insert(t.key, t.val, t.state, ra, rb,
                                           keys, vals, winner,
-                                          max_rounds=2, interpret=interpret)
+                                          max_rounds=2)
     maybe = winner & ~ok
 
     def kick(op):
@@ -448,23 +432,21 @@ def cuckoo_insert_fused(t: CuckooTable, keys: jax.Array, vals: jax.Array,
     return replace(t, key=tk, val=tv, state=ts), ok
 
 
-def cuckoo_delete_fused(t: CuckooTable, keys: jax.Array, mask: jax.Array, *,
-                        interpret: bool = True):
+def cuckoo_delete_fused(t: CuckooTable, keys: jax.Array, mask: jax.Array):
     """Kernel-backed cuckoo delete: the twochoice location-emitting pass +
     one tombstone scatter."""
     from repro.kernels import ops
     winner = batch_winners(keys, mask)
     ra, rb = _ck_rows(t, keys)
     state, ok = ops.twochoice_delete(t.key, t.val, t.state, ra, rb, keys,
-                                     winner, interpret=interpret)
+                                     winner)
     return replace(t, state=state), ok
 
 
 def cuckoo_ordered_lookup_fused(t_old: CuckooTable, t_new: CuckooTable,
                                 hazard_key: jax.Array, hazard_val: jax.Array,
                                 hazard_live: jax.Array, keys: jax.Array, *,
-                                nres_cap: int = NRES_CAP,
-                                interpret: bool = True):
+                                nres_cap: int = NRES_CAP):
     """Kernel-backed cuckoo rebuild-epoch lookup: the twochoice tc_probe2
     pass (ONE argsort + ONE pallas_call) over side-offset rows."""
     from repro.kernels import ops
@@ -474,14 +456,13 @@ def cuckoo_ordered_lookup_fused(t_old: CuckooTable, t_new: CuckooTable,
         (t_old.key, t_old.val, t_old.state),
         (t_new.key, t_new.val, t_new.state),
         hazard_key, hazard_val, hazard_live,
-        ra_o, rb_o, ra_n, rb_n, keys, nres_cap=nres_cap, interpret=interpret)
+        ra_o, rb_o, ra_n, rb_n, keys, nres_cap=nres_cap)
 
 
 def cuckoo_ordered_delete_fused(t_old: CuckooTable, t_new: CuckooTable,
                                 hazard_key: jax.Array, hazard_val: jax.Array,
                                 hazard_live: jax.Array, keys: jax.Array,
-                                mask: jax.Array, *, nres_cap: int = NRES_CAP,
-                                interpret: bool = True):
+                                mask: jax.Array, *, nres_cap: int = NRES_CAP):
     """Kernel-backed cuckoo rebuild-epoch delete (paper Alg. 5) via the
     twochoice probe2 pass over side-offset rows.  Returns the raw
     (old_state', new_state', hazard_live', ok[Q])."""
@@ -493,20 +474,16 @@ def cuckoo_ordered_delete_fused(t_old: CuckooTable, t_new: CuckooTable,
         (t_old.key, t_old.val, t_old.state),
         (t_new.key, t_new.val, t_new.state),
         hazard_key, hazard_val, hazard_live,
-        ra_o, rb_o, ra_n, rb_n, keys, winner, nres_cap=nres_cap,
-        interpret=interpret)
+        ra_o, rb_o, ra_n, rb_n, keys, winner, nres_cap=nres_cap)
 
 
-def cuckoo_extract_chunk_fused(t: CuckooTable, cursor: jax.Array, n: int, *,
-                               interpret: bool = True):
+def cuckoo_extract_chunk_fused(t: CuckooTable, cursor: jax.Array, n: int):
     """Kernel-backed cuckoo rebuild chunk scan on the row-major flattened
     [2B*W] arrays (the scan order is identical)."""
     from repro.kernels import ops
-    if n > ops.SLAB:
-        return buckets.cuckoo_extract_chunk(t, cursor, n)
     state, hk, hv, hl, cur = ops.extract_chunk_fused(
         t.key.reshape(-1), t.val.reshape(-1), t.state.reshape(-1), cursor,
-        chunk=n, interpret=interpret)
+        chunk=n)
     return replace(t, state=state.reshape(2 * t.nbuckets, t.width)), \
         hk, hv, hl, cur
 
@@ -515,19 +492,18 @@ def cuckoo_extract_chunk_fused(t: CuckooTable, cursor: jax.Array, n: int, *,
 # chain: fused adapters over the arena-sorted node layout
 # ---------------------------------------------------------------------------
 
-def chain_lookup_fused(t: ChainTable, keys: jax.Array, *,
-                       interpret: bool = True):
+def chain_lookup_fused(t: ChainTable, keys: jax.Array):
     """Kernel-backed chain lookup over the arena-sorted layout.  Returns
     (found, vals, loc) — ``loc`` is the arena node index (-1 if absent)."""
     from repro.kernels import ops
     b = hashing.bucket_of(t.hfn, keys, t.nbuckets)
     return ops.chain_lookup_fused(*_chain_parts(t), b, keys,
                                   max_chain=t.max_chain,
-                                  dirty_cap=t.dirty_cap, interpret=interpret)
+                                  dirty_cap=t.dirty_cap)
 
 
 def chain_insert_fused(t: ChainTable, keys: jax.Array, vals: jax.Array,
-                       mask: jax.Array, *, interpret: bool = True):
+                       mask: jax.Array):
     """Kernel-backed chain insert: batch_winners dedup, ONE sort keyed on
     the bucket, one presence pallas_call, then vectorized tail allocation +
     segmented head relink — no pointer chasing.  New nodes extend the dirty
@@ -538,13 +514,12 @@ def chain_insert_fused(t: ChainTable, keys: jax.Array, vals: jax.Array,
     arena_t, links, seg = _chain_parts(t)
     akey, aval, astate, anext, heads, free_top, ok = ops.chain_insert_fused(
         arena_t, links, seg, t.free_stack, t.free_top, b, keys, vals, winner,
-        max_chain=t.max_chain, dirty_cap=t.dirty_cap, interpret=interpret)
+        max_chain=t.max_chain, dirty_cap=t.dirty_cap)
     return replace(t, akey=akey, aval=aval, astate=astate, anext=anext,
                    heads=heads, free_top=free_top), ok
 
 
-def chain_delete_fused(t: ChainTable, keys: jax.Array, mask: jax.Array, *,
-                       interpret: bool = True):
+def chain_delete_fused(t: ChainTable, keys: jax.Array, mask: jax.Array):
     """Kernel-backed chain delete: the location-emitting probe (sorted
     segment window + dirty-tail compare) tombstones in ONE pass."""
     from repro.kernels import ops
@@ -552,16 +527,14 @@ def chain_delete_fused(t: ChainTable, keys: jax.Array, mask: jax.Array, *,
     b = hashing.bucket_of(t.hfn, keys, t.nbuckets)
     astate, ok = ops.chain_delete_fused(*_chain_parts(t), b, keys, winner,
                                         max_chain=t.max_chain,
-                                        dirty_cap=t.dirty_cap,
-                                        interpret=interpret)
+                                        dirty_cap=t.dirty_cap)
     return replace(t, astate=astate), ok
 
 
 def chain_ordered_lookup_fused(t_old: ChainTable, t_new: ChainTable,
                                hazard_key: jax.Array, hazard_val: jax.Array,
                                hazard_live: jax.Array, keys: jax.Array, *,
-                               nres_cap: int = NRES_CAP,
-                               interpret: bool = True):
+                               nres_cap: int = NRES_CAP):
     """Kernel-backed chain rebuild-epoch lookup: the whole ordered check in
     ONE sort + ONE chain_probe2 pallas_call.  Returns (found, vals)."""
     from repro.kernels import ops
@@ -571,15 +544,13 @@ def chain_ordered_lookup_fused(t_old: ChainTable, t_new: ChainTable,
         *_chain_parts(t_old), *_chain_parts(t_new),
         hazard_key, hazard_val, hazard_live, b_old, b_new, keys,
         max_chain=max(t_old.max_chain, t_new.max_chain),
-        nres_cap=nres_cap, dirty_cap=max(t_old.dirty_cap, t_new.dirty_cap),
-        interpret=interpret)
+        nres_cap=nres_cap, dirty_cap=max(t_old.dirty_cap, t_new.dirty_cap))
 
 
 def chain_ordered_delete_fused(t_old: ChainTable, t_new: ChainTable,
                                hazard_key: jax.Array, hazard_val: jax.Array,
                                hazard_live: jax.Array, keys: jax.Array,
-                               mask: jax.Array, *, nres_cap: int = NRES_CAP,
-                               interpret: bool = True):
+                               mask: jax.Array, *, nres_cap: int = NRES_CAP):
     """Kernel-backed chain rebuild-epoch delete (paper Alg. 5).  Returns the
     raw (old_astate', new_astate', hazard_live', ok[Q])."""
     from repro.kernels import ops
@@ -590,19 +561,15 @@ def chain_ordered_delete_fused(t_old: ChainTable, t_new: ChainTable,
         *_chain_parts(t_old), *_chain_parts(t_new),
         hazard_key, hazard_val, hazard_live, b_old, b_new, keys, winner,
         max_chain=max(t_old.max_chain, t_new.max_chain),
-        nres_cap=nres_cap, dirty_cap=max(t_old.dirty_cap, t_new.dirty_cap),
-        interpret=interpret)
+        nres_cap=nres_cap, dirty_cap=max(t_old.dirty_cap, t_new.dirty_cap))
 
 
-def chain_extract_chunk_fused(t: ChainTable, cursor: jax.Array, n: int, *,
-                              interpret: bool = True):
+def chain_extract_chunk_fused(t: ChainTable, cursor: jax.Array, n: int):
     """Kernel-backed rebuild chunk scan: the arena is a flat array, so the
     extract kernel runs verbatim (positions are scan order)."""
     from repro.kernels import ops
-    if n > ops.SLAB:   # window contract; fall back to the jnp scan
-        return buckets.chain_extract_chunk(t, cursor, n)
     astate, hk, hv, hl, cur = ops.extract_chunk_fused(
-        t.akey, t.aval, t.astate, cursor, chunk=n, interpret=interpret)
+        t.akey, t.aval, t.astate, cursor, chunk=n)
     return replace(t, astate=astate), hk, hv, hl, cur
 
 
@@ -632,12 +599,11 @@ def chain_maybe_compact(t: ChainTable, *,
                         lambda tt: tt, t)
 
 
-def _chain_insert_fused_compacting(t: ChainTable, keys, vals, mask, *,
-                                   interpret: bool = True):
+def _chain_insert_fused_compacting(t: ChainTable, keys, vals, mask):
     """The descriptor-bound chain insert: the fused insert plus the
     cond-gated arena re-sort that keeps subsequent probes kernel-resident —
     what the DHash layer (user inserts AND hazard landings) runs."""
-    t2, ok = chain_insert_fused(t, keys, vals, mask, interpret=interpret)
+    t2, ok = chain_insert_fused(t, keys, vals, mask)
     return chain_maybe_compact(t2), ok
 
 
@@ -650,7 +616,12 @@ def _next_pow2(x: int) -> int:
 
 
 def _make_linear(capacity: int, seed, *, load_factor: float = 0.75,
-                 max_probes: int = 64) -> LinearTable:
+                 max_probes: int = 128) -> LinearTable:
+    # A probe window is two 128-slot rows whatever the bound up to 129
+    # (window.window_rows), so 128 costs no more than 64 did.  At 64, a
+    # 2^26-slot table loaded to 0.5 has occupied runs longer than the bound
+    # (66 and 72 slots for two of four seeded key sets), and a late insert
+    # starting near the front of such a run was refused.
     rng = np.random.default_rng(seed)
     slots = _next_pow2(int(capacity / load_factor) + 1)
     return buckets.linear_make(slots, hashing.fresh("mix32", rng),
@@ -814,6 +785,16 @@ def _drop_loc(fn):
 # the built-in registry
 # ---------------------------------------------------------------------------
 
+# What Mosaic answers when the sorted-slab kernels are compiled for a TPU
+# (tests/test_tpu_compile.py holds these as strict xfails): their per-query
+# gathers from a VMEM window have no TPU lowering.
+_TC_REFUSAL = ("tc_lookup_tiles, tc_insert_tiles and tc_probe2_tiles: "
+               "ValueError: Shape mismatch in input, indices and output "
+               "(per-entry row gather from the resident row blocks)")
+_CHAIN_REFUSAL = ("chain_probe_tiles and chain_probe2_tiles: "
+                  "NotImplementedError: Only 2D gather is supported "
+                  "(per-query gather from the 1-D slab window)")
+
 LINEAR = register(BucketBackend(
     name="linear",
     table_cls=LinearTable,
@@ -870,6 +851,7 @@ TWOCHOICE = register(BucketBackend(
     ordered_lookup_fused=twochoice_ordered_lookup_fused,
     ordered_delete_fused=twochoice_ordered_delete_fused,
     bounded_placement=True,
+    tpu_refusal=_TC_REFUSAL,
 ))
 
 CUCKOO = register(BucketBackend(
@@ -899,6 +881,7 @@ CUCKOO = register(BucketBackend(
     ordered_lookup_fused=cuckoo_ordered_lookup_fused,
     ordered_delete_fused=cuckoo_ordered_delete_fused,
     bounded_placement=True,
+    tpu_refusal=_TC_REFUSAL,
 ))
 
 CHAIN = register(BucketBackend(
@@ -928,4 +911,5 @@ CHAIN = register(BucketBackend(
     ordered_lookup_fused=chain_ordered_lookup_fused,
     ordered_delete_fused=chain_ordered_delete_fused,
     freeze_old=chain_compact_fused,
+    tpu_refusal=_CHAIN_REFUSAL,
 ))

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 
 from repro.core import hashing
 from repro.core.struct_utils import pytree_dataclass, replace
+from repro.kernels import window
 
 I32 = jnp.int32
 EMPTY, LIVE, TOMB, MIGRATED = I32(0), I32(1), I32(2), I32(3)
@@ -118,66 +119,34 @@ def linear_lookup_fwd(t: LinearTable, keys: jax.Array):
     forwarding"): a slot whose entry was pulled into the rebuild's hazard
     buffer still holds its key, so the probe that passes over it identifies
     the hazard entry at zero extra cost — the beyond-paper replacement for
-    the O(Q x chunk) hazard broadcast compare (EXPERIMENTS.md §Perf).
+    the O(Q x chunk) hazard broadcast compare.  The probe runs over each
+    query's gathered window (``kernels/window.py``) as plain XLA.
     Returns (found, val, loc, mig_loc) with mig_loc = -1 if none."""
     c = t.capacity
     h0 = hashing.bucket_of(t.hfn, keys, c)
-    q = keys.shape[0]
-
-    def cond(carry):
-        active, i = carry[0], carry[5]
-        return active.any() & (i < t.max_probes)
-
-    def body(carry):
-        active, found, val, loc, mig, i = carry
-        pos = (h0 + i) % c
-        st = t.state[pos]
-        kmatch = t.key[pos] == keys
-        hit = active & (st == LIVE) & kmatch
-        mig = jnp.where(active & (st == MIGRATED) & kmatch & (mig < 0),
-                        pos, mig)
-        stop = active & (st == EMPTY)
-        val = jnp.where(hit, t.val[pos], val)
-        loc = jnp.where(hit, pos, loc)
-        found = found | hit
-        active = active & ~hit & ~stop
-        return active, found, val, loc, mig, i + 1
-
-    init = (jnp.ones((q,), bool), jnp.zeros((q,), bool),
-            jnp.zeros((q,), I32), jnp.full((q,), -1, I32),
-            jnp.full((q,), -1, I32), jnp.asarray(0, I32))
-    _, found, val, loc, mig, _ = jax.lax.while_loop(cond, body, init)
-    return found, val, loc, mig
+    kw, sw = window.windows((t.key, t.state), h0, c, t.max_probes)
+    qk = keys[:, None]
+    hit, stop, pos, inwin = window.probe(kw, sw, (h0 % window.LANES)[:, None],
+                                         qk, t.max_probes)
+    w = kw.shape[1]
+    before = pos < jnp.minimum(stop, jnp.where(hit >= 0, hit, w))
+    mig = window.first(inwin & before & (sw == MIGRATED) & (kw == qk), pos, w)
+    hit, mig = hit[:, 0], mig[:, 0]
+    found = hit >= 0
+    loc = jnp.where(found, window.slot(h0, hit) % c, -1)
+    mig_loc = jnp.where(mig < w, window.slot(h0, mig) % c, -1)
+    return found, jnp.where(found, t.val[loc], 0), loc, mig_loc
 
 
 def linear_insert(t: LinearTable, keys: jax.Array, vals: jax.Array, mask: jax.Array):
-    c, q = t.capacity, keys.shape[0]
+    """Batched insert: ``window.insert`` (claim-first-non-LIVE in the probe
+    range, contested slots settled by probe distance then batch index,
+    losers claim again) with its claim pass evaluated by XLA."""
     winner = batch_winners(keys, mask)
-    present, _, _ = linear_lookup(t, keys)
-    pending0 = winner & ~present
-    h0 = hashing.bucket_of(t.hfn, keys, c)
-    idx = jnp.arange(q, dtype=I32)
-
-    def body(_, carry):
-        key, val, state, pending, off, done = carry
-        pos = (h0 + off) % c
-        free = pending & (state[pos] != LIVE)
-        wpos = jnp.where(free, pos, c)
-        claim = jnp.full((c,), q, I32).at[wpos].min(idx, mode="drop")
-        won = free & (claim[pos % c] == idx) & (wpos < c)
-        wp = jnp.where(won, pos, c)
-        key = key.at[wp].set(keys, mode="drop")
-        val = val.at[wp].set(vals, mode="drop")
-        state = state.at[wp].set(LIVE, mode="drop")
-        done = done | won
-        pending = pending & ~won
-        off = jnp.where(pending, off + 1, off)
-        return key, val, state, pending, off, done
-
-    init = (t.key, t.val, t.state, pending0, jnp.zeros((q,), I32), jnp.zeros((q,), bool))
-    key, val, state, _, _, done = jax.lax.fori_loop(0, t.max_probes, body, init)
-    t = LinearTable(capacity=c, max_probes=t.max_probes, hfn=t.hfn, key=key, val=val, state=state)
-    return t, done
+    h0 = hashing.bucket_of(t.hfn, keys, t.capacity)
+    key, val, state, done = window.insert(t.key, t.val, t.state, h0, keys,
+                                          vals, winner, t.max_probes)
+    return replace(t, key=key, val=val, state=state), done
 
 
 def linear_delete(t: LinearTable, keys: jax.Array, mask: jax.Array):

@@ -62,6 +62,7 @@ import numpy as np
 from repro.core import backend as backends
 from repro.core import buckets
 from repro.core.struct_utils import pytree_dataclass, replace
+from repro.kernels import probe
 
 I32 = jnp.int32
 
@@ -137,6 +138,12 @@ def make(backend: str = "linear", capacity: int = 1024, *, chunk: int = 256,
             f"fused kernels are not implemented for backend {backend!r}; "
             f"fused-capable: "
             f"{tuple(n for n in backends.names() if backends.get(n).fused)}")
+    if fused and chunk > probe.SLAB:
+        raise ValueError(f"fused tables take a rebuild chunk of at most "
+                         f"{probe.SLAB} entries, got {chunk}")
+    if fused and be.tpu_refusal and probe.platform() == "tpu":
+        raise ValueError(f"backend {backend!r} has no fused kernels that "
+                         f"compile for TPU: {be.tpu_refusal}")
     if nres_cap is None:
         nres_cap = be.nres_cap
     old = be.make(capacity, seed, **kw)

@@ -55,15 +55,6 @@ from repro.core import dhash, hashing
 I32 = jnp.int32
 
 
-def _axis_size(axis) -> int:
-    """Static mesh-axis size, tolerant of the jax API move: ``lax.axis_size``
-    arrived after 0.5; on older releases ``psum(1, axis)`` constant-folds to
-    the same Python int."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 class Route(NamedTuple):
     """The routing layout of one batch: the [S, cap + spill_cap] send
     buffer (primary columns + shared spill-slab columns) plus the per-key
@@ -215,7 +206,7 @@ def shard_of(keys: jax.Array, nshards: int,
 def routed_lookup(d: dhash.DHashState, keys: jax.Array, axis: str,
                   owner_hfn: hashing.HashFn, cap: int | None = None):
     """DHash lookup across shards. Call inside shard_map."""
-    s = _axis_size(axis)
+    s = lax.axis_size(axis)
     owner = shard_of(keys, s, owner_hfn)
     rt = _route(keys, owner, s, cap)
     c = rt.send.shape[1]
@@ -232,7 +223,7 @@ def routed_update(d: dhash.DHashState, keys: jax.Array, vals: jax.Array,
                   mask: jax.Array, axis: str, owner_hfn: hashing.HashFn,
                   op: Callable = dhash.insert, cap: int | None = None):
     """DHash insert/delete across shards. Returns (d', ok). Call inside shard_map."""
-    s = _axis_size(axis)
+    s = lax.axis_size(axis)
     owner = shard_of(keys, s, owner_hfn)
     rt = _route(keys, owner, s, cap)
     c = rt.send.shape[1]
@@ -308,7 +299,7 @@ def routed_stack_lookup(d: dhash.DHashState, keys: jax.Array,
     under a compact ``spill_slack`` the slab can run out, and only then do
     keys come back not-found (counted in ``Route.dropped``, never silently
     zeroed)."""
-    s = _axis_size(axis)
+    s = lax.axis_size(axis)
     t = dhash.stack_size(d)
     q = keys.shape[0]
     cap = route_cap(cap_factor, q, s * t)
@@ -338,7 +329,7 @@ def routed_stack_update(d: dhash.DHashState, keys: jax.Array,
     the default overflow-proof slab every key is applied; under a compact
     ``spill_slack`` only slab-exhausted keys report ok=False.  Call inside
     shard_map."""
-    s = _axis_size(axis)
+    s = lax.axis_size(axis)
     t = dhash.stack_size(d)
     q = keys.shape[0]
     cap = route_cap(cap_factor, q, s * t)
@@ -358,16 +349,36 @@ def routed_stack_update(d: dhash.DHashState, keys: jax.Array,
 
 
 def make_stacked(nshards: int, backend: str = "linear", capacity: int = 1024,
-                 *, chunk: int = 256, seed: int = 0, **kw) -> dhash.DHashState:
+                 *, chunk: int = 256, seed: int = 0,
+                 sharding: jax.sharding.Sharding | None = None,
+                 **kw) -> dhash.DHashState:
     """Build ``nshards`` independent shard tables stacked on a leading axis
     (``dhash.make_stack`` — the same uniform-pytree stack the vmap ops
     batch; here the leading axis is sharded over the mesh instead).
 
-    Shard the leading axis over the mesh axis, then inside shard_map peel it
-    with ``tree_map(lambda x: x[0], stacked)`` — see ``shardwise``.
+    With ``sharding`` (one leading-axis index per device, e.g.
+    ``NamedSharding(mesh, P(axis))``) each shard's table is built on the
+    device that holds it, so no device ever holds more than its own shard;
+    without it the whole stack is built on the default device.  Inside
+    shard_map peel the leading axis with ``peel``.
     """
-    return dhash.make_stack(nshards, backend, capacity, chunk=chunk,
-                            seed=seed, **kw)
+    if sharding is None:
+        return dhash.make_stack(nshards, backend, capacity, chunk=chunk,
+                                seed=seed, **kw)
+    parts = []
+    for dev, (idx, *_) in sharding.devices_indices_map((nshards,)).items():
+        i = idx.start or 0
+        if (idx.stop or nshards) - i != 1:
+            raise ValueError(f"sharding must hold one shard per device, "
+                             f"{dev} holds {idx}")
+        # built in place on ``dev``: no second copy for the leading axis
+        parts.append(jax.jit(
+            lambda i=i: unpeel(dhash.make(backend, capacity, chunk=chunk,
+                                          seed=seed + i, **kw)),
+            out_shardings=jax.sharding.SingleDeviceSharding(dev))())
+    return jax.tree_util.tree_map(
+        lambda *xs: jax.make_array_from_single_device_arrays(
+            (nshards,) + xs[0].shape[1:], sharding, list(xs)), *parts)
 
 
 def peel(stacked):
@@ -390,7 +401,7 @@ def routed_service_step(d: dhash.DHashState, lookup_keys: jax.Array,
 
     cap_factor > 0 bounds the routing buffers at cap = ceil(cap_factor*Q/S)
     (§Perf lever: S x fewer wire bytes and S x smaller remote batches)."""
-    s = _axis_size(axis)
+    s = lax.axis_size(axis)
     capof = (lambda q: route_cap(cap_factor, q, s)) if cap_factor > 0 \
         else (lambda q: None)
     found, vals = routed_lookup(d, lookup_keys, axis, owner_hfn,

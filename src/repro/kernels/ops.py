@@ -1,35 +1,33 @@
-"""Jit'd wrappers around the Pallas kernels: padding, sorting, fallback.
+"""Jit'd wrappers around the Pallas kernels: window gathers, claims, fallback.
 
-``probe_lookup`` is a drop-in accelerated equivalent of
-``ref.probe_lookup_ref`` (and of ``buckets.linear_lookup``'s inner loop);
-``ordered_lookup_fused`` is the accelerated rebuild-epoch path (one sort +
-one pallas_call for the whole old->hazard->new ordered check);
-``probe_insert`` / ``probe_delete`` are the accelerated write paths (claim
-or location kernel + one scatter); ``ordered_delete_fused`` is the
-rebuild-epoch delete (the same probe2 kernel's location outputs drive the
-old/new tombstones and the hazard kill); ``extract_chunk_fused`` is the
-rebuild chunk scan; ``twochoice_lookup`` / ``twochoice_insert`` /
-``twochoice_delete`` bring the 2-choice backend onto the same
-sort + scalar-prefetch treatment (both row choices of a query expand into
-two entries of ONE sorted batch), and ``twochoice_ordered_lookup`` /
-``twochoice_ordered_delete`` are its rebuild-epoch single-pass analogues
-(one sort + one tc_probe2 pallas_call for old -> hazard -> new); the
-``chain_*`` family brings the last backend onto the same treatment via the
-arena-sorted node layout (``chain_compact_fused`` + per-bucket segment
-windows + dirty-tail dense compare — see the chain section below).
+Linear backend (``kernels/window.py`` windows, ``kernels/probe.py``
+kernels): every op gathers each query's probe window with XLA row gathers
+and runs ONE pallas_call over the dense windows.
 
-The rebuild-epoch ops cover arbitrarily grown new tables via a **two-level
-tile map**: a first-level jnp pass (``_resident_blockmap`` — histogram +
-top_k, no extra sort) picks up to ``NRES_CAP`` resident new-table blocks
-per query tile, and the probe2 kernels reduce over them on a
-``(tiles, nres)`` grid.  ``rebuild_escape_rate`` reports the fraction of
-queries that still overflow to the fallback (the growth-escape benchmark
-gates it).
+* ``probe_lookup`` / ``probe_delete`` — the lookup kernel; the delete
+  tombstones the hit slot with one scatter (no second probe).
+* ``ordered_lookup_fused`` / ``ordered_delete_fused`` — the rebuild-epoch
+  ops: ONE probe2 pallas_call covers the old table, the hazard buffer and the
+  new table (the paper's Lemma-4.1 order), whatever the two tables' sizes.
+* ``probe_insert`` — ``window.insert`` with the claim kernel: ONE sort
+  settles claims two queries make on the same slot, one scatter per table
+  array, and the losers claim again on the updated table.
+* ``extract_chunk_fused`` — the rebuild chunk scan (LIVE mask + compaction
+  ranks in the kernel, the compaction itself one scatter).
 
-Exactness contract shared by all of them: queries whose probe window escapes
-the VMEM-resident slab (hash skew), or whose insert claim collides across
-tiles, are recomputed by the jnp oracle fallback — which is gated behind
-``jax.lax.cond`` so the steady state (no escapes) never pays for it.
+The windows cover the whole probe bound, so the linear ops are exact with
+no jnp fallback; only an insert whose claimed slot went to another query
+of the batch takes a second claim pass (``insert_retry_share`` reports the share
+of a batch that does).
+
+``twochoice_*`` (also driving ``cuckoo``) and ``chain_*`` keep the
+sorted-slab treatment: ONE argsort of the batch, ONE pallas_call over
+scalar-prefetched slab blocks, and the gated jnp fallback for queries whose
+window escapes the slab.  Their rebuild-epoch ops cover grown new tables
+with a **two-level tile map**: a first-level jnp pass
+(``_resident_blockmap`` — histogram + top_k, no extra sort) picks up to
+``NRES_CAP`` resident new-table blocks per query tile, and the probe2
+kernels reduce over them on a ``(tiles, nres)`` grid.
 """
 from __future__ import annotations
 
@@ -38,40 +36,255 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
-from repro.kernels.probe import (QT, SLAB, _tc_rowslab, chain_probe2_tiles,
-                                 chain_probe_tiles, extract_tiles,
-                                 probe2_tiles, probe_insert_tiles,
-                                 probe_lookup_tiles, tc_insert_tiles,
-                                 tc_lookup_tiles, tc_probe2_tiles)
+from repro.kernels import ref, window
+from repro.kernels.probe import (DIRTY_CAP, HZB, NRES_CAP, QT, SLAB, WQT,
+                                 _tc_rowslab, chain_probe2_tiles,
+                                 chain_probe_tiles, claim_windows,
+                                 extract_rows, probe2_windows, probe_windows,
+                                 tc_insert_tiles, tc_lookup_tiles,
+                                 tc_probe2_tiles)
+from repro.kernels.window import LANES
 
 I32 = jnp.int32
 EMPTY, LIVE, TOMB, MIGRATED = 0, 1, 2, 3
-
-# Resident new-table blocks per query tile in the rebuild-epoch probe (the
-# second level of the two-level tile map).  16 block pairs cover a new table
-# of up to ~16 SLABs (64K slots) COMPLETELY — a 16x growth rebuild of the
-# default benchmark tables stays fully fused; beyond that, the least-
-# populated blocks of a tile overflow to the gated jnp fallback.  This is
-# the DEFAULT of the ``nres_cap`` parameter the rebuild-epoch ops accept;
-# the per-backend value lives on the ``BucketBackend`` descriptor
-# (core/backend.py) and is threaded here through ``dhash.make()``.
-NRES_CAP = 16
-
-# Dirty-tail window of the arena-sorted chain backend: nodes inserted since
-# the last compaction live in a contiguous tail, resolved by a dense window
-# compare (the hazard-buffer treatment).  A tail grown past DIRTY_CAP is no
-# longer fully visible to the window, so the fused chain ops escape to the
-# pointer-chasing jnp reference — ``backend.chain_maybe_compact`` re-sorts
-# the arena at exactly this threshold to keep the steady state on-kernel.
-# Like NRES_CAP this is only the DEFAULT of the ``dirty_cap`` parameter;
-# the live value is a ``BucketBackend`` descriptor field.
-DIRTY_CAP = 512
 
 
 def _pad_to(x: jax.Array, n: int, fill=0):
     return jnp.pad(x, (0, n - x.shape[0]), constant_values=fill)
 
+
+# ---------------------------------------------------------------------------
+# linear: per-query probe windows (kernels/window.py)
+# ---------------------------------------------------------------------------
+
+def _queries(tables, h0: jax.Array, keys: jax.Array, max_probes: int):
+    """Kernel operands of a query batch padded to a ``WQT`` multiple: the
+    start lanes and keys as ``[Qpad, 1]`` columns and the (key, state)
+    windows of ``tables`` (a key/state pair) as ``[Qpad, W]``."""
+    q = keys.shape[0]
+    qpad = -(-q // WQT) * WQT
+    h0p = _pad_to(h0.astype(I32), qpad)
+    kw, sw = window.windows(tables, h0p, tables[0].shape[0], max_probes)
+    return ((h0p % LANES)[:, None], _pad_to(keys.astype(I32), qpad)[:, None],
+            kw, sw)
+
+
+def _lanes_to_slots(h0: jax.Array, lane: jax.Array) -> jax.Array:
+    """Window lanes ``[Qpad, 1]`` -> unwrapped slots ``[Q]`` (``>= h0``),
+    -1 where absent."""
+    lane = lane[:h0.shape[0], 0]
+    return jnp.where(lane >= 0, window.slot(h0, lane), -1)
+
+
+def _kernel_probe(tkey, tstate, h0, keys, max_probes: int):
+    """The lookup kernel over gathered windows: the unwrapped slot of each
+    key's LIVE entry, -1 if absent."""
+    hit = probe_windows(*_queries((tkey, tstate), h0, keys, max_probes),
+                        max_probes=max_probes)
+    return _lanes_to_slots(h0, hit)
+
+
+def _kernel_claim(tkey, tstate, h0, keys, max_probes: int):
+    """``window.insert``'s claim pass through the claim kernel."""
+    q = keys.shape[0]
+    hit, free = claim_windows(*_queries((tkey, tstate), h0, keys, max_probes),
+                              max_probes=max_probes)
+    return hit[:q, 0], free[:q, 0]
+
+
+@partial(jax.jit, static_argnames=("max_probes", "with_loc"))
+def probe_lookup(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
+                 h0: jax.Array, qkey: jax.Array, *, max_probes: int = 64,
+                 with_loc: bool = False):
+    """Batched linear-probe lookup. Returns (found[Q], val[Q]), or
+    (found, val, loc[Q]) when ``with_loc`` — ``loc`` is the hit's unwrapped
+    slot (``>= h0``, ``loc % C`` physical; -1 on miss), the probe telemetry
+    input for the elastic policy's expensive-lookup counter.
+
+    Args:
+      tkey/tval/tstate: table arrays [C].
+      h0: start slot per query (hash(key) % C), [Q].
+      qkey: query keys [Q].
+    """
+    c = tkey.shape[0]
+    loc = _kernel_probe(tkey, tstate, h0, qkey, max_probes)
+    found = loc >= 0
+    val = jnp.where(found, tval[loc % c], 0)
+    if with_loc:
+        return found, val, loc
+    return found, val
+
+
+@partial(jax.jit, static_argnames=("max_probes",))
+def ordered_lookup(old_tables, new_tables, hazard_key, hazard_val, hazard_live,
+                   h0_old, h0_new, qkey, *, max_probes: int = 64):
+    """UNFUSED rebuild-epoch lookup: old table -> hazard buffer -> new table
+    (the paper's Lemma 4.1 order), each table through its own lookup
+    pallas_call.  Kept as the comparison baseline for
+    ``ordered_lookup_fused`` (see bench_rebuild's fused=on|off axis)."""
+    f_old, v_old = probe_lookup(*old_tables, h0_old, qkey,
+                                max_probes=max_probes)
+    eq = (qkey[:, None] == hazard_key[None, :]) & hazard_live[None, :]
+    f_hz = eq.any(-1)
+    v_hz = jnp.take(hazard_val, jnp.argmax(eq, axis=-1))
+    f_new, v_new = probe_lookup(*new_tables, h0_new, qkey,
+                                max_probes=max_probes)
+    found = f_old | f_hz | f_new
+    val = jnp.where(f_old, v_old, jnp.where(f_hz, v_hz, v_new))
+    return found, val
+
+
+def _probe2(old_tables, new_tables, hazard_key, hazard_live, h0_old, h0_new,
+            keys, max_probes: int):
+    """The ONE probe2 pallas_call of the fused rebuild-epoch ops.  Returns
+    (old slot, hazard index, new slot) per query, -1 where absent; the slots
+    are unwrapped as in ``probe_lookup``."""
+    off_o, qk, *old_w = _queries((old_tables[0], old_tables[2]), h0_old,
+                                 keys, max_probes)
+    off_n, _, *new_w = _queries((new_tables[0], new_tables[2]), h0_new, keys,
+                                max_probes)
+    ch = hazard_key.shape[0]
+    chp = ch if ch <= HZB else -(-ch // HZB) * HZB
+    hit_o, hz, hit_n = probe2_windows(
+        off_o, off_n, qk, old_w, new_w, _pad_to(hazard_key, chp)[None],
+        _pad_to(hazard_live.astype(I32), chp)[None], max_probes=max_probes)
+    return (_lanes_to_slots(h0_old, hit_o), hz[:keys.shape[0], 0],
+            _lanes_to_slots(h0_new, hit_n))
+
+
+@partial(jax.jit, static_argnames=("max_probes",))
+def ordered_lookup_fused(old_tables, new_tables, hazard_key, hazard_val,
+                         hazard_live, h0_old, h0_new, qkey, *,
+                         max_probes: int = 64):
+    """FUSED rebuild-epoch lookup: ONE pallas_call resolves the old table,
+    the hazard buffer and the new table; the Lemma-4.1 priority (old >
+    hazard > new) picks the answer.  Returns (found[Q], val[Q])."""
+    loc_o, hz, loc_n = _probe2(old_tables, new_tables, hazard_key,
+                               hazard_live, h0_old, h0_new, qkey, max_probes)
+    f_old, f_hz, f_new = loc_o >= 0, hz >= 0, loc_n >= 0
+    v_old = old_tables[1][loc_o % old_tables[1].shape[0]]
+    v_hz = hazard_val[jnp.maximum(hz, 0)]
+    v_new = new_tables[1][loc_n % new_tables[1].shape[0]]
+    found = f_old | f_hz | f_new
+    val = jnp.where(f_old, v_old,
+                    jnp.where(f_hz, v_hz, jnp.where(f_new, v_new, 0)))
+    return found, val
+
+
+@partial(jax.jit, static_argnames=("max_probes",))
+def probe_insert(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
+                 h0: jax.Array, keys: jax.Array, vals: jax.Array,
+                 mask: jax.Array, *, max_probes: int = 64):
+    """Batched linear-probe INSERT (``window.insert``) with the claim
+    kernel as its claim pass: one claim-settling sort and one scatter per
+    table array per pass; a claim that lost its slot (``window.settle``)
+    claims again on the updated table (a second pass, rare at deployment
+    load — ``insert_retry_share``).
+
+    Caller contract: ``mask`` is winner-filtered (at most one True per
+    distinct key; use ``buckets.batch_winners``).  Set semantics: ok=False
+    if the key is already LIVE or no free slot exists within
+    ``max_probes``.
+
+    Returns (tkey', tval', tstate', ok[Q]).
+    """
+    return window.insert(tkey, tval, tstate, h0, keys, vals, mask,
+                         max_probes, claim_pass=_kernel_claim)
+
+
+@partial(jax.jit, static_argnames=("max_probes",))
+def insert_retry_share(tkey: jax.Array, tstate: jax.Array, h0: jax.Array,
+                       keys: jax.Array, mask: jax.Array, *,
+                       max_probes: int = 64):
+    """Share of a masked insert batch that ``probe_insert`` sends through a
+    second claim pass (its first claim lost the slot) — the same claim
+    kernel and settling sort."""
+    return window.retry_share(tkey, tstate, h0, keys, mask, max_probes,
+                              claim_pass=_kernel_claim)
+
+
+@partial(jax.jit, static_argnames=("max_probes",))
+def probe_delete(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
+                 h0: jax.Array, keys: jax.Array, mask: jax.Array, *,
+                 max_probes: int = 64):
+    """Batched linear-probe DELETE: the lookup kernel's hit slot + ONE
+    tombstone scatter (no second probe pass).
+
+    Caller contract: ``mask`` is winner-filtered (at most one True per
+    distinct key; use ``buckets.batch_winners``), so distinct masked keys
+    occupy distinct slots and the scatter cannot conflict.
+
+    Returns (tstate', ok[Q]).
+    """
+    del tval   # the table triple is the uniform operand of the linear ops
+    c = tkey.shape[0]
+    loc = _kernel_probe(tkey, tstate, h0, keys, max_probes)
+    ok = mask & (loc >= 0)
+    return tstate.at[jnp.where(ok, loc % c, c)].set(TOMB, mode="drop"), ok
+
+
+@partial(jax.jit, static_argnames=("max_probes",))
+def ordered_delete_fused(old_tables, new_tables, hazard_key, hazard_val,
+                         hazard_live, h0_old, h0_new, keys, mask, *,
+                         max_probes: int = 64):
+    """FUSED rebuild-epoch delete (paper Alg. 5): the SAME probe2 pass as
+    the ordered lookup, then three scatters land the result — tombstone the
+    old-table slot, or clear the hazard live bit (LOGICALLY_REMOVED on an
+    in-flight entry; landing drops it), or tombstone the new-table slot.
+
+    Caller contract: ``mask`` is winner-filtered.  Returns
+    (old_state', new_state', hazard_live', ok[Q]).
+    """
+    del hazard_val
+    c_old, c_new = old_tables[0].shape[0], new_tables[0].shape[0]
+    ch = hazard_key.shape[0]
+    loc_o, hz, loc_n = _probe2(old_tables, new_tables, hazard_key,
+                               hazard_live, h0_old, h0_new, keys, max_probes)
+    ok_old = mask & (loc_o >= 0)
+    ok_hz = mask & ~ok_old & (hz >= 0)
+    ok_new = mask & ~ok_old & ~ok_hz & (loc_n >= 0)
+    old_state = old_tables[2].at[
+        jnp.where(ok_old, loc_o % c_old, c_old)].set(TOMB, mode="drop")
+    new_state = new_tables[2].at[
+        jnp.where(ok_new, loc_n % c_new, c_new)].set(TOMB, mode="drop")
+    kill = jnp.zeros_like(hazard_live).at[
+        jnp.where(ok_hz, hz, ch)].set(True, mode="drop")
+    return old_state, new_state, hazard_live & ~kill, ok_old | ok_hz | ok_new
+
+
+@partial(jax.jit, static_argnames=("chunk",))
+def extract_chunk_fused(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
+                        cursor: jax.Array, *, chunk: int):
+    """Rebuild chunk scan via the extract kernel: ONE pallas_call finds the
+    LIVE slots of ``[cursor, cursor + chunk)`` and their compaction ranks;
+    one scatter per hazard array compacts them, one scatter marks them
+    MIGRATED.
+
+    Returns (tstate', hkeys[chunk], hvals[chunk], hlive[chunk] bool,
+    new_cursor) — identical set contents to the jnp scan, with the hazard
+    entries compacted to the front.
+    """
+    c = tkey.shape[0]
+    rows = -(-chunk // LANES)
+    pos = cursor + jnp.arange(rows * LANES, dtype=I32)
+    src = jnp.minimum(pos, c - 1)
+    live, rank = extract_rows(tstate[src].reshape(rows, LANES),
+                              jnp.clip(c - cursor, 0, chunk))
+    live = live.reshape(-1)[:chunk] != 0
+    dest = jnp.where(live, rank.reshape(-1)[:chunk], chunk)
+    src = src[:chunk]
+    hk = jnp.zeros((chunk,), I32).at[dest].set(tkey[src], mode="drop")
+    hv = jnp.zeros((chunk,), I32).at[dest].set(tval[src], mode="drop")
+    hl = jnp.arange(chunk, dtype=I32) < live.sum()
+    tstate2 = tstate.at[jnp.where(live, pos[:chunk], c)].set(
+        MIGRATED, mode="drop")
+    new_cursor = jnp.minimum(cursor + chunk, c).astype(I32)
+    return tstate2, hk, hv, hl, new_cursor
+
+
+# ---------------------------------------------------------------------------
+# sorted-slab helpers (twochoice / cuckoo / chain)
+# ---------------------------------------------------------------------------
 
 def _pad_table(arrays, c: int, max_probes: int):
     """Pad table arrays with a wrapped copy (probes never wrap in-kernel),
@@ -120,389 +333,6 @@ def _resident_blockmap(blk_sorted: jax.Array, tiles: int, nblocks: int,
     return jnp.minimum(top.astype(I32), nblocks - 2).T
 
 
-@partial(jax.jit, static_argnames=("max_probes", "with_loc", "interpret"))
-def probe_lookup(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
-                 h0: jax.Array, qkey: jax.Array, *, max_probes: int = 64,
-                 with_loc: bool = False, interpret: bool = True):
-    """Batched linear-probe lookup. Returns (found[Q], val[Q]), or
-    (found, val, loc[Q]) when ``with_loc`` — ``loc`` is the hit's
-    padded-table coordinate (unwrapped, >= h0; -1 on miss), the probe
-    telemetry input for the elastic policy's expensive-lookup counter.
-
-    Args:
-      tkey/tval/tstate: table arrays [C].
-      h0: start slot per query (hash(key) % C), [Q].
-      qkey: query keys [Q].
-    """
-    c = tkey.shape[0]
-    q = qkey.shape[0]
-    tk, tv, ts = _pad_table((tkey, tval, tstate), c, max_probes)
-
-    # ONE sort: queries ordered by start slot so tiles hit contiguous slabs
-    order = jnp.argsort(h0)
-    qpad = -(-q // QT) * QT
-    h0s, qks = _sort_pad_queries(order, qpad, h0, qkey)
-    tiles = qpad // QT
-    slab_base = _tile_base(h0s, tiles, tk.shape[0])
-
-    found_s, val_s, loc_s, complete_s = probe_lookup_tiles(
-        tk, tv, ts, h0s, qks, slab_base, max_probes=max_probes,
-        interpret=interpret)
-
-    # fallback: recompute incomplete queries with the jnp oracle — gated so
-    # the no-skew steady state skips the oracle pass entirely (h0s is already
-    # in [0, C), so no re-mod either; the oracle wraps internally).
-    need = ~complete_s
-
-    if with_loc:
-        def fallback(fvl):
-            f0, v0, l0 = fvl
-            fb_f, fb_v = ref.probe_lookup_ref(tkey, tval, tstate, h0s, qks,
-                                              max_probes)
-            # a query that escaped the resident window genuinely probed past
-            # it: report max cost so the policy sees it as expensive
-            fb_l = jnp.where(fb_f, h0s + (max_probes - 1), -1).astype(I32)
-            return (jnp.where(need, fb_f, f0), jnp.where(need, fb_v, v0),
-                    jnp.where(need, fb_l, l0))
-
-        found_s, val_s, loc_s = jax.lax.cond(need.any(), fallback,
-                                             lambda fvl: fvl,
-                                             (found_s, val_s, loc_s))
-        found = jnp.zeros((q,), jnp.bool_).at[order].set(found_s[:q])
-        val = jnp.zeros((q,), I32).at[order].set(val_s[:q])
-        loc = jnp.full((q,), -1, I32).at[order].set(loc_s[:q].astype(I32))
-        return found, val, loc
-
-    def fallback(fv):
-        f0, v0 = fv
-        fb_f, fb_v = ref.probe_lookup_ref(tkey, tval, tstate, h0s, qks,
-                                          max_probes)
-        return jnp.where(need, fb_f, f0), jnp.where(need, fb_v, v0)
-
-    found_s, val_s = jax.lax.cond(need.any(), fallback, lambda fv: fv,
-                                  (found_s, val_s))
-
-    # unsort (order permutes [0, q); tail positions are padding)
-    found = jnp.zeros((q,), jnp.bool_).at[order].set(found_s[:q])
-    val = jnp.zeros((q,), I32).at[order].set(val_s[:q])
-    return found, val
-
-
-@partial(jax.jit, static_argnames=("max_probes", "interpret"))
-def ordered_lookup(old_tables, new_tables, hazard_key, hazard_val, hazard_live,
-                   h0_old, h0_new, qkey, *, max_probes: int = 64,
-                   interpret: bool = True):
-    """UNFUSED rebuild-epoch lookup: old table -> hazard buffer -> new table
-    (the paper's Lemma 4.1 order), each table pass via its own sort +
-    pallas_call.  Kept as the comparison baseline for ``ordered_lookup_fused``
-    (see bench_rebuild's fused=on|off axis)."""
-    f_old, v_old = probe_lookup(*old_tables, h0_old, qkey,
-                                max_probes=max_probes, interpret=interpret)
-    eq = (qkey[:, None] == hazard_key[None, :]) & hazard_live[None, :]
-    f_hz = eq.any(-1)
-    v_hz = jnp.take(hazard_val, jnp.argmax(eq, axis=-1))
-    f_new, v_new = probe_lookup(*new_tables, h0_new, qkey,
-                                max_probes=max_probes, interpret=interpret)
-    found = f_old | f_hz | f_new
-    val = jnp.where(f_old, v_old, jnp.where(f_hz, v_hz, v_new))
-    return found, val
-
-
-def _probe2_run(old_tables, new_tables, hazard_key, hazard_val, hazard_live,
-                h0_old, h0_new, keys, max_probes: int, interpret: bool,
-                nres_cap: int = NRES_CAP):
-    """Shared prep + launch for the fused rebuild-epoch ops: the ONE argsort
-    (keyed on the old table's start slot), the two-level new-table tile map
-    (per-tile resident blocks, no second sort), and the ONE probe2
-    pallas_call.  Returns (order, (h0os, h0ns, qks), kernel outputs)."""
-    c_old = old_tables[0].shape[0]
-    c_new = new_tables[0].shape[0]
-    q = keys.shape[0]
-    old_p = _pad_table(old_tables, c_old, max_probes)
-    new_p = _pad_table(new_tables, c_new, max_probes)
-
-    order = jnp.argsort(h0_old)
-    qpad = -(-q // QT) * QT
-    h0os, h0ns, qks = _sort_pad_queries(order, qpad, h0_old, h0_new, keys)
-    tiles = qpad // QT
-    nblocks_new = new_p[0].shape[0] // SLAB
-    nres = min(nres_cap, nblocks_new - 1)
-    slab2 = jnp.concatenate([
-        _tile_base(h0os, tiles, old_p[0].shape[0])[None],
-        _resident_blockmap(h0ns // SLAB, tiles, nblocks_new, nres)])
-
-    outs = probe2_tiles(
-        old_p, new_p, hazard_key, hazard_val, hazard_live.astype(I32),
-        h0os, h0ns, qks, slab2, max_probes=max_probes, interpret=interpret)
-    return order, (h0os, h0ns, qks), outs
-
-
-@partial(jax.jit, static_argnames=("max_probes", "interpret", "nres_cap"))
-def ordered_lookup_fused(old_tables, new_tables, hazard_key, hazard_val,
-                         hazard_live, h0_old, h0_new, qkey, *,
-                         max_probes: int = 64, interpret: bool = True,
-                         nres_cap: int = NRES_CAP):
-    """FUSED rebuild-epoch lookup: ONE argsort (keyed on h0_old) and ONE
-    pallas_call emit the Lemma-4.1-ordered result for both tables plus the
-    hazard buffer.  New-table residency is the two-level tile map: each
-    tile's windows are bucketed into up to ``NRES_CAP`` resident blocks by a
-    cheap jnp histogram pass, so growth-heavy rebuilds stay fused; a query
-    whose block overflows the residents AND that the old table / hazard
-    buffer did not resolve falls back to the jnp oracle (gated — free when
-    nothing escapes)."""
-    q = qkey.shape[0]
-    order, (h0os, h0ns, qks), outs = _probe2_run(
-        old_tables, new_tables, hazard_key, hazard_val, hazard_live,
-        h0_old, h0_new, qkey, max_probes, interpret, nres_cap)
-    found_s, val_s, complete_s = outs[0], outs[1], outs[2]
-
-    need = ~complete_s
-
-    def fallback(fv):
-        f0, v0 = fv
-        fb_f, fb_v = ref.ordered_lookup_ref(
-            old_tables, new_tables, hazard_key, hazard_val, hazard_live,
-            h0os, h0ns, qks, max_probes)
-        return jnp.where(need, fb_f, f0), jnp.where(need, fb_v, v0)
-
-    found_s, val_s = jax.lax.cond(need.any(), fallback, lambda fv: fv,
-                                  (found_s, val_s))
-
-    found = jnp.zeros((q,), jnp.bool_).at[order].set(found_s[:q])
-    val = jnp.zeros((q,), I32).at[order].set(val_s[:q])
-    return found, val
-
-
-@partial(jax.jit, static_argnames=("max_probes", "interpret", "nres_cap"))
-def rebuild_escape_rate(old_tables, new_tables, hazard_key, hazard_val,
-                        hazard_live, h0_old, h0_new, qkey, *,
-                        max_probes: int = 64, interpret: bool = True,
-                        nres_cap: int = NRES_CAP):
-    """Diagnostic for the growth-escape benchmark: the fraction of
-    rebuild-epoch queries the fused probe2 pass could NOT resolve in-kernel
-    (``complete=False`` — the gated jnp oracle recomputes exactly these).
-    Runs the identical prep + kernel as ``ordered_lookup_fused``, so the
-    rate it reports is the rate the fused path actually pays."""
-    q = qkey.shape[0]
-    order, _sorted, outs = _probe2_run(
-        old_tables, new_tables, hazard_key, hazard_val, hazard_live,
-        h0_old, h0_new, qkey, max_probes, interpret, nres_cap)
-    complete_s = outs[2]
-    escaped = jnp.zeros((q,), jnp.bool_).at[order].set((~complete_s)[:q])
-    return escaped.mean()
-
-
-@partial(jax.jit, static_argnames=("max_probes", "interpret"))
-def probe_insert(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
-                 h0: jax.Array, keys: jax.Array, vals: jax.Array,
-                 mask: jax.Array, *, max_probes: int = 64,
-                 interpret: bool = True):
-    """Batched linear-probe INSERT via the claim kernel + one scatter.
-
-    Caller contract: ``mask`` is winner-filtered (at most one True per
-    distinct key; use ``buckets.batch_winners``).  Set semantics: ok=False if
-    the key is already LIVE or no free slot exists within ``max_probes``.
-
-    Escape hatches (all exact, resolved by the gated jnp fallback):
-      * probe window escapes the 2-block slab (``complete=False``);
-      * two tiles claim the same physical slot (the padded table holds a
-        wrapped copy of the first ``max_probes`` slots, so the same physical
-        slot can be claimed under two padded positions) — first claimant in
-        sort order keeps it, the loser escapes.
-
-    Returns (tkey', tval', tstate', ok[Q]).
-    """
-    c = tkey.shape[0]
-    q = keys.shape[0]
-    tk, ts = _pad_table((tkey, tstate), c, max_probes)
-
-    order = jnp.argsort(h0)
-    qpad = -(-q // QT) * QT
-    h0s, qks, qvs = _sort_pad_queries(order, qpad, h0, keys, vals)
-    qms = _pad_to(mask[order], qpad, fill=False)
-    tiles = qpad // QT
-    slab_base = _tile_base(h0s, tiles, tk.shape[0])
-
-    present_s, claim_s, complete_s = probe_insert_tiles(
-        tk, ts, h0s, qks, qms.astype(I32), slab_base,
-        max_probes=max_probes, interpret=interpret)
-
-    # resolve claims globally: claims live in padded coordinates within
-    # [h0, h0 + max_probes) ⊂ [0, C + max_probes), so % C maps the wrapped
-    # region back onto the physical table; first claimant (sort order) wins.
-    claimed = complete_s & (claim_s >= 0)
-    phys = jnp.where(claimed, claim_s % c, c)
-    sidx = jnp.arange(qpad, dtype=I32)
-    first = jnp.full((c,), qpad, I32).at[phys].min(sidx, mode="drop")
-    keep = claimed & (first[jnp.clip(phys, 0, c - 1)] == sidx)
-    conflict = claimed & ~keep
-
-    wp = jnp.where(keep, phys, c)
-    tkey2 = tkey.at[wp].set(qks, mode="drop")
-    tval2 = tval.at[wp].set(qvs, mode="drop")
-    tstate2 = tstate.at[wp].set(LIVE, mode="drop")
-    ok_s = keep
-
-    need = qms & (~complete_s | conflict)
-
-    def fallback(op):
-        k, v, s, ok = op
-        fb_k, fb_v, fb_s, fb_ok = ref.probe_insert_ref(
-            k, v, s, h0s, qks, qvs, need, max_probes)
-        return fb_k, fb_v, fb_s, ok | fb_ok
-
-    tkey2, tval2, tstate2, ok_s = jax.lax.cond(
-        need.any(), fallback, lambda op: op, (tkey2, tval2, tstate2, ok_s))
-
-    ok = jnp.zeros((q,), jnp.bool_).at[order].set(ok_s[:q])
-    return tkey2, tval2, tstate2, ok
-
-
-@partial(jax.jit, static_argnames=("max_probes", "interpret"))
-def probe_delete(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
-                 h0: jax.Array, keys: jax.Array, mask: jax.Array, *,
-                 max_probes: int = 64, interpret: bool = True):
-    """Batched linear-probe DELETE: the location-emitting lookup kernel +
-    ONE tombstone scatter (no second probe pass).
-
-    Caller contract: ``mask`` is winner-filtered (at most one True per
-    distinct key; use ``buckets.batch_winners``), so distinct masked keys
-    occupy distinct slots and the scatter cannot conflict.  Queries whose
-    probe window escapes the resident slab fall back to the jnp oracle
-    (gated — free when nothing escapes).
-
-    Returns (tstate', ok[Q]).
-    """
-    c = tkey.shape[0]
-    q = keys.shape[0]
-    tk, tv, ts = _pad_table((tkey, tval, tstate), c, max_probes)
-
-    order = jnp.argsort(h0)
-    qpad = -(-q // QT) * QT
-    h0s, qks = _sort_pad_queries(order, qpad, h0, keys)
-    qms = _pad_to(mask[order], qpad, fill=False)
-    tiles = qpad // QT
-    slab_base = _tile_base(h0s, tiles, tk.shape[0])
-
-    found_s, _val_s, loc_s, complete_s = probe_lookup_tiles(
-        tk, tv, ts, h0s, qks, slab_base, max_probes=max_probes,
-        interpret=interpret)
-
-    # loc is in padded coordinates within [h0, h0 + max_probes); % C maps the
-    # wrapped region back onto the physical table
-    ok_s = qms & found_s
-    tstate2 = tstate.at[jnp.where(ok_s, loc_s % c, c)].set(TOMB, mode="drop")
-
-    need = qms & ~complete_s
-
-    def fallback(op):
-        s, ok = op
-        fb_s, fb_ok = ref.probe_delete_ref(tkey, tval, s, h0s, qks, need,
-                                           max_probes)
-        return fb_s, ok | fb_ok
-
-    tstate2, ok_s = jax.lax.cond(need.any(), fallback, lambda op: op,
-                                 (tstate2, ok_s))
-
-    ok = jnp.zeros((q,), jnp.bool_).at[order].set(ok_s[:q])
-    return tstate2, ok
-
-
-@partial(jax.jit, static_argnames=("max_probes", "interpret", "nres_cap"))
-def ordered_delete_fused(old_tables, new_tables, hazard_key, hazard_val,
-                         hazard_live, h0_old, h0_new, keys, mask, *,
-                         max_probes: int = 64, interpret: bool = True,
-                         nres_cap: int = NRES_CAP):
-    """FUSED rebuild-epoch delete (paper Alg. 5): ONE argsort + ONE
-    pallas_call (the probe2 kernel's location outputs) resolve the ordered
-    check, then three scatters land the result — tombstone the old-table
-    slot, or clear the hazard live bit (LOGICALLY_REMOVED on an in-flight
-    entry; landing drops it), or tombstone the new-table slot.
-
-    Caller contract: ``mask`` is winner-filtered.  Returns
-    (old_state', new_state', hazard_live', ok[Q]).
-    """
-    c_old = old_tables[0].shape[0]
-    c_new = new_tables[0].shape[0]
-    ch = hazard_key.shape[0]
-    q = keys.shape[0]
-    qpad = -(-q // QT) * QT
-    order, (h0os, h0ns, qks), outs = _probe2_run(
-        old_tables, new_tables, hazard_key, hazard_val, hazard_live,
-        h0_old, h0_new, keys, max_probes, interpret, nres_cap)
-    (_found_s, _val_s, complete_s, fold_s, locold_s, hzidx_s,
-     locnew_s, _cold_s) = outs
-    qms = _pad_to(mask[order], qpad, fill=False)
-
-    # ordered landing: old hit > hazard hit > new hit (at most one fires)
-    f_hz = hzidx_s >= 0
-    ok_old = qms & fold_s
-    ok_hz = qms & complete_s & ~fold_s & f_hz
-    ok_new = qms & complete_s & ~fold_s & ~f_hz & (locnew_s >= 0)
-
-    old_state = old_tables[2].at[
-        jnp.where(ok_old, locold_s % c_old, c_old)].set(TOMB, mode="drop")
-    new_state = new_tables[2].at[
-        jnp.where(ok_new, locnew_s % c_new, c_new)].set(TOMB, mode="drop")
-    kill = jnp.zeros_like(hazard_live).at[
-        jnp.where(ok_hz, hzidx_s, ch)].set(True, mode="drop")
-    hz_live = hazard_live & ~kill
-    ok_s = ok_old | ok_hz | ok_new
-
-    need = qms & ~complete_s
-
-    def fallback(op):
-        os_, ns_, hl_, ok = op
-        fb_os, ok_o = ref.probe_delete_ref(old_tables[0], old_tables[1],
-                                           os_, h0os, qks, need, max_probes)
-        pend = need & ~ok_o
-        eq = (qks[:, None] == hazard_key[None, :]) & hl_[None, :]
-        hz_hit = eq.any(-1) & pend
-        kill2 = jnp.zeros_like(hl_).at[
-            jnp.where(hz_hit, jnp.argmax(eq, axis=-1), ch)].set(
-            True, mode="drop")
-        fb_ns, ok_n = ref.probe_delete_ref(new_tables[0], new_tables[1],
-                                           ns_, h0ns, qks, pend & ~hz_hit,
-                                           max_probes)
-        return fb_os, fb_ns, hl_ & ~kill2, ok | ok_o | hz_hit | ok_n
-
-    old_state, new_state, hz_live, ok_s = jax.lax.cond(
-        need.any(), fallback, lambda op: op,
-        (old_state, new_state, hz_live, ok_s))
-
-    ok = jnp.zeros((q,), jnp.bool_).at[order].set(ok_s[:q])
-    return old_state, new_state, hz_live, ok
-
-
-@partial(jax.jit, static_argnames=("chunk", "interpret"))
-def extract_chunk_fused(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
-                        cursor: jax.Array, *, chunk: int,
-                        interpret: bool = True):
-    """Rebuild chunk scan via the extract kernel: ONE pallas_call reads the
-    slab window at ``cursor`` and compacts the live entries on-device; ONE
-    scatter marks them MIGRATED.  Requires ``chunk <= SLAB`` (the caller
-    gates; dhash chunks default to 256).
-
-    Returns (tstate', hkeys[chunk], hvals[chunk], hlive[chunk] bool,
-    new_cursor) — identical set contents to the jnp scan, with the hazard
-    entries compacted to the front.
-    """
-    assert chunk <= SLAB, f"chunk {chunk} exceeds slab window {SLAB}"
-    c = tkey.shape[0]
-    cpad = -(-c // SLAB) * SLAB + SLAB
-    tk, tv, ts = (_pad_to(a, cpad) for a in (tkey, tval, tstate))
-    block = jnp.minimum(cursor // SLAB, cpad // SLAB - 2).astype(I32)
-
-    hk, hv, hl, mig = extract_tiles(tk, tv, ts, block, cursor, chunk=chunk,
-                                    capacity=c, interpret=interpret)
-
-    pos = cursor + jnp.arange(chunk, dtype=I32)
-    tstate2 = tstate.at[jnp.where(mig != 0, pos, c)].set(
-        MIGRATED, mode="drop")
-    new_cursor = jnp.minimum(cursor + chunk, c).astype(I32)
-    return tstate2, hk, hv, hl != 0, new_cursor
-
-
 # ---------------------------------------------------------------------------
 # twochoice: both row choices expand into one sorted entry batch
 # ---------------------------------------------------------------------------
@@ -532,10 +362,9 @@ def _tc_expand_sort(rows_a, rows_b, bpad: int, slab_r: int, *arrays):
     return order, epad, rs, sorted_arrays, slab_base
 
 
-@partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def twochoice_lookup(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
-                     rows_a: jax.Array, rows_b: jax.Array, qkey: jax.Array,
-                     *, interpret: bool = True):
+                     rows_a: jax.Array, rows_b: jax.Array, qkey: jax.Array):
     """Fused twochoice lookup: the 2Q entry expansion (each query's two row
     choices), ONE argsort keyed on the row index, ONE pallas_call of the
     W-wide row-gather kernel, then a per-query recombine (a-row priority —
@@ -553,7 +382,7 @@ def twochoice_lookup(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
         rows_a, rows_b, tk.shape[0], slab_r, qkey)
 
     found_s, val_s, loc_s, complete_s = tc_lookup_tiles(
-        tk, tv, ts, rs, qks, slab_base, interpret=interpret)
+        tk, tv, ts, rs, qks, slab_base)
 
     need = ~complete_s
 
@@ -576,11 +405,11 @@ def twochoice_lookup(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
     return found, val, loc
 
 
-@partial(jax.jit, static_argnames=("max_rounds", "interpret"))
+@partial(jax.jit, static_argnames=("max_rounds"))
 def twochoice_insert(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
                      rows_a: jax.Array, rows_b: jax.Array, keys: jax.Array,
                      vals: jax.Array, mask: jax.Array, *,
-                     max_rounds: int = 8, interpret: bool = True):
+                     max_rounds: int = 8):
     """Batched twochoice INSERT via the claim kernel + one scatter.
 
     Caller contract: ``mask`` is winner-filtered.  Set semantics: ok=False
@@ -603,7 +432,7 @@ def twochoice_insert(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
     qms = _pad_to(jnp.concatenate([mask, mask])[order], epad, fill=False)
 
     present_s, claim_s, complete_s = tc_insert_tiles(
-        tk, ts, rs, qks, qms.astype(I32), slab_base, interpret=interpret)
+        tk, ts, rs, qks, qms.astype(I32), slab_base)
 
     pe = jnp.zeros((e,), jnp.bool_).at[order].set(present_s[:e])
     ce = jnp.full((e,), -1, I32).at[order].set(claim_s[:e])
@@ -639,10 +468,10 @@ def twochoice_insert(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
     return tkey2, tval2, tstate2, ok
 
 
-@partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def twochoice_delete(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
                      rows_a: jax.Array, rows_b: jax.Array, keys: jax.Array,
-                     mask: jax.Array, *, interpret: bool = True):
+                     mask: jax.Array):
     """Batched twochoice DELETE: reuses the fused lookup's location output —
     one kernel pass, one tombstone scatter, never a second probe (the jnp
     ``twochoice_delete`` re-gathers both rows to find the slot again).
@@ -651,7 +480,7 @@ def twochoice_delete(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
     """
     b, w = tkey.shape
     found, _val, loc = twochoice_lookup(tkey, tval, tstate, rows_a, rows_b,
-                                        keys, interpret=interpret)
+                                        keys)
     ok = mask & found
     tstate2 = tstate.reshape(-1).at[jnp.where(ok, loc, b * w)].set(
         TOMB, mode="drop").reshape(b, w)
@@ -664,7 +493,7 @@ def twochoice_delete(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
 
 def _tc_probe2_run(old_t, new_t, hazard_key, hazard_val, hazard_live,
                    rows_a_old, rows_b_old, rows_a_new, rows_b_new, keys,
-                   interpret: bool, nres_cap: int = NRES_CAP):
+                   nres_cap: int = NRES_CAP):
     """Shared prep + launch for the fused twochoice rebuild-epoch ops: the
     2Q entry expansion (each query's two row choices, paired old/new), ONE
     argsort keyed on the OLD row, the two-level resident map for the new
@@ -694,8 +523,7 @@ def _tc_probe2_run(old_t, new_t, hazard_key, hazard_val, hazard_live,
                                         nres)])
 
     outs = tc_probe2_tiles(old_p, new_p, hazard_key, hazard_val,
-                           hazard_live.astype(I32), ors, nrs, qks, slab2,
-                           interpret=interpret)
+                           hazard_live.astype(I32), ors, nrs, qks, slab2)
     unsorted = tuple(jnp.zeros((e,), o.dtype).at[order].set(o[:e])
                      for o in outs)
     return unsorted
@@ -722,11 +550,10 @@ def _tc_ordered_combine(outs, hazard_key, hazard_val, q: int):
     return fo, vo, lo, f_hz, hzq, v_hz, fn, vn, ln, complete
 
 
-@partial(jax.jit, static_argnames=("interpret", "nres_cap"))
+@partial(jax.jit, static_argnames=("nres_cap"))
 def twochoice_ordered_lookup(old_t, new_t, hazard_key, hazard_val,
                              hazard_live, rows_a_old, rows_b_old,
                              rows_a_new, rows_b_new, qkey, *,
-                             interpret: bool = True,
                              nres_cap: int = NRES_CAP):
     """FUSED twochoice rebuild-epoch lookup: ONE argsort (the 2Q entry batch
     keyed on the old table's row index) + ONE pallas_call emit the
@@ -739,7 +566,7 @@ def twochoice_ordered_lookup(old_t, new_t, hazard_key, hazard_val,
     q = qkey.shape[0]
     outs = _tc_probe2_run(old_t, new_t, hazard_key, hazard_val, hazard_live,
                           rows_a_old, rows_b_old, rows_a_new, rows_b_new,
-                          qkey, interpret, nres_cap)
+                          qkey, nres_cap)
     (fo, vo, _lo, f_hz, _hzq, v_hz, fn, vn, _ln,
      complete) = _tc_ordered_combine(outs, hazard_key, hazard_val, q)
     found = (fo | f_hz | fn) & complete
@@ -768,11 +595,10 @@ def twochoice_ordered_lookup(old_t, new_t, hazard_key, hazard_val,
     return jax.lax.cond(need.any(), fallback, lambda fv: fv, (found, val))
 
 
-@partial(jax.jit, static_argnames=("interpret", "nres_cap"))
+@partial(jax.jit, static_argnames=("nres_cap"))
 def twochoice_ordered_delete(old_t, new_t, hazard_key, hazard_val,
                              hazard_live, rows_a_old, rows_b_old,
                              rows_a_new, rows_b_new, keys, mask, *,
-                             interpret: bool = True,
                              nres_cap: int = NRES_CAP):
     """FUSED twochoice rebuild-epoch delete (paper Alg. 5): the SAME single
     probe2-style pass as the ordered lookup resolves old-slot / hazard-index
@@ -786,7 +612,7 @@ def twochoice_ordered_delete(old_t, new_t, hazard_key, hazard_val,
     q = keys.shape[0]
     outs = _tc_probe2_run(old_t, new_t, hazard_key, hazard_val, hazard_live,
                           rows_a_old, rows_b_old, rows_a_new, rows_b_new,
-                          keys, interpret, nres_cap)
+                          keys, nres_cap)
     (fo, _vo, lo, f_hz, hzq, _vhz, fn, _vn, ln,
      complete) = _tc_ordered_combine(outs, hazard_key, hazard_val, q)
 
@@ -879,7 +705,7 @@ def _chain_dirty_window(arena, sorted_upto, dirty, qkey,
     return hit, val, loc, covered
 
 
-def _chain_run(arena, seg, bq, qkey, max_chain: int, interpret: bool,
+def _chain_run(arena, seg, bq, qkey, max_chain: int,
                dirty_cap: int = DIRTY_CAP):
     """Shared prep + launch for the single-arena chain ops: the ONE sort
     (stable argsort on the bucket — ``bstart`` is nondecreasing in the
@@ -901,8 +727,7 @@ def _chain_run(arena, seg, bq, qkey, max_chain: int, interpret: bool,
     slab_base = _tile_base(h0s, tiles, tk.shape[0])
 
     f_s, v_s, l_s, c_s = chain_probe_tiles(
-        tk, tv, ts, h0s, qls, qks, slab_base, max_probes=max_chain,
-        interpret=interpret)
+        tk, tv, ts, h0s, qls, qks, slab_base, max_probes=max_chain)
 
     fw, vw, lw, covered = _chain_dirty_window(arena, sorted_upto, dirty, qks,
                                               dirty_cap)
@@ -915,9 +740,9 @@ def _chain_run(arena, seg, bq, qkey, max_chain: int, interpret: bool,
     return order, (qks, bqs), (found_s, val_s, loc_s, need_s)
 
 
-@partial(jax.jit, static_argnames=("max_chain", "interpret", "dirty_cap"))
+@partial(jax.jit, static_argnames=("max_chain", "dirty_cap"))
 def chain_lookup_fused(arena, links, seg, bq, qkey, *, max_chain: int = 64,
-                       interpret: bool = True, dirty_cap: int = DIRTY_CAP):
+                       dirty_cap: int = DIRTY_CAP):
     """Fused chain lookup: ONE argsort + ONE chain-probe pallas_call over
     the bucket-sorted segments, a dense dirty-tail window, and the
     pointer-chasing jnp reference as the gated fallback for unresolved
@@ -925,7 +750,7 @@ def chain_lookup_fused(arena, links, seg, bq, qkey, *, max_chain: int = 64,
     is reused by the fused delete so deleting never probes twice."""
     q = qkey.shape[0]
     order, (qks, bqs), (found_s, val_s, loc_s, need_s) = _chain_run(
-        arena, seg, bq, qkey, max_chain, interpret, dirty_cap)
+        arena, seg, bq, qkey, max_chain, dirty_cap)
 
     def fallback(fvl):
         f0, v0, l0 = fvl
@@ -943,9 +768,9 @@ def chain_lookup_fused(arena, links, seg, bq, qkey, *, max_chain: int = 64,
     return found, val, loc
 
 
-@partial(jax.jit, static_argnames=("max_chain", "interpret", "dirty_cap"))
+@partial(jax.jit, static_argnames=("max_chain", "dirty_cap"))
 def chain_delete_fused(arena, links, seg, bq, keys, mask, *,
-                       max_chain: int = 64, interpret: bool = True,
+                       max_chain: int = 64,
                        dirty_cap: int = DIRTY_CAP):
     """Fused chain delete: the location-emitting probe run + ONE tombstone
     scatter (logical deletion; compaction reclaims).  Caller contract:
@@ -954,7 +779,7 @@ def chain_delete_fused(arena, links, seg, bq, keys, mask, *,
     q = keys.shape[0]
     qpad = -(-q // QT) * QT
     order, (qks, bqs), (found_s, _val_s, loc_s, need_s) = _chain_run(
-        arena, seg, bq, keys, max_chain, interpret, dirty_cap)
+        arena, seg, bq, keys, max_chain, dirty_cap)
     qms = _pad_to(mask[order], qpad, fill=False)
 
     ok_s = qms & found_s
@@ -975,10 +800,9 @@ def chain_delete_fused(arena, links, seg, bq, keys, mask, *,
     return astate2, ok
 
 
-@partial(jax.jit, static_argnames=("max_chain", "interpret", "dirty_cap"))
+@partial(jax.jit, static_argnames=("max_chain", "dirty_cap"))
 def chain_insert_fused(arena, links, seg, free_stack, free_top, bq, keys,
-                       vals, mask, *, max_chain: int = 64,
-                       interpret: bool = True, dirty_cap: int = DIRTY_CAP):
+                       vals, mask, *, max_chain: int = 64, dirty_cap: int = DIRTY_CAP):
     """Fused chain insert: the presence probe (kernel + dirty window +
     gated pointer fallback) and the head relink share the SAME stable sort
     keyed on the bucket, so the whole op is ONE argsort + ONE pallas_call.
@@ -996,7 +820,7 @@ def chain_insert_fused(arena, links, seg, free_stack, free_top, bq, keys,
     nb = heads.shape[0]
     q = keys.shape[0]
     order, (qks, bqs), (found_s, _v, _l, need_s) = _chain_run(
-        arena, seg, bq, keys, max_chain, interpret, dirty_cap)
+        arena, seg, bq, keys, max_chain, dirty_cap)
 
     def fb_present(p):
         fb_f, _, _ = ref.chain_lookup_ref(akey, aval, astate, anext, heads,
@@ -1084,7 +908,7 @@ def chain_compact_fused(akey, aval, astate, bq_nodes, *, nbuckets: int):
 
 def _chain_probe2_run(old_arena, old_seg, new_arena, new_seg, hazard_key,
                       hazard_val, hazard_live, bq_old, bq_new, keys,
-                      max_chain: int, interpret: bool,
+                      max_chain: int,
                       nres_cap: int = NRES_CAP, dirty_cap: int = DIRTY_CAP):
     """Shared prep + launch for the fused chain rebuild-epoch ops: the ONE
     argsort (keyed on the old arena's segment starts), the two-level tile
@@ -1114,8 +938,7 @@ def _chain_probe2_run(old_arena, old_seg, new_arena, new_seg, hazard_key,
 
     (f_o, v_o, l_o, c_o, hz, f_n, v_n, l_n, c_n) = chain_probe2_tiles(
         old_p, new_p, hazard_key, hazard_val, hazard_live.astype(I32),
-        h0os, qlos, h0ns, qlns, qks, slab2, max_probes=max_chain,
-        interpret=interpret)
+        h0os, qlos, h0ns, qlns, qks, slab2, max_probes=max_chain)
 
     fwo, vwo, lwo, cov_o = _chain_dirty_window(old_arena, old_seg[2],
                                                old_seg[3], qks, dirty_cap)
@@ -1140,12 +963,12 @@ def _chain_probe2_run(old_arena, old_seg, new_arena, new_seg, hazard_key,
                                       ln, complete)
 
 
-@partial(jax.jit, static_argnames=("max_chain", "interpret", "nres_cap",
+@partial(jax.jit, static_argnames=("max_chain", "nres_cap",
                                    "dirty_cap"))
 def chain_ordered_lookup(old_arena, old_links, old_seg, new_arena, new_links,
                          new_seg, hazard_key, hazard_val, hazard_live,
                          bq_old, bq_new, qkey, *, max_chain: int = 64,
-                         interpret: bool = True, nres_cap: int = NRES_CAP,
+                         nres_cap: int = NRES_CAP,
                          dirty_cap: int = DIRTY_CAP):
     """FUSED chain rebuild-epoch lookup: ONE argsort + ONE chain_probe2
     pallas_call emit the Lemma-4.1-ordered result (old arena -> hazard
@@ -1156,7 +979,7 @@ def chain_ordered_lookup(old_arena, old_links, old_seg, new_arena, new_links,
     q = qkey.shape[0]
     order, (qks, bqos, bqns), comps = _chain_probe2_run(
         old_arena, old_seg, new_arena, new_seg, hazard_key, hazard_val,
-        hazard_live, bq_old, bq_new, qkey, max_chain, interpret,
+        hazard_live, bq_old, bq_new, qkey, max_chain,
         nres_cap, dirty_cap)
     (fo, vo, _lo, f_hz, _hz, v_hz, fn, vn, _ln, complete) = comps
     found_s = (fo | f_hz | fn) & complete
@@ -1181,12 +1004,12 @@ def chain_ordered_lookup(old_arena, old_links, old_seg, new_arena, new_links,
     return found, val
 
 
-@partial(jax.jit, static_argnames=("max_chain", "interpret", "nres_cap",
+@partial(jax.jit, static_argnames=("max_chain", "nres_cap",
                                    "dirty_cap"))
 def chain_ordered_delete(old_arena, old_links, old_seg, new_arena, new_links,
                          new_seg, hazard_key, hazard_val, hazard_live,
                          bq_old, bq_new, keys, mask, *, max_chain: int = 64,
-                         interpret: bool = True, nres_cap: int = NRES_CAP,
+                         nres_cap: int = NRES_CAP,
                          dirty_cap: int = DIRTY_CAP):
     """FUSED chain rebuild-epoch delete (paper Alg. 5): the SAME single
     chain_probe2 pass resolves old-node / hazard-index / new-node, then
@@ -1201,7 +1024,7 @@ def chain_ordered_delete(old_arena, old_links, old_seg, new_arena, new_links,
     qpad = -(-q // QT) * QT
     order, (qks, bqos, bqns), comps = _chain_probe2_run(
         old_arena, old_seg, new_arena, new_seg, hazard_key, hazard_val,
-        hazard_live, bq_old, bq_new, keys, max_chain, interpret,
+        hazard_live, bq_old, bq_new, keys, max_chain,
         nres_cap, dirty_cap)
     (fo, _vo, lo, f_hz, hz, _vhz, fn, _vn, ln, complete) = comps
     qms = _pad_to(mask[order], qpad, fill=False)

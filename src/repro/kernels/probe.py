@@ -1,108 +1,50 @@
-"""Pallas TPU kernels: batched probing over VMEM-resident table slabs.
+"""Pallas TPU kernels: batched probing of hash tables in VMEM.
 
 TPU adaptation of the paper's hot paths.  On CPUs the per-op cost at load
 factor alpha is pointer chasing; on TPU the equivalent hot loop is the probe
-sequence, and the roofline term is HBM traffic: a naive gather streams table
-lines per query.  Every kernel here restructures the access pattern the same
-way (HashGraph-style sorted/coalesced probing):
+sequence, and the roofline term is HBM traffic.
 
-  1. ops.py sorts the query batch by start slot h0 (ONE XLA sort per batch),
-     so each query tile touches a *contiguous slab* of the table;
-  2. a scalar-prefetch BlockSpec (`pltpu.PrefetchScalarGridSpec`) picks the
-     two consecutive table blocks covering the tile's slab — data-dependent
-     block indexing, the canonical TPU pattern for sorted gathers;
-  3. the probe loop then runs entirely in VMEM on the VPU: each of the
-     ``max_probes`` rounds is a vectorized compare of the query tile against
-     dynamically-indexed slab lanes.
+**Where the kernels run.**  ``pallas_call`` below is the only launcher: it
+reads the JAX platform when the op is traced.  The TPU compiles every kernel
+with Mosaic; the CPU emulates the same kernels (tests, CI); any other
+platform raises.  No caller chooses.
 
-Nine kernels share that skeleton:
+**Linear probing (the default backend): per-query windows.**  ops.py
+gathers every query's probe window (``kernels/window.py``: the rows of the
+table, viewed as ``[C/128, 128]``, that hold ``h0 .. h0+max_probes-1``),
+and these kernels evaluate the window math of ``window.py`` over dense
+``[WQT, W]`` tiles — the same functions the jnp op set runs as plain XLA.
+The window covers the whole probe bound, so they are exact and never
+partial.  Four kernels:
 
-* ``_probe_kernel``        — single-table lookup (steady state, no rebuild).
-  Emits per-query slot LOCATIONS alongside found/val, so the delete path
-  (``ops.probe_delete``) tombstones with one scatter — lookup and delete are
-  the same single pass.
-* ``_probe2_kernel``       — the fused **rebuild-epoch** lookup: ONE pass
-  emits the paper's Lemma-4.1-ordered result (old table -> hazard buffer ->
-  new table).  One shared query sort keyed on ``h0_old`` drives the
-  old-table slab selection; the new table gets a **two-level tile map**
-  instead of a second sort: a first-level jnp pass (ops.py) buckets each
-  tile's new-table windows into ``nres`` resident blocks (per-tile
-  histogram + top_k — no extra sort), the scalar-prefetch operand becomes a
-  ``[1 + nres, tiles]`` block map (row 0 = old-table slab, rows 1.. = the
-  tile's resident new-table blocks), and the kernel grid is
-  ``(tiles, nres)``: iteration ``(i, r)`` probes tile ``i`` against resident
-  new block ``r`` and REDUCES hits into the revisited output block
-  (``r == 0`` initialises old + hazard + first new window, ``r > 0`` merges
-  further new windows).  Growth-heavy rebuilds (new table many slabs long)
-  therefore stay fused instead of escaping to the jnp fallback.  The hazard
-  buffer is broadcast whole into VMEM for a dense tile-vs-chunk compare.
-  The same pass also emits the ordered DELETE outputs — old hit flag +
-  slot, hazard index, new slot — so ``ops.ordered_delete_fused`` lands
-  old-tombstone / hazard-kill / new-tombstone without a second probe.
-* ``_tc_probe2_kernel``    — the same treatment for ``twochoice``: each
-  query's two row choices expand into two entries of ONE batch sorted by the
-  OLD table's row index; iteration ``(i, r)`` gathers each entry's resident
-  old row, runs the dense hazard compare, and merges the entry's new-table
-  row from the tile's ``nres`` resident new row-blocks — the whole
-  rebuild-epoch ordered lookup/delete for twochoice is one sort + one
-  pallas_call (it previously composed two fused single-table passes).
-* ``_probe_insert_kernel`` — batched linear-probe INSERT (claim-first-empty):
-  phase 1 re-proves absence against the original slab states, phase 2 runs
-  the claim loop on a local VMEM copy of the slab states (lowest in-tile
-  query index wins a contested slot; claimed slots flip LIVE locally so later
-  rounds skip them).  The kernel emits *claim positions*; ops.py applies them
-  with one scatter and resolves cross-tile collisions there.  The rebuild's
-  hazard LANDING is this same kernel (dhash routes it through the fused
-  insert), so the whole epoch stays on-device.
-* ``_extract_kernel``      — the rebuild chunk scan: reads the 2-block slab
-  window holding ``cursor``, COMPACTS the live entries of the chunk to the
-  front of the hazard outputs on-device (cumsum rank + local scatter), and
-  emits the position-aligned MIGRATED mask that ops.py lands with one
-  scatter.  Contract: ``chunk <= SLAB``; slots at/past the unpadded capacity
-  never migrate; no sort needed (the window is already contiguous).
-* ``_tc_lookup_kernel`` / ``_tc_insert_kernel`` — the ``twochoice`` backend
-  on the same treatment: each query's TWO row choices expand into two
-  entries of ONE batch sorted by row index; row blocks are ``[SLAB_R, W]``
-  with ``SLAB_R * W = SLAB`` words.  Lookup gathers each entry's resident
-  row and compares all W lanes at once (emitting flat slot locations the
-  fused twochoice delete reuses — never a second probe); insert runs the
-  same local-claim protocol as the linear kernel, one lane per round, and
-  ops.py drops b-claims shadowed by a-claims before resolving cross-tile
-  collisions.
-* ``_chain_probe_kernel`` / ``_chain_probe2_kernel`` — the ``chain``
-  backend over its ARENA-SORTED layout (the last backend onto the fused
-  path): ``ops.chain_compact_fused`` keeps the node arena bucket-sorted and
-  tombstone-compacted, so a chain probe is a segment window
-  ``[bstart[b], bstart[b] + blen[b])`` — the same slab reduction as a
-  linear probe, terminated by the per-query segment LENGTH instead of an
-  EMPTY sentinel (the packed arena has none; cross-segment reads cannot
-  false-match because a key's bucket is a function of the key).
-  ``_chain_probe2_kernel`` is the rebuild-epoch single pass (old segments +
-  dense hazard compare + new segments on the same ``(tiles, nres)``
-  reduction grid, component outputs for the ordered delete).  Nodes
-  inserted since the last compaction live in a contiguous dirty tail that
-  ops.py resolves with a dense window compare — the hazard-buffer
-  treatment — and a tail grown past ``ops.DIRTY_CAP`` escapes to the
-  pointer-chasing jnp reference via the gated fallback.
+* ``_probe_kernel``   — single-table lookup; emits the hit's window lane
+  (-1 = absent).  ops.py turns the lane into the slot, reads the value with
+  one element gather, and the delete path tombstones that slot.
+* ``_probe2_kernel``  — the fused **rebuild-epoch** probe: old-table window,
+  dense hazard-buffer compare, new-table window in ONE pass (the paper's
+  Lemma-4.1 order old -> hazard -> new is applied by ops.py to the three
+  outputs, which serve both the ordered lookup and the ordered delete).
+* ``_claim_kernel``   — the insert's claim pass: presence and the first
+  non-LIVE lane of the window (``window.insert`` settles claims on one
+  slot and lets the losers claim again).
+* ``_extract_kernel`` — the rebuild chunk scan: LIVE mask of the chunk and
+  each live entry's rank among them (prefix counts as two small triangular
+  matmuls), which ops.py uses to compact the hazard entries with one
+  scatter.
 
-Exactness contract (all kernels): a query whose probe window escapes its
-2-block slab (hash skew), or whose new-table window misses ALL of its
-tile's resident blocks (new table grown past the tile map's ``nres``
-coverage), or whose claimed slot collides across tiles, raises
-``complete=False`` / a conflict flag and is re-run by the jnp fallback in
-ops.py — the kernels are exact, never wrong, occasionally partial.
+**twochoice / cuckoo / chain: sorted-slab kernels.**  These backends keep
+the older skeleton: ops.py sorts the batch, a scalar-prefetch BlockSpec
+picks the two table blocks covering each ``QT``-query tile's slab, and the
+probe loop gathers per-query values from that VMEM window.  Queries whose
+window escapes the slab fall back to the jnp oracle.  Mosaic refuses their
+per-query 1-D gathers, so they run on the CPU only; on a TPU ``dhash.make``
+refuses these backends with ``fused=True`` (``backend.BucketBackend.
+tpu_refusal``).
 
-VMEM budget (v5e ~16 MiB/core): query tile QT=1024 (8x128 vregs, 3 x 4 KiB),
-slab block SLAB=4096 i32 words.  Single-table lookup holds 2 blocks x 3
-arrays x 16 KiB = 96 KiB.  The fused probe2 doubles the table residency
-(old + new = 192 KiB) and adds the hazard buffer (3 x chunk x 4 B; 48 KiB at
-chunk=4096) plus the dense compare intermediate QT x chunk bools (4 MiB at
-chunk=4096 before vreg tiling) — keep ``chunk <= 4096`` to stay well inside
-VMEM.  The two-level tile map does NOT grow residency: only one resident
-new-table block pair is in VMEM per ``(tile, r)`` grid step; the ``nres``
-axis trades grid steps (and VPU probe rounds) for coverage.  Insert holds 2 key blocks + 2 state blocks + a 2*SLAB local state
-copy = 96 KiB.  The MXU is idle throughout (VPU/memory kernels), so the
-matmul pipeline of a co-scheduled layer is undisturbed.
+VMEM budget (v5e, 16 MiB scoped): a probe2 tile holds four ``[WQT, W]`` i32
+windows (256 KiB each at ``max_probes <= 129``, double-buffered 2 MiB), the
+hazard buffer as ``[1, CH]`` rows (128 KiB each at ``CH = 4096``) and
+``[WQT, HZB]`` compare intermediates (512 KiB each).
 """
 from __future__ import annotations
 
@@ -113,420 +55,185 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import window
+from repro.kernels.window import LANES
+
 I32 = jnp.int32
 EMPTY, LIVE = 0, 1
 
-QT = 1024     # queries per tile
-SLAB = 4096   # table words per block (2 consecutive blocks resident)
+WQT = 256     # queries per tile of the windowed kernels
+HZB = 512     # hazard entries compared per inner step of the probe2 kernel
+QT = 1024     # queries per tile of the sorted-slab kernels
+SLAB = 4096   # table words per block (2 consecutive blocks resident); also
+              # the largest rebuild chunk a fused table takes
+
+# Resident new-table blocks per query tile in the sorted-slab rebuild-epoch
+# probes (the second level of the two-level tile map).  16 block pairs cover
+# a new table of up to ~16 SLABs (64K slots) COMPLETELY; beyond that, the
+# least-populated blocks of a tile overflow to the gated jnp fallback.  This
+# is the DEFAULT of the ``nres_cap`` parameter the rebuild-epoch ops accept;
+# the per-backend value lives on the ``BucketBackend`` descriptor
+# (core/backend.py) and is threaded through ``dhash.make()``.
+NRES_CAP = 16
+
+# Dirty-tail window of the arena-sorted chain backend: nodes inserted since
+# the last compaction live in a contiguous tail, resolved by a dense window
+# compare (the hazard-buffer treatment).  A tail grown past DIRTY_CAP is no
+# longer fully visible to the window, so the fused chain ops escape to the
+# pointer-chasing jnp reference — ``backend.chain_maybe_compact`` re-sorts
+# the arena at exactly this threshold to keep the steady state on-kernel.
+# Like NRES_CAP this is only the DEFAULT of the ``dirty_cap`` parameter;
+# the live value is a ``BucketBackend`` descriptor field.
+DIRTY_CAP = 512
 
 
-def _window_probe(base_blk, h0, qk, k0, k1, v0, v1, s0, s1, max_probes: int):
-    """Shared probe loop over one 2-block VMEM window.
-
-    Returns (found, val, loc, complete); found/val/loc are gated to
-    False/0/-1 for incomplete queries (probe window escapes the resident
-    window).  ``loc`` is the padded-table coordinate of the LIVE hit (the
-    slot the delete path tombstones), -1 when the key was not found.
-    """
-    base = base_blk * SLAB
-    off = h0 - base                               # [QT] offset into 2*SLAB
-    keys = jnp.concatenate([k0[...], k1[...]])    # [2*SLAB]
-    vals = jnp.concatenate([v0[...], v1[...]])
-    stat = jnp.concatenate([s0[...], s1[...]])
-
-    # a probe sequence is complete iff it fits the resident window
-    complete = (off >= 0) & (off + max_probes <= 2 * SLAB)
-    safe_off = jnp.clip(off, 0, 2 * SLAB - max_probes)
-
-    def body(p, carry):
-        active, found, val, loc = carry
-        idx = safe_off + p
-        k = jnp.take(keys, idx, axis=0)
-        v = jnp.take(vals, idx, axis=0)
-        s = jnp.take(stat, idx, axis=0)
-        hit = active & (s == LIVE) & (k == qk)
-        stop = active & (s == EMPTY)
-        val = jnp.where(hit, v, val)
-        loc = jnp.where(hit, base + idx, loc)
-        found = found | hit
-        active = active & ~hit & ~stop
-        return active, found, val, loc
-
-    q = h0.shape[0]
-    init = (jnp.ones((q,), bool), jnp.zeros((q,), bool),
-            jnp.zeros((q,), I32), jnp.full((q,), -1, I32))
-    _, found, val, loc = jax.lax.fori_loop(0, max_probes, body, init)
-    return (found & complete, jnp.where(complete, val, 0),
-            jnp.where(complete, loc, -1), complete)
+def platform() -> str:
+    """The platform the kernels are traced for: ``"tpu"`` or ``"cpu"``."""
+    p = jax.default_backend()
+    if p not in ("tpu", "cpu"):
+        raise ValueError(f"no Pallas kernel path for platform {p!r}; the "
+                         f"kernels compile for TPU and run interpreted on CPU")
+    return p
 
 
-def _probe_kernel(slab_ref,              # scalar-prefetch: [tiles] block index
-                  h0_ref, qk_ref,        # [QT] query start slots / keys
-                  tk0, tk1, tv0, tv1, ts0, ts1,   # [SLAB] table key/val/state
-                  found_ref, val_ref, loc_ref, complete_ref,
-                  *, max_probes: int):
-    i = pl.program_id(0)
-    found, val, loc, complete = _window_probe(
-        slab_ref[i], h0_ref[...], qk_ref[...],
-        tk0, tk1, tv0, tv1, ts0, ts1, max_probes)
-    found_ref[...] = found
-    val_ref[...] = val
-    loc_ref[...] = loc
-    complete_ref[...] = complete
+def pallas_call(kernel, **kw):
+    """``pl.pallas_call`` compiled by Mosaic on a TPU and run by the Pallas
+    interpreter on the CPU — resolved from the platform at trace time."""
+    return pl.pallas_call(kernel, interpret=platform() == "cpu", **kw)
 
 
-def _probe2_kernel(slab2_ref,            # scalar-prefetch: [1 + nres, tiles]
-                   h0o_ref, h0n_ref, qk_ref,           # [QT]
-                   ok0, ok1, ov0, ov1, os0, os1,       # old table blocks
-                   nk0, nk1, nv0, nv1, ns0, ns1,       # new resident blocks
-                   hk_ref, hv_ref, hl_ref,             # [CH] hazard buffer
-                   found_ref, val_ref, complete_ref,
-                   fold_ref, locold_ref, hzidx_ref, locnew_ref, cold_ref,
-                   *, max_probes: int):
-    """Fused rebuild-epoch lookup: Lemma 4.1 order old -> hazard -> new in a
-    single pass.  ``complete`` is refined: a query resolved by the old table
-    or the hazard buffer is complete even if its new-table window escaped —
-    the answer is already determined by the ordered-check priority.
+def _probe_kernel(off_ref, qk_ref, kw_ref, sw_ref, hit_ref, *,
+                  max_probes: int):
+    hit_ref[...] = window.probe(kw_ref[...], sw_ref[...], off_ref[...],
+                                qk_ref[...], max_probes)[0]
 
-    Grid is ``(tiles, nres)``: the second axis walks the tile's resident
-    new-table blocks (two-level tile map, rows 1.. of ``slab2``).  The
-    output block index depends only on the tile, so iterations ``r > 0``
-    revisit the same output block and REDUCE into it: ``r == 0`` computes
-    the old-table probe, the hazard compare, and the first new window;
-    later iterations merge further new windows (a query's window matches at
-    most one distinct resident block, so OR/max/where merges are exact).
-    ``c_old`` is emitted so the merge rounds (and ops.py) can extend
-    ``complete`` without re-probing the old table.
 
-    Beyond found/val the kernel emits the WRITE-PATH outputs the ordered
-    delete needs to tombstone in the same pass: the old-table hit flag and
-    slot location, the hazard-buffer index of a live key match (-1 if none),
-    and the new-table slot location (-1 when absent or the new-table window
-    escaped).  found/val are NOT gated by ``complete`` here — ops.py's gated
-    fallback overwrites every incomplete query anyway."""
-    i = pl.program_id(0)
-    r = pl.program_id(1)
+def _claim_kernel(off_ref, qk_ref, kw_ref, sw_ref, hit_ref, free_ref, *,
+                  max_probes: int):
+    """Insert claim pass: the presence probe plus the first non-LIVE lane of
+    the window (``window.claim``)."""
+    hit_ref[...], free_ref[...] = window.claim(
+        kw_ref[...], sw_ref[...], off_ref[...], qk_ref[...], max_probes)
+
+
+def _probe2_kernel(offo_ref, offn_ref, qk_ref, kwo_ref, swo_ref, kwn_ref,
+                   swn_ref, hk_ref, hl_ref, hito_ref, hz_ref, hitn_ref, *,
+                   max_probes: int):
+    """Fused rebuild-epoch probe: the old-table window, the hazard buffer
+    (dense compare, ``HZB`` entries per step, first live match), and the
+    new-table window — three outputs, one pass."""
     qk = qk_ref[...]
-    f_new, v_new, l_new, c_new = _window_probe(
-        slab2_ref[1 + r, i], h0n_ref[...], qk,
-        nk0, nk1, nv0, nv1, ns0, ns1, max_probes)
+    hito_ref[...] = window.probe(kwo_ref[...], swo_ref[...], offo_ref[...],
+                                 qk, max_probes)[0]
+    hitn_ref[...] = window.probe(kwn_ref[...], swn_ref[...], offn_ref[...],
+                                 qk, max_probes)[0]
+    ch = hk_ref.shape[1]
+    hb = min(ch, HZB)
 
-    @pl.when(r == 0)
-    def _init():
-        f_old, v_old, l_old, c_old = _window_probe(
-            slab2_ref[0, i], h0o_ref[...], qk,
-            ok0, ok1, ov0, ov1, os0, os1, max_probes)
-        # hazard buffer: dense [QT, CH] compare, whole chunk resident in VMEM
-        eq = (qk[:, None] == hk_ref[...][None, :]) & (hl_ref[...][None, :] != 0)
-        f_hz = eq.any(-1)
-        hz_i = jnp.argmax(eq, axis=-1)
-        v_hz = jnp.take(hv_ref[...], hz_i, axis=0)
+    def hazard(c, best):
+        start = pl.multiple_of(c * hb, hb)
+        k = hk_ref[:, pl.ds(start, hb)]
+        live = hl_ref[:, pl.ds(start, hb)]
+        idx = start + jax.lax.broadcasted_iota(I32, (1, hb), 1)
+        return jnp.minimum(best,
+                           window.first((qk == k) & (live != 0), idx, ch))
 
-        found_ref[...] = f_old | f_hz | f_new
-        val_ref[...] = jnp.where(
-            f_old, v_old, jnp.where(f_hz, v_hz, jnp.where(f_new, v_new, 0)))
-        complete_ref[...] = c_old & (f_old | f_hz | c_new)
-        fold_ref[...] = f_old
-        locold_ref[...] = l_old
-        hzidx_ref[...] = jnp.where(f_hz, hz_i.astype(I32), -1)
-        locnew_ref[...] = l_new   # already -1 when absent or window escaped
-        cold_ref[...] = c_old
-
-    @pl.when(r > 0)
-    def _merge():
-        resolved = found_ref[...]
-        found_ref[...] = resolved | f_new
-        val_ref[...] = jnp.where(f_new & ~resolved, v_new, val_ref[...])
-        complete_ref[...] = complete_ref[...] | (cold_ref[...] & c_new)
-        locnew_ref[...] = jnp.maximum(locnew_ref[...], l_new)
+    best = jax.lax.fori_loop(0, ch // hb, hazard,
+                             jnp.full(qk.shape, ch, I32))
+    hz_ref[...] = jnp.where(best < ch, best, -1)
 
 
-def _probe_insert_kernel(slab_ref,           # scalar-prefetch: [tiles]
-                         h0_ref, qk_ref, qm_ref,       # [QT] (qm: i32 mask)
-                         tk0, tk1, ts0, ts1,           # [SLAB] key/state
-                         present_ref, claim_ref, complete_ref,
-                         *, max_probes: int):
-    """Claim-first-EMPTY batched insert.  Emits per-query claim positions
-    (padded-table coordinates; -1 = no claim) instead of mutating the table;
-    ops.py scatters the claims and sends cross-tile conflicts to the jnp
-    fallback.  Caller contract: ``qm`` is winner-filtered (at most one True
-    per distinct key in the whole batch)."""
-    i = pl.program_id(0)
-    base = slab_ref[i] * SLAB
-    off = h0_ref[...] - base
-    qk = qk_ref[...]
-    qm = qm_ref[...] != 0
-    keys = jnp.concatenate([tk0[...], tk1[...]])
-    stat = jnp.concatenate([ts0[...], ts1[...]])
-
-    complete = (off >= 0) & (off + max_probes <= 2 * SLAB)
-    safe_off = jnp.clip(off, 0, 2 * SLAB - max_probes)
-
-    # phase 1: re-prove absence on the ORIGINAL slab states (same semantics
-    # as buckets.linear_insert's presence lookup before its claim loop)
-    def probe(p, carry):
-        active, present = carry
-        idx = safe_off + p
-        s = jnp.take(stat, idx, axis=0)
-        hit = active & (s == LIVE) & (jnp.take(keys, idx, axis=0) == qk)
-        stop = active & (s == EMPTY)
-        present = present | hit
-        active = active & ~hit & ~stop
-        return active, present
-
-    qn = off.shape[0]
-    _, present = jax.lax.fori_loop(0, max_probes, probe,
-                                   (qm, jnp.zeros((qn,), bool)))
-
-    # phase 2: claim loop on a LOCAL copy of the slab states; claimed slots
-    # flip LIVE locally so later rounds skip them (matches the evolving-state
-    # semantics of buckets.linear_insert); lowest in-tile index wins a slot.
-    qidx = jax.lax.broadcasted_iota(I32, (qn,), 0)
-    pending0 = qm & complete & ~present
-
-    def claim_round(p, carry):
-        st, pending, claim_rel = carry
-        pos = safe_off + p
-        free = pending & (jnp.take(st, pos, axis=0) != LIVE)
-        tgt = jnp.where(free, pos, 2 * SLAB)
-        cl = jnp.full((2 * SLAB,), qn, I32).at[tgt].min(qidx, mode="drop")
-        won = free & (jnp.take(cl, pos, axis=0) == qidx)
-        st = st.at[jnp.where(won, pos, 2 * SLAB)].set(LIVE, mode="drop")
-        claim_rel = jnp.where(won, pos, claim_rel)
-        return st, pending & ~won, claim_rel
-
-    _, _, claim_rel = jax.lax.fori_loop(
-        0, max_probes, claim_round,
-        (stat, pending0, jnp.full((qn,), -1, I32)))
-
-    present_ref[...] = present & complete
-    claim_ref[...] = jnp.where(claim_rel >= 0, base + claim_rel, -1)
-    complete_ref[...] = complete
+def _query_specs(w: int):
+    col = pl.BlockSpec((WQT, 1), lambda i: (i, 0))
+    win = pl.BlockSpec((WQT, w), lambda i: (i, 0))
+    return col, win
 
 
-def probe_lookup_tiles(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
-                       h0_sorted: jax.Array, qk_sorted: jax.Array,
-                       slab_base: jax.Array, *, max_probes: int,
-                       interpret: bool = True):
-    """Run the kernel over pre-sorted, pre-tiled queries.
-
-    tkey/tval/tstate: padded table arrays, length a multiple of SLAB and at
-    least ``max(h0)+max_probes`` (ops.py pads with a wrapped copy so probes
-    never wrap inside the kernel).
-    h0_sorted/qk_sorted: [Q] sorted by h0, Q a multiple of QT.
-    slab_base: [Q/QT] block index (h0_min of the tile // SLAB), clipped so
-    block+1 stays in range.
-
-    Returns (found[Q], val[Q], loc[Q], complete[Q]); ``loc`` is the hit's
-    padded-table coordinate (-1 if absent) — the delete path tombstones
-    ``loc % C`` with one scatter, no second probe pass.
-    """
-    q = h0_sorted.shape[0]
-    assert q % QT == 0 and tkey.shape[0] % SLAB == 0
-    tiles = q // QT
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(tiles,),
-        in_specs=[
-            pl.BlockSpec((QT,), lambda i, s: (i,)),
-            pl.BlockSpec((QT,), lambda i, s: (i,)),
-            pl.BlockSpec((SLAB,), lambda i, s: (s[i],)),
-            pl.BlockSpec((SLAB,), lambda i, s: (s[i] + 1,)),
-            pl.BlockSpec((SLAB,), lambda i, s: (s[i],)),
-            pl.BlockSpec((SLAB,), lambda i, s: (s[i] + 1,)),
-            pl.BlockSpec((SLAB,), lambda i, s: (s[i],)),
-            pl.BlockSpec((SLAB,), lambda i, s: (s[i] + 1,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((QT,), lambda i, s: (i,)),
-            pl.BlockSpec((QT,), lambda i, s: (i,)),
-            pl.BlockSpec((QT,), lambda i, s: (i,)),
-            pl.BlockSpec((QT,), lambda i, s: (i,)),
-        ],
-    )
-    out_shape = [
-        jax.ShapeDtypeStruct((q,), jnp.bool_),
-        jax.ShapeDtypeStruct((q,), I32),
-        jax.ShapeDtypeStruct((q,), I32),
-        jax.ShapeDtypeStruct((q,), jnp.bool_),
-    ]
-    kernel = functools.partial(_probe_kernel, max_probes=max_probes)
-    # each table array is passed twice: block s and block s+1 of the same
-    # buffer (XLA aliases the operand; no copy)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
-        slab_base, h0_sorted, qk_sorted, tkey, tkey, tval, tval, tstate, tstate)
+def probe_windows(off, qk, kw, sw, *, max_probes: int):
+    """Single-table probe over gathered windows.  ``off``/``qk`` [Q, 1],
+    ``kw``/``sw`` [Q, W] with Q a ``WQT`` multiple.  Returns hit lanes
+    [Q, 1] (-1 = absent)."""
+    q, w = kw.shape
+    col, win = _query_specs(w)
+    return pallas_call(
+        functools.partial(_probe_kernel, max_probes=max_probes),
+        grid=(q // WQT,), in_specs=[col, col, win, win], out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((q, 1), I32))(off, qk, kw, sw)
 
 
-def probe2_tiles(old_padded, new_padded,
-                 hazard_key: jax.Array, hazard_val: jax.Array,
-                 hazard_live_i32: jax.Array,
-                 h0o_sorted: jax.Array, h0n_sorted: jax.Array,
-                 qk_sorted: jax.Array, slab2: jax.Array, *,
-                 max_probes: int, interpret: bool = True):
-    """Fused rebuild-epoch probe over pre-sorted, pre-tiled queries.
-
-    old_padded/new_padded: (key, val, state) triples padded as in
-    ``probe_lookup_tiles`` (each table padded independently).
-    slab2: [1 + nres, tiles] i32 — row 0 old-table block, rows 1.. the
-    tile's resident new-table blocks (two-level tile map; repeat the last
-    entry to pad).  hazard_live_i32: hazard liveness as i32
-    (pallas-friendly).
-
-    Returns (found, val, complete, f_old, loc_old, hz_idx, loc_new, c_old);
-    f_old/loc_old/hz_idx/loc_new are the ordered-delete outputs (see
-    ``_probe2_kernel``).  found/val are ungated — mask with ``complete``
-    (the gated fallback in ops.py does this implicitly).
-    """
-    q = qk_sorted.shape[0]
-    (okk, ovv, oss), (nkk, nvv, nss) = old_padded, new_padded
-    assert q % QT == 0 and okk.shape[0] % SLAB == 0 and nkk.shape[0] % SLAB == 0
-    tiles = q // QT
-    nres = slab2.shape[0] - 1
-    assert nres >= 1
-    ch = hazard_key.shape[0]
-
-    qspec = pl.BlockSpec((QT,), lambda i, r, s: (i,))
-    oblk0 = pl.BlockSpec((SLAB,), lambda i, r, s: (s[0, i],))
-    oblk1 = pl.BlockSpec((SLAB,), lambda i, r, s: (s[0, i] + 1,))
-    nblk0 = pl.BlockSpec((SLAB,), lambda i, r, s: (s[1 + r, i],))
-    nblk1 = pl.BlockSpec((SLAB,), lambda i, r, s: (s[1 + r, i] + 1,))
-    hspec = pl.BlockSpec((ch,), lambda i, r, s: (0,))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(tiles, nres),
-        in_specs=[qspec, qspec, qspec,
-                  oblk0, oblk1, oblk0, oblk1, oblk0, oblk1,
-                  nblk0, nblk1, nblk0, nblk1, nblk0, nblk1,
-                  hspec, hspec, hspec],
-        out_specs=[qspec] * 8,
-    )
-    out_shape = [
-        jax.ShapeDtypeStruct((q,), jnp.bool_),    # found
-        jax.ShapeDtypeStruct((q,), I32),          # val
-        jax.ShapeDtypeStruct((q,), jnp.bool_),    # complete
-        jax.ShapeDtypeStruct((q,), jnp.bool_),    # f_old
-        jax.ShapeDtypeStruct((q,), I32),          # loc_old (padded coords)
-        jax.ShapeDtypeStruct((q,), I32),          # hazard index (-1 = none)
-        jax.ShapeDtypeStruct((q,), I32),          # loc_new (padded coords)
-        jax.ShapeDtypeStruct((q,), jnp.bool_),    # c_old (old window covered)
-    ]
-    kernel = functools.partial(_probe2_kernel, max_probes=max_probes)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
-        slab2, h0o_sorted, h0n_sorted, qk_sorted,
-        okk, okk, ovv, ovv, oss, oss,
-        nkk, nkk, nvv, nvv, nss, nss,
-        hazard_key, hazard_val, hazard_live_i32)
+def claim_windows(off, qk, kw, sw, *, max_probes: int):
+    """Insert claim pass over gathered windows.  Returns (hit, free) lanes,
+    each [Q, 1] (-1 = none)."""
+    q, w = kw.shape
+    col, win = _query_specs(w)
+    return pallas_call(
+        functools.partial(_claim_kernel, max_probes=max_probes),
+        grid=(q // WQT,), in_specs=[col, col, win, win],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((q, 1), I32)] * 2)(off, qk, kw, sw)
 
 
-def probe_insert_tiles(tkey: jax.Array, tstate: jax.Array,
-                       h0_sorted: jax.Array, qk_sorted: jax.Array,
-                       qm_sorted_i32: jax.Array, slab_base: jax.Array, *,
-                       max_probes: int, interpret: bool = True):
-    """Claim pass of the batched insert over pre-sorted, pre-tiled queries.
-
-    Returns (present[Q], claim[Q] padded-table position or -1, complete[Q]).
-    """
-    q = h0_sorted.shape[0]
-    assert q % QT == 0 and tkey.shape[0] % SLAB == 0
-    tiles = q // QT
-
-    qspec = pl.BlockSpec((QT,), lambda i, s: (i,))
-    blk0 = pl.BlockSpec((SLAB,), lambda i, s: (s[i],))
-    blk1 = pl.BlockSpec((SLAB,), lambda i, s: (s[i] + 1,))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(tiles,),
-        in_specs=[qspec, qspec, qspec, blk0, blk1, blk0, blk1],
-        out_specs=[qspec, qspec, qspec],
-    )
-    out_shape = [
-        jax.ShapeDtypeStruct((q,), jnp.bool_),
-        jax.ShapeDtypeStruct((q,), I32),
-        jax.ShapeDtypeStruct((q,), jnp.bool_),
-    ]
-    kernel = functools.partial(_probe_insert_kernel, max_probes=max_probes)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
-        slab_base, h0_sorted, qk_sorted, qm_sorted_i32,
-        tkey, tkey, tstate, tstate)
+def probe2_windows(off_old, off_new, qk, old_w, new_w, hazard_key,
+                   hazard_live_i32, *, max_probes: int):
+    """Fused rebuild-epoch probe.  ``old_w``/``new_w`` are (key, state)
+    window pairs [Q, W]; the hazard arrays are [1, CH] with CH a multiple of
+    ``min(CH, HZB)``.  Returns (old hit lane, hazard index, new hit lane),
+    each [Q, 1] (-1 = none)."""
+    q, w = old_w[0].shape
+    ch = hazard_key.shape[1]
+    col, win = _query_specs(w)
+    hspec = pl.BlockSpec((1, ch), lambda i: (0, 0))
+    return pallas_call(
+        functools.partial(_probe2_kernel, max_probes=max_probes),
+        grid=(q // WQT,),
+        in_specs=[col, col, col, win, win, win, win, hspec, hspec],
+        out_specs=[col, col, col],
+        out_shape=[jax.ShapeDtypeStruct((q, 1), I32)] * 3)(
+        off_old, off_new, qk, *old_w, *new_w, hazard_key, hazard_live_i32)
 
 
 # ---------------------------------------------------------------------------
-# rebuild chunk extraction: slab window scan + on-device compaction
+# rebuild chunk extraction: LIVE mask + compaction ranks
 # ---------------------------------------------------------------------------
 
-def _extract_kernel(info_ref,            # scalar-prefetch: [2] (block, cursor)
-                    tk0, tk1, tv0, tv1, ts0, ts1,   # [SLAB] key/val/state
-                    hk_ref, hv_ref, hl_ref, mig_ref,
-                    *, chunk: int, capacity: int):
-    """Rebuild chunk scan: read the ``chunk`` slots at ``cursor`` from the
-    resident 2-block slab window, COMPACT the live entries to the front of
-    the hazard outputs on-device (cumsum ranking + one local scatter), and
-    emit the position-aligned MIGRATED mask ``mig`` that ops.py applies to
-    the table state with a single scatter.
-
-    Contract: ``chunk <= SLAB`` so the window always fits the two resident
-    blocks, and ``capacity`` is the UNPADDED table length (slots at or past
-    it never migrate).  Replaces the jnp gather scan in ``rebuild_extract``:
-    one pallas_call + one scatter instead of three table gathers + scatter.
-    """
-    base = info_ref[0] * SLAB
-    cur = info_ref[1]
-    keys = jnp.concatenate([tk0[...], tk1[...]])
-    vals = jnp.concatenate([tv0[...], tv1[...]])
-    stat = jnp.concatenate([ts0[...], ts1[...]])
-
-    lane = jax.lax.broadcasted_iota(I32, (chunk,), 0)
-    off = jnp.clip(cur - base, 0, 2 * SLAB - chunk) + lane
-    pos = cur + lane                               # absolute table position
-    live = (pos < capacity) & (jnp.take(stat, off, axis=0) == LIVE)
-
-    # compact: live entry j lands at rank(j) = #live entries before it
-    rank = jnp.cumsum(live.astype(I32)) - 1
-    dest = jnp.where(live, rank, chunk)
-    hk_ref[...] = jnp.zeros((chunk,), I32).at[dest].set(
-        jnp.take(keys, off, axis=0), mode="drop")
-    hv_ref[...] = jnp.zeros((chunk,), I32).at[dest].set(
-        jnp.take(vals, off, axis=0), mode="drop")
-    hl_ref[...] = (lane < live.sum()).astype(I32)
-    mig_ref[...] = live.astype(I32)
+def _extract_kernel(lim_ref, st_ref, live_ref, rank_ref):
+    """Rebuild chunk scan over the chunk's states [R, 128] (slot ``r*128+l``
+    of the chunk).  ``lim`` is the number of chunk slots inside the table.
+    Emits the LIVE mask and each live slot's rank among the live slots in
+    scan order: within-row inclusive prefix counts (``x @ U``, U upper
+    triangular) plus the counts of the rows above (``L @ row totals``, L
+    strictly lower triangular).  Operands are 0/1 and row totals <= 128, so
+    the matmuls are exact in any MXU precision."""
+    r = st_ref.shape[0]
+    slot = (jax.lax.broadcasted_iota(I32, (r, LANES), 0) * LANES
+            + jax.lax.broadcasted_iota(I32, (r, LANES), 1))
+    live = (slot < lim_ref[0, 0]) & (st_ref[...] == LIVE)
+    x = live.astype(jnp.float32)
+    upper = (jax.lax.broadcasted_iota(I32, (LANES, LANES), 0)
+             <= jax.lax.broadcasted_iota(I32, (LANES, LANES), 1))
+    incl = jnp.dot(x, upper.astype(jnp.float32),
+                   preferred_element_type=jnp.float32)
+    below = (jax.lax.broadcasted_iota(I32, (r, r), 1)
+             < jax.lax.broadcasted_iota(I32, (r, r), 0))
+    tot = jnp.broadcast_to(incl[:, LANES - 1:], (r, LANES))
+    above = jnp.dot(below.astype(jnp.float32), tot,
+                    preferred_element_type=jnp.float32)
+    live_ref[...] = live.astype(I32)
+    rank_ref[...] = (incl + above).astype(I32) - 1
 
 
-def extract_tiles(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
-                  block: jax.Array, cursor: jax.Array, *, chunk: int,
-                  capacity: int, interpret: bool = True):
-    """Run the extract kernel once over the slab window holding ``cursor``.
-
-    tkey/tval/tstate: padded to a SLAB multiple with one spare block (pad is
-    EMPTY, so padding never migrates).  block: scalar i32 slab block index
-    (cursor // SLAB clipped so block+1 stays in range).  Returns
-    (hkeys[chunk], hvals[chunk], hlive_i32[chunk], migrated_i32[chunk]) with
-    the hazard outputs compacted and ``migrated`` aligned to slot positions.
-    """
-    assert chunk <= SLAB and tkey.shape[0] % SLAB == 0
-    info = jnp.stack([block.astype(I32), cursor.astype(I32)])
-
-    blk0 = pl.BlockSpec((SLAB,), lambda i, s: (s[0],))
-    blk1 = pl.BlockSpec((SLAB,), lambda i, s: (s[0] + 1,))
-    cspec = pl.BlockSpec((chunk,), lambda i, s: (0,))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[blk0, blk1, blk0, blk1, blk0, blk1],
-        out_specs=[cspec, cspec, cspec, cspec],
-    )
-    out_shape = [jax.ShapeDtypeStruct((chunk,), I32)] * 4
-    kernel = functools.partial(_extract_kernel, chunk=chunk,
-                               capacity=capacity)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
-        info, tkey, tkey, tval, tval, tstate, tstate)
+def extract_rows(states: jax.Array, lim: jax.Array):
+    """Run the extract kernel on a chunk's states laid out [R, 128].
+    ``lim``: scalar count of chunk slots below the table's capacity.
+    Returns (live_i32 [R, 128], rank [R, 128])."""
+    r = states.shape[0]
+    blk = pl.BlockSpec((r, LANES), lambda: (0, 0))
+    return pallas_call(
+        _extract_kernel,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), blk],
+        out_specs=[blk, blk],
+        out_shape=[jax.ShapeDtypeStruct((r, LANES), I32)] * 2)(
+        jnp.reshape(lim, (1, 1)).astype(I32), states)
 
 
 # ---------------------------------------------------------------------------
@@ -658,7 +365,7 @@ def _tc_specs(width: int):
 
 def tc_lookup_tiles(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
                     row_sorted: jax.Array, qk_sorted: jax.Array,
-                    slab_base: jax.Array, *, interpret: bool = True):
+                    slab_base: jax.Array):
     """Run the twochoice lookup kernel over pre-sorted, pre-tiled entries.
 
     tkey/tval/tstate: [Bpad, W] row-padded tables (Bpad a SLAB_R multiple
@@ -686,16 +393,14 @@ def tc_lookup_tiles(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
         jax.ShapeDtypeStruct((e,), jnp.bool_),
     ]
     kernel = functools.partial(_tc_lookup_kernel, width=width)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
+    return pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape)(
         slab_base, row_sorted, qk_sorted,
         tkey, tkey, tval, tval, tstate, tstate)
 
 
 def tc_insert_tiles(tkey: jax.Array, tstate: jax.Array,
                     row_sorted: jax.Array, qk_sorted: jax.Array,
-                    qm_sorted_i32: jax.Array, slab_base: jax.Array, *,
-                    interpret: bool = True):
+                    qm_sorted_i32: jax.Array, slab_base: jax.Array):
     """Claim pass of the twochoice insert over pre-sorted, pre-tiled entries.
 
     Returns (present[E], claim[E] flat table slot or -1, complete[E]).
@@ -719,8 +424,7 @@ def tc_insert_tiles(tkey: jax.Array, tstate: jax.Array,
         jax.ShapeDtypeStruct((e,), jnp.bool_),
     ]
     kernel = functools.partial(_tc_insert_kernel, width=width)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
+    return pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape)(
         slab_base, row_sorted, qk_sorted, qm_sorted_i32,
         tkey, tkey, tstate, tstate)
 
@@ -786,8 +490,7 @@ def tc_probe2_tiles(old_padded, new_padded,
                     hazard_key: jax.Array, hazard_val: jax.Array,
                     hazard_live_i32: jax.Array,
                     orow_sorted: jax.Array, nrow_sorted: jax.Array,
-                    qk_sorted: jax.Array, slab2: jax.Array, *,
-                    interpret: bool = True):
+                    qk_sorted: jax.Array, slab2: jax.Array):
     """Run the twochoice rebuild-epoch kernel over pre-sorted entries.
 
     old_padded/new_padded: (key, val, state) triples of row-padded [Bpad, W]
@@ -839,8 +542,7 @@ def tc_probe2_tiles(old_padded, new_padded,
         jax.ShapeDtypeStruct((e,), jnp.bool_),    # c_new
     ]
     kernel = functools.partial(_tc_probe2_kernel, width=width)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
+    return pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape)(
         slab2, orow_sorted, nrow_sorted, qk_sorted,
         okk, okk, ovv, ovv, oss, oss,
         nkk, nkk, nvv, nvv, nss, nss,
@@ -868,7 +570,7 @@ def _chain_window_probe(base_blk, h0, qlen, qk, k0, k1, v0, v1, s0, s1,
                         max_probes: int):
     """Segment-bounded probe loop over one 2-block VMEM window.
 
-    Like ``_window_probe`` but the probe run is [h0, h0 + qlen) — absence is
+    Like ``window.probe`` but the probe run is [h0, h0 + qlen) — absence is
     proven by exhausting the segment, not by an EMPTY sentinel.  ``complete``
     additionally requires ``qlen <= max_probes`` (a segment longer than the
     probe bound cannot prove absence).  Returns (found, val, loc, complete);
@@ -976,7 +678,7 @@ def _chain_probe2_kernel(slab2_ref,          # scalar-prefetch: [1+nres, tiles]
 def chain_probe_tiles(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
                       h0_sorted: jax.Array, qlen_sorted: jax.Array,
                       qk_sorted: jax.Array, slab_base: jax.Array, *,
-                      max_probes: int, interpret: bool = True):
+                      max_probes: int):
     """Run the chain lookup kernel over pre-sorted, pre-tiled queries.
 
     tkey/tval/tstate: padded arena arrays (``ops._pad_table``-style).
@@ -1007,8 +709,7 @@ def chain_probe_tiles(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
         jax.ShapeDtypeStruct((q,), jnp.bool_),
     ]
     kernel = functools.partial(_chain_probe_kernel, max_probes=max_probes)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
+    return pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape)(
         slab_base, h0_sorted, qlen_sorted, qk_sorted,
         tkey, tkey, tval, tval, tstate, tstate)
 
@@ -1019,7 +720,7 @@ def chain_probe2_tiles(old_padded, new_padded,
                        h0o_sorted: jax.Array, qlo_sorted: jax.Array,
                        h0n_sorted: jax.Array, qln_sorted: jax.Array,
                        qk_sorted: jax.Array, slab2: jax.Array, *,
-                       max_probes: int, interpret: bool = True):
+                       max_probes: int):
     """Run the chain rebuild-epoch kernel over pre-sorted queries.
 
     old_padded/new_padded: (key, val, state) arena triples padded
@@ -1067,8 +768,7 @@ def chain_probe2_tiles(old_padded, new_padded,
         jax.ShapeDtypeStruct((q,), jnp.bool_),    # c_new
     ]
     kernel = functools.partial(_chain_probe2_kernel, max_probes=max_probes)
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(
+    return pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape)(
         slab2, h0o_sorted, qlo_sorted, h0n_sorted, qln_sorted, qk_sorted,
         okk, okk, ovv, ovv, oss, oss,
         nkk, nkk, nvv, nvv, nss, nss,

@@ -39,9 +39,13 @@ def probe_lookup_ref(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
 def probe_insert_ref(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
                      h0: jax.Array, keys: jax.Array, vals: jax.Array,
                      mask: jax.Array, max_probes: int):
-    """Linear-probe insert oracle on raw table arrays (claim-first-non-LIVE,
-    lowest batch index wins a contested slot — the same linearization as
-    ``buckets.linear_insert``).
+    """Linear-probe insert oracle on raw table arrays, one probe step at a
+    time (claim-first-non-LIVE; every pending key tries the slot at the same
+    probe distance, the lowest batch index wins a contested slot).
+    ``window.insert`` claims whole probe ranges at once and settles a
+    contested slot by probe distance, then batch index, which is the order
+    in which this loop reaches it: both build the same table, slot for slot
+    (``tests/test_kernels.py::test_linear_insert_tables_match_oracle``).
 
     Caller contract: ``mask`` is winner-filtered (at most one True per
     distinct key; use ``buckets.batch_winners``).  Returns

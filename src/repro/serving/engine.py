@@ -270,10 +270,13 @@ class ServingEngine:
 
     # -- stepping -------------------------------------------------------------
     def _run_slots(self, sample: bool = True):
-        sids = jnp.asarray(self.seq_ids)
-        toks = jnp.asarray(self.cur_tok)
-        lens = jnp.asarray(self.lengths)
-        act = jnp.asarray(self.active)
+        # copies: on the CPU backend jnp.asarray can alias a numpy buffer,
+        # and the slot arrays are mutated in place while this step may
+        # still be running asynchronously
+        sids = jnp.asarray(self.seq_ids.copy())
+        toks = jnp.asarray(self.cur_tok.copy())
+        lens = jnp.asarray(self.lengths.copy())
+        act = jnp.asarray(self.active.copy())
         logits, self.kv = self._step(self.params, kv=self.kv, seq_ids=sids,
                                      tokens=toks, lengths=lens, active=act)
         self.lengths = np.where(self.active, self.lengths + 1, self.lengths)
