@@ -46,6 +46,16 @@ This is the paper's core contribution (§3-§4) mapped to the SPMD/XLA model:
   by ONE kernel launch per op, each table free to run its own rebuild epoch
   (multi-tenant serving: per-tenant page tables in serving/kvcache.py).
 
+* **Named scopes**: each public op runs under a ``jax.named_scope`` —
+  ``dhash.lookup`` (also ``lookup_counted``), ``dhash.insert``,
+  ``dhash.delete``, ``dhash.rebuild_step``, ``dhash.finish_same_shape``,
+  ``dhash.rebuild_autostart`` — and the jnp hazard-buffer work inside a
+  lookup or delete under a nested ``dhash.hazard``.  Scopes are HLO
+  metadata only (no op, fusion or count changes); a profiler trace keeps
+  them as each device op's ``tf_op``, so every caller's trace (engines,
+  stacks under ``vmap``, the router, the serving page tables) names the
+  DHash operation each op belongs to.
+
 Progress-guarantee analogue (DESIGN.md §2): a step's latency is bounded and
 independent of rebuild progress — rebuild costs O(chunk) per transition,
 never a stop-the-world O(N) pause.
@@ -164,6 +174,7 @@ def make(backend: str = "linear", capacity: int = 1024, *, chunk: int = 256,
 # the ordered check: old -> hazard -> new (Lemma 4.1)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("dhash.hazard")
 def _hazard_probe(d: DHashState, keys: jax.Array):
     eq = (keys[:, None] == d.hazard_key[None, :]) & d.hazard_live[None, :]
     found = eq.any(-1)
@@ -184,12 +195,13 @@ def _slow_lookup(dd: DHashState, keys: jax.Array):
         # MIGRATED slots of the in-flight chunk, so the hazard check is
         # a forwarding index, not a second pass (§Perf dhash-service)
         f_old, v_old, _, mig = be.lookup_fwd(dd.old, keys)
-        base = dd.cursor - dd.chunk
-        hz_idx = mig - base
-        inwin = (mig >= 0) & (hz_idx >= 0) & (hz_idx < dd.chunk)
-        safe = jnp.clip(hz_idx, 0, dd.chunk - 1)
-        f_hz = inwin & dd.hazard_live[safe] & (dd.hazard_key[safe] == keys)
-        v_hz = dd.hazard_val[safe]
+        with jax.named_scope("dhash.hazard"):
+            base = dd.cursor - dd.chunk
+            hz_idx = mig - base
+            inwin = (mig >= 0) & (hz_idx >= 0) & (hz_idx < dd.chunk)
+            safe = jnp.clip(hz_idx, 0, dd.chunk - 1)
+            f_hz = inwin & dd.hazard_live[safe] & (dd.hazard_key[safe] == keys)
+            v_hz = dd.hazard_val[safe]
     else:
         f_old, v_old, _ = be.lookup(dd.old, keys)        # (1) old table
         f_hz, v_hz = _hazard_probe(dd, keys)             # (2) rebuild_cur
@@ -199,6 +211,7 @@ def _slow_lookup(dd: DHashState, keys: jax.Array):
     return found, val
 
 
+@jax.named_scope("dhash.lookup")
 def lookup(d: DHashState, keys: jax.Array):
     """Batched lookup honouring the rebuild protocol. Returns (found, vals).
 
@@ -217,6 +230,7 @@ def lookup(d: DHashState, keys: jax.Array):
     return jax.lax.cond(d.rebuilding, _slow_lookup, fast, d, keys)
 
 
+@jax.named_scope("dhash.lookup")
 def lookup_counted(d: DHashState, keys: jax.Array, *,
                    probe_hi: int = 7):
     """Lookup that also feeds the elastic policy's probe telemetry.
@@ -264,6 +278,7 @@ def _ins_table(dd: DHashState, t, kk, vv, mm):
     return be.insert(t, kk, vv, mm)
 
 
+@jax.named_scope("dhash.insert")
 def insert(d: DHashState, keys: jax.Array, vals: jax.Array, mask: jax.Array | None = None):
     """Batched insert (set semantics: ok=False if key already present in the
     *target* table — Alg. 6). Returns (state', ok)."""
@@ -281,6 +296,7 @@ def insert(d: DHashState, keys: jax.Array, vals: jax.Array, mask: jax.Array | No
     return jax.lax.cond(d.rebuilding, slow, fast, d)
 
 
+@jax.named_scope("dhash.delete")
 def delete(d: DHashState, keys: jax.Array, mask: jax.Array | None = None):
     """Batched delete honouring the ordered check (Alg. 5). Returns (state', ok).
 
@@ -317,11 +333,13 @@ def delete(d: DHashState, keys: jax.Array, mask: jax.Array | None = None):
         pending = mask & ~ok_old
         # (2) hazard buffer: clear the live bit (LOGICALLY_REMOVED on the
         # in-flight node) - landing will drop it.
-        eq = (keys[:, None] == dd.hazard_key[None, :]) & dd.hazard_live[None, :]
-        hit_hz = eq.any(-1) & pending
-        win_hz = buckets.batch_winners(keys, hit_hz) & hit_hz
-        kill = (eq & win_hz[:, None]).any(0)
-        hazard_live = dd.hazard_live & ~kill
+        with jax.named_scope("dhash.hazard"):
+            eq = ((keys[:, None] == dd.hazard_key[None, :])
+                  & dd.hazard_live[None, :])
+            hit_hz = eq.any(-1) & pending
+            win_hz = buckets.batch_winners(keys, hit_hz) & hit_hz
+            kill = (eq & win_hz[:, None]).any(0)
+            hazard_live = dd.hazard_live & ~kill
         pending2 = pending & ~hit_hz
         t_new, ok_new = _del(dd, dd.new, keys, pending2)               # (3) new
         ok = ok_old | win_hz | ok_new
@@ -437,6 +455,7 @@ def rebuild_finish(d: DHashState) -> DHashState:
                    lookups=jnp.asarray(0, I32), expensive=jnp.asarray(0, I32))
 
 
+@jax.named_scope("dhash.finish_same_shape")
 def finish_same_shape(d: DHashState) -> DHashState:
     """Fully-jitted epoch swap, valid when old/new share static shapes
     (continuous-rebuild benchmarks; router rebalancing)."""
@@ -455,12 +474,14 @@ def finish_same_shape(d: DHashState) -> DHashState:
                    expensive=jnp.where(done, 0, d.expensive).astype(I32))
 
 
+@jax.named_scope("dhash.rebuild_step")
 def rebuild_step(d: DHashState) -> DHashState:
     """One rebuild transition per call: land if hazard pending, else extract.
     Interleave with op batches for concurrent-rebuild execution."""
     return jax.lax.cond(d.hazard_live.any(), rebuild_land, rebuild_extract, d)
 
 
+@jax.named_scope("dhash.rebuild_autostart")
 def rebuild_autostart(d: DHashState) -> DHashState:
     """Fully-jitted rebuild start: when NOT rebuilding, clear the (drained)
     standby table, reseed its hash function on-device from the epoch counter

@@ -45,6 +45,15 @@ not be used elsewhere.
 Used by the benchmarks (continuous-rebuild mode reproduces the paper's Fig 2
 setup) and by the serving engine for live cache rehash.
 
+Observability: each ``step`` is a host span ``dhash.engine.step`` (its
+step number as the span argument ``step``) holding ``dhash.engine.put``
+(the operands to the device), ``dhash.engine.dispatch`` (the jitted call)
+and, once every ``poll_every`` steps, ``dhash.engine.poll``; each
+``lookup`` is ``dhash.engine.lookup`` holding ``put`` and ``dispatch``.
+They are ``jax.profiler.TraceAnnotation``s, so they land in a profiler
+trace on the device ops' clock and cost about a microsecond each
+when no profiler runs; nothing else records them.
+
 ``DHashStackEngine`` is the multi-table variant: it drives a
 ``dhash.make_stack`` state — T independent tables vmapped inside one jitted
 step, each with its OWN rebuild epoch (staggered live rehashes across
@@ -59,6 +68,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.core import backend as backends
 from repro.core import dhash
@@ -70,13 +80,43 @@ I32 = jnp.int32
 DEFAULT_POLL_EVERY = 32
 
 
+def _put(lookup_keys, ins_keys, ins_vals, del_keys, ins_mask, del_mask):
+    """A step's six operands on the device."""
+    with span("dhash.engine.put"):
+        lk = jnp.asarray(lookup_keys, I32)
+        ik = jnp.asarray(ins_keys, I32)
+        iv = jnp.asarray(ins_vals, I32)
+        dk = jnp.asarray(del_keys, I32)
+        im = (jnp.ones(ik.shape, bool) if ins_mask is None
+              else jnp.asarray(ins_mask))
+        dm = (jnp.ones(dk.shape, bool) if del_mask is None
+              else jnp.asarray(del_mask))
+    return lk, ik, iv, dk, im, dm
+
+
+def _count_and_poll(eng, ops: int) -> None:
+    """Count a step, and poll the device once every ``poll_every`` steps."""
+    eng._stats.steps += 1
+    eng._stats.ops += ops
+    if eng.poll_every <= 1 or eng._stats.steps % eng.poll_every == 0:
+        with span("dhash.engine.poll"):
+            eng._poll()
+
+
+def _lookup(fn, state, keys):
+    """A lookup batch through the jitted ``fn``, under the engine's spans."""
+    with span("dhash.engine.lookup"):
+        with span("dhash.engine.put"):
+            k = jnp.asarray(keys, I32)
+        with span("dhash.engine.dispatch"):
+            return fn(state, k)
+
+
 @dataclass
 class EngineStats:
     steps: int = 0
     ops: int = 0
-    hits: int = 0
     rebuilds_completed: int = 0
-    rebuild_transitions: int = 0
     host_syncs: int = 0         # engine-internal device_get round-trips
     grows: int = 0              # policy-applied capacity increases
     shrinks: int = 0            # policy-applied capacity decreases
@@ -177,24 +217,19 @@ class DHashEngine:
 
     def step(self, lookup_keys, ins_keys, ins_vals, del_keys,
              ins_mask=None, del_mask=None):
-        lk = jnp.asarray(lookup_keys, I32)
-        ik = jnp.asarray(ins_keys, I32)
-        iv = jnp.asarray(ins_vals, I32)
-        dk = jnp.asarray(del_keys, I32)
-        im = jnp.ones(ik.shape, bool) if ins_mask is None else jnp.asarray(ins_mask)
-        dm = jnp.ones(dk.shape, bool) if del_mask is None else jnp.asarray(del_mask)
-        if self.policy is not None:
-            self.state, self.policy, out = _policy_engine_step(
-                self.state, self.policy, lk, ik, iv, dk, im, dm,
-                swap_on_device=self._swap_on_device())
-        else:
-            fn = self._get_step_fn(self._swap_on_device())
-            self.state, out = fn(self.state, lk, ik, iv, dk, im, dm)
-        self._stats.steps += 1
-        self._stats.ops += lk.size + ik.size + dk.size
-        if self.poll_every <= 1 or self._stats.steps % self.poll_every == 0:
-            self._poll()
-        return out
+        with span("dhash.engine.step", step=self._stats.steps):
+            lk, ik, iv, dk, im, dm = _put(lookup_keys, ins_keys, ins_vals,
+                                          del_keys, ins_mask, del_mask)
+            with span("dhash.engine.dispatch"):
+                if self.policy is not None:
+                    self.state, self.policy, out = _policy_engine_step(
+                        self.state, self.policy, lk, ik, iv, dk, im, dm,
+                        swap_on_device=self._swap_on_device())
+                else:
+                    fn = self._get_step_fn(self._swap_on_device())
+                    self.state, out = fn(self.state, lk, ik, iv, dk, im, dm)
+            _count_and_poll(self, lk.size + ik.size + dk.size)
+            return out
 
     # -- host-side polling (1 of every K steps) ------------------------------
 
@@ -299,7 +334,7 @@ class DHashEngine:
         return True
 
     def lookup(self, keys):
-        return self._lookup_fn(self.state, jnp.asarray(keys, I32))
+        return _lookup(self._lookup_fn, self.state, keys)
 
     def count(self) -> int:
         self._stats.host_syncs += 1
@@ -391,22 +426,18 @@ class DHashStackEngine:
     def step(self, lookup_keys, ins_keys, ins_vals, del_keys,
              ins_mask=None, del_mask=None):
         """One batched step for all T tables: operands are [T, Q]."""
-        lk = jnp.asarray(lookup_keys, I32)
-        ik = jnp.asarray(ins_keys, I32)
-        iv = jnp.asarray(ins_vals, I32)
-        dk = jnp.asarray(del_keys, I32)
-        im = jnp.ones(ik.shape, bool) if ins_mask is None else jnp.asarray(ins_mask)
-        dm = jnp.ones(dk.shape, bool) if del_mask is None else jnp.asarray(del_mask)
-        if self.policy is not None:
-            self.state, self.policy, out = self._step_fn(
-                self.state, self.policy, lk, ik, iv, dk, im, dm)
-        else:
-            self.state, out = self._step_fn(self.state, lk, ik, iv, dk, im, dm)
-        self._stats.steps += 1
-        self._stats.ops += lk.size + ik.size + dk.size
-        if self.poll_every <= 1 or self._stats.steps % self.poll_every == 0:
-            self._poll()
-        return out
+        with span("dhash.engine.step", step=self._stats.steps):
+            lk, ik, iv, dk, im, dm = _put(lookup_keys, ins_keys, ins_vals,
+                                          del_keys, ins_mask, del_mask)
+            with span("dhash.engine.dispatch"):
+                if self.policy is not None:
+                    self.state, self.policy, out = self._step_fn(
+                        self.state, self.policy, lk, ik, iv, dk, im, dm)
+                else:
+                    self.state, out = self._step_fn(self.state, lk, ik, iv,
+                                                    dk, im, dm)
+            _count_and_poll(self, lk.size + ik.size + dk.size)
+            return out
 
     def _poll(self):
         epochs = np.asarray(jax.device_get(self.state.epoch))
@@ -433,7 +464,7 @@ class DHashStackEngine:
         self.state = self._start_fn(self.state, m)
 
     def lookup(self, keys):
-        return self._lookup_fn(self.state, jnp.asarray(keys, I32))
+        return _lookup(self._lookup_fn, self.state, keys)
 
     def counts(self) -> np.ndarray:
         """[T] live-entry counts (one host sync)."""
