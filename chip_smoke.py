@@ -279,6 +279,7 @@ def single_chip(jax, seed: int, fused: bool) -> None:
         step, swapped_at, checked = 0, None, []
         pending, want = [], []
         user_sh, land_sh = [], []
+        after_sample = False
         t_first = t_swap = None
         while swapped_at is None or step < swapped_at + AFTER:
             look, ins_ids, iv, del_ids = traffic.step()
@@ -288,12 +289,15 @@ def single_chip(jax, seed: int, fused: bool) -> None:
                 or (swapped_at is not None and step % 8 == 0)
             if sample:
                 exp_f, exp_v = model.lookup(look)
-                if fused:
-                    u, la, landing = jax.device_get(
-                        shares(eng.state, jnp.asarray(ik)))
-                    user_sh.append(float(u))
-                    if landing:
-                        land_sh.append(float(la))
+            # a step takes an odd number of transitions, so of a sampled
+            # step and the next one, one meets a hazard chunk to land
+            if fused and (sample or after_sample):
+                u, la, landing = jax.device_get(
+                    shares(eng.state, jnp.asarray(ik)))
+                user_sh.append(float(u))
+                if landing:
+                    land_sh.append(float(la))
+            after_sample = sample
             exp_i = model.insert(ins_ids, iv)
             exp_d = model.delete(del_ids)
             out = eng.step(lk, ik, iv, dk)
@@ -301,7 +305,7 @@ def single_chip(jax, seed: int, fused: bool) -> None:
             pending.append(counts(out[2], out[3]))
             want.append((exp_i.sum(), exp_d.sum()))
             if step == 0:
-                fn = eng._get_step_fn(eng._swap_on_device())
+                fn = eng._step_fn
                 txt = fn.lower(eng.state, *(jnp.asarray(a) for a in (
                     lk, ik, iv, dk)), jnp.ones(ik.shape, bool),
                     jnp.ones(dk.shape, bool)).compile().as_text()

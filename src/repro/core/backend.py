@@ -19,7 +19,8 @@ descriptor bundling everything the DHash layer needs to drive it:
   constants to descriptor fields and threaded through ``dhash.make()``;
 * optional hooks: ``freeze_old`` (pre-epoch maintenance — the chain arena
   compaction), ``lookup_fwd`` (the linear backend's MIGRATED-slot hazard
-  forwarding).
+  forwarding), ``insert_either``/``insert_either_fused`` (the linear
+  backend's insert into old or new as one claim loop over both tables).
 
 ``core/dhash.py`` contains ZERO per-backend branches: every public op
 dispatches through the descriptor looked up by ``DHashState.backend``.
@@ -106,6 +107,16 @@ class BucketBackend:
           -> (found, vals)                     whole Lemma-4.1 ordered check
       ordered_delete_fused(t_old, t_new, hk, hv, hl, keys, mask, *, nres_cap)
           -> (old_state', new_state', hl', ok)
+
+    Two-table insert hooks (``None`` = ``dhash.insert`` picks the table
+    with a ``lax.cond``):
+
+      insert_either(t_old, t_new, to_new, keys, vals, mask)
+          -> (t_old', t_new', ok)              ``insert`` into t_new where
+                                               the scalar to_new holds, else
+                                               into t_old, writing both in
+                                               place
+      insert_either_fused(...)                 the same on the fused op set
     """
 
     name: str
@@ -148,6 +159,8 @@ class BucketBackend:
     # optional hooks
     freeze_old: Callable[..., Any] | None = None
     lookup_fwd: Callable[..., Any] | None = None
+    insert_either: Callable[..., Any] | None = None
+    insert_either_fused: Callable[..., Any] | None = None
     # the TPU compiler's refusal of this backend's fused kernels (kernel and
     # error, as tests/test_tpu_compile.py records it), None when they
     # compile; dhash.make raises it instead of building a fused table on a
@@ -233,6 +246,24 @@ def linear_insert_fused(t: LinearTable, keys: jax.Array, vals: jax.Array,
     tk, tv, ts, ok = ops.probe_insert(t.key, t.val, t.state, h0, keys, vals,
                                       winner, max_probes=t.max_probes)
     return replace(t, key=tk, val=tv, state=ts), ok
+
+
+def linear_insert_either_fused(t_old: LinearTable, t_new: LinearTable,
+                               to_new: jax.Array, keys: jax.Array,
+                               vals: jax.Array, mask: jax.Array):
+    """Kernel-backed ``buckets.linear_insert_either``: the claim kernel
+    reads the target table, one claim loop writes both.  Returns (t_old',
+    t_new', ok)."""
+    from repro.kernels import ops
+    winner = batch_winners(keys, mask)
+    h0 = jnp.where(to_new, hashing.bucket_of(t_new.hfn, keys, t_new.capacity),
+                   hashing.bucket_of(t_old.hfn, keys, t_old.capacity))
+    a, b, ok = ops.probe_insert_either(
+        (t_old.key, t_old.val, t_old.state),
+        (t_new.key, t_new.val, t_new.state), to_new, h0, keys, vals, winner,
+        max_probes=(t_old.max_probes, t_new.max_probes))
+    return (replace(t_old, key=a[0], val=a[1], state=a[2]),
+            replace(t_new, key=b[0], val=b[1], state=b[2]), ok)
 
 
 def linear_delete_fused(t: LinearTable, keys: jax.Array, mask: jax.Array):
@@ -822,6 +853,8 @@ LINEAR = register(BucketBackend(
     ordered_lookup_fused=linear_ordered_lookup_fused,
     ordered_delete_fused=linear_ordered_delete_fused,
     lookup_fwd=buckets.linear_lookup_fwd,
+    insert_either=buckets.linear_insert_either,
+    insert_either_fused=linear_insert_either_fused,
 ))
 
 TWOCHOICE = register(BucketBackend(

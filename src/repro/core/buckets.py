@@ -149,6 +149,24 @@ def linear_insert(t: LinearTable, keys: jax.Array, vals: jax.Array, mask: jax.Ar
     return replace(t, key=key, val=val, state=state), done
 
 
+def linear_insert_either(t_old: LinearTable, t_new: LinearTable,
+                         to_new: jax.Array, keys: jax.Array, vals: jax.Array,
+                         mask: jax.Array):
+    """``linear_insert`` into ``t_new`` where the scalar ``to_new`` holds,
+    else into ``t_old``, as ONE claim loop over both tables
+    (``window.insert_either``): presence is checked in the target alone.
+    Returns (t_old', t_new', ok)."""
+    winner = batch_winners(keys, mask)
+    h0 = jnp.where(to_new, hashing.bucket_of(t_new.hfn, keys, t_new.capacity),
+                   hashing.bucket_of(t_old.hfn, keys, t_old.capacity))
+    a, b, ok = window.insert_either(
+        (t_old.key, t_old.val, t_old.state),
+        (t_new.key, t_new.val, t_new.state), to_new, h0, keys, vals, winner,
+        (t_old.max_probes, t_new.max_probes))
+    return (replace(t_old, key=a[0], val=a[1], state=a[2]),
+            replace(t_new, key=b[0], val=b[1], state=b[2]), ok)
+
+
 def linear_delete(t: LinearTable, keys: jax.Array, mask: jax.Array):
     winner = batch_winners(keys, mask)
     found, _, loc = linear_lookup(t, keys)

@@ -27,11 +27,27 @@ This is the paper's core contribution (§3-§4) mapped to the SPMD/XLA model:
   table's copy (Alg. 3 lines 34-36).
 
 * The epoch swap (Alg. 3 lines 41-46) is a host-level transition
-  (``rebuild_finish``) because old/new may differ in static shape; for
-  shape-preserving rebuilds there is a fully-jitted ``finish_same_shape``.
-  The paper's ``synchronize_rcu`` grace periods are step boundaries: a
-  transition consumes state_t and produces state_{t+1}, so no reader of
-  state_t can observe state_{t+1} — the grace period is free.
+  (``rebuild_finish``, or ``epoch_swap`` where the caller has read
+  ``rebuild_done`` itself): an O(1) swap of the two tables' pytrees, valid
+  whatever their static shapes.  ``DHashEngine`` runs it at its poll.
+  ``finish_same_shape`` is the jitted swap of same-shape tables, for
+  callers that cannot leave the device between steps (table stacks, the
+  policy engine, the router); it selects every table array, so it costs
+  whole-table passes on every call.  The paper's ``synchronize_rcu`` grace
+  periods are step boundaries: a transition consumes state_t and produces
+  state_{t+1}, so no reader of state_t can observe state_{t+1} — the grace
+  period is free.
+
+* **No whole table through a conditional.**  A ``lax.cond`` that hands a
+  table on unwritten in one branch, while another branch writes it in
+  place, makes XLA copy the whole table into or out of the branches (copy
+  insertion).  So the linear backend's insert is one claim loop over both
+  tables whose claim pass reads the target inside a ``lax.cond`` that
+  returns only [Q] lanes (the descriptor's ``insert_either`` hook), and
+  its landing runs outside the rebuild step's conditional.  The census in
+  ``tests/test_tpu_compile.py`` holds the engine's step and the lookup,
+  compiled for a v5e at 2^26 slots, to no whole-table ``copy`` or
+  ``select``.
 
 * **Backend dispatch is the descriptor registry** (core/backend.py): every
   op below resolves ``DHashState.backend`` to a frozen ``BucketBackend``
@@ -278,12 +294,26 @@ def _ins_table(dd: DHashState, t, kk, vv, mm):
     return be.insert(t, kk, vv, mm)
 
 
+def _insert_either(d: DHashState):
+    """The descriptor's two-table insert hook for ``d``'s op set, or None."""
+    be = _be(d)
+    return be.insert_either_fused if d.fused else be.insert_either
+
+
 @jax.named_scope("dhash.insert")
 def insert(d: DHashState, keys: jax.Array, vals: jax.Array, mask: jax.Array | None = None):
     """Batched insert (set semantics: ok=False if key already present in the
-    *target* table — Alg. 6). Returns (state', ok)."""
+    *target* table — Alg. 6). Returns (state', ok).
+
+    A backend with an ``insert_either`` hook (linear) runs one claim loop
+    over both tables and writes them in place; the others pick the target
+    with a ``lax.cond``."""
     if mask is None:
         mask = jnp.ones(keys.shape, bool)
+    either = _insert_either(d)
+    if either is not None:
+        old, new, ok = either(d.old, d.new, d.rebuilding, keys, vals, mask)
+        return replace(d, old=old, new=new), ok
 
     def fast(dd: DHashState):
         t, ok = _ins_table(dd, dd.old, keys, vals, mask)
@@ -380,6 +410,11 @@ def rebuild_extract(d: DHashState) -> DHashState:
     scan is the extract kernel (one pallas_call over the resident slab
     window + one MIGRATED scatter; hazard entries compacted on-device)
     instead of the jnp gather scan."""
+    return _extract(d, d.rebuilding & ~d.hazard_live.any())
+
+
+def _extract(d: DHashState, can: jax.Array) -> DHashState:
+    """``rebuild_extract`` where the scalar ``can`` holds."""
     be = _be(d)
 
     def go(dd: DHashState):
@@ -392,7 +427,6 @@ def rebuild_extract(d: DHashState) -> DHashState:
         return replace(dd, old=t, hazard_key=hk, hazard_val=hv,
                        hazard_live=hl, cursor=cur)
 
-    can = d.rebuilding & ~d.hazard_live.any()
     return jax.lax.cond(can, go, lambda dd: dd, d)
 
 
@@ -402,7 +436,7 @@ def rebuild_land(d: DHashState) -> DHashState:
     hazard (delete during the hazard period) are dropped.
 
     With ``fused`` the landing runs through the SAME claim kernel as user
-    inserts, so the whole rebuild epoch — extract -> land -> swap — stays
+    inserts, so the extract and the landing of a whole rebuild epoch stay
     on-device inside the jitted engine step.
 
     A landing insert can fail two ways and they MUST be told apart: the key
@@ -414,23 +448,25 @@ def rebuild_land(d: DHashState) -> DHashState:
     disambiguating presence check is the plain jnp probe — elementwise, no
     extra sort or kernel pass — and cond-gated so clean landings never pay
     it."""
-    be = _be(d)
+    return jax.lax.cond(d.rebuilding, _land, lambda dd: dd, d)
 
-    def go(dd: DHashState):
-        t, ok = _ins_table(dd, dd.new, dd.hazard_key, dd.hazard_val,
-                           dd.hazard_live)
-        failed = dd.hazard_live & ~ok
 
-        def reconcile(args):
-            t_, failed_ = args
-            present, _, _ = be.lookup(t_, dd.hazard_key)
-            return failed_ & ~present          # keep only the capacity fails
+def _land(d: DHashState, live: jax.Array | None = None) -> DHashState:
+    """Land the ``live`` hazard entries (all live ones by default) in the
+    new table; the others keep their live bit."""
+    if live is None:
+        live = d.hazard_live
+    t, ok = _ins_table(d, d.new, d.hazard_key, d.hazard_val, live)
+    failed = live & ~ok
 
-        keep = jax.lax.cond(failed.any(), reconcile,
-                            lambda args: jnp.zeros_like(failed), (t, failed))
-        return replace(dd, new=t, hazard_live=keep)
+    def reconcile(args):
+        t_, failed_ = args
+        present, _, _ = _be(d).lookup(t_, d.hazard_key)
+        return failed_ & ~present          # keep only the capacity fails
 
-    return jax.lax.cond(d.rebuilding, go, lambda dd: dd, d)
+    keep = jax.lax.cond(failed.any(), reconcile,
+                        lambda args: jnp.zeros_like(failed), (t, failed))
+    return replace(d, new=t, hazard_live=(d.hazard_live & ~live) | keep)
 
 
 def rebuild_chunk(d: DHashState) -> DHashState:
@@ -447,8 +483,15 @@ def rebuild_done(d: DHashState) -> jax.Array:
 
 def rebuild_finish(d: DHashState) -> DHashState:
     """Host-level epoch swap (Alg. 3 lines 41-46). old/new may differ in
-    static shape, so this is not jittable in general; O(1) pytree shuffle."""
+    static shape, so this is not jittable in general; O(1) pytree shuffle
+    after one device read that checks ``rebuild_done``."""
     assert bool(jax.device_get(rebuild_done(d))), "rebuild not complete"
+    return epoch_swap(d)
+
+
+def epoch_swap(d: DHashState) -> DHashState:
+    """``rebuild_finish`` for a caller that has read ``rebuild_done`` on the
+    host itself (the engine's poll): the swap alone, with no device sync."""
     # probe telemetry is per-table-generation: a fresh epoch samples afresh
     return replace(d, old=d.new, new=d.old, cursor=jnp.asarray(0, I32),
                    rebuilding=jnp.asarray(False), epoch=d.epoch + 1,
@@ -458,7 +501,9 @@ def rebuild_finish(d: DHashState) -> DHashState:
 @jax.named_scope("dhash.finish_same_shape")
 def finish_same_shape(d: DHashState) -> DHashState:
     """Fully-jitted epoch swap, valid when old/new share static shapes
-    (continuous-rebuild benchmarks; router rebalancing)."""
+    (table stacks, the policy engine, router rebalancing).  It selects
+    every table array whether or not the epoch ends: whole-table passes on
+    every call, which ``DHashEngine`` avoids by swapping at its poll."""
     done = rebuild_done(d)
     old_leaves, treedef = jax.tree_util.tree_flatten(d.old)
     new_leaves = jax.tree_util.tree_leaves(d.new)
@@ -477,8 +522,18 @@ def finish_same_shape(d: DHashState) -> DHashState:
 @jax.named_scope("dhash.rebuild_step")
 def rebuild_step(d: DHashState) -> DHashState:
     """One rebuild transition per call: land if hazard pending, else extract.
-    Interleave with op batches for concurrent-rebuild execution."""
-    return jax.lax.cond(d.hazard_live.any(), rebuild_land, rebuild_extract, d)
+    Interleave with op batches for concurrent-rebuild execution.
+
+    A backend with an ``insert_either`` hook (linear) lands outside any
+    conditional: its claim loop runs no round on an empty mask, so the
+    landing runs every step with the mask empty unless it is due, and only
+    the extract keeps its ``lax.cond``."""
+    if _insert_either(d) is None:
+        return jax.lax.cond(d.hazard_live.any(), rebuild_land,
+                            rebuild_extract, d)
+    pending = d.hazard_live.any()
+    landed = _land(d, d.hazard_live & d.rebuilding)
+    return _extract(landed, d.rebuilding & ~pending)
 
 
 @jax.named_scope("dhash.rebuild_autostart")
@@ -487,10 +542,10 @@ def rebuild_autostart(d: DHashState) -> DHashState:
     standby table, reseed its hash function on-device from the epoch counter
     (hashing.reseed — no host RNG), and raise ``rebuilding``.
 
-    This is the continuous-rebuild engine's device-side replacement for the
-    host-level ``rebuild_start``: combined with ``finish_same_shape`` the
-    steady state never leaves the accelerator.  Valid when old/new share
-    static shapes (same-capacity rebuilds)."""
+    This is the device-side replacement for the host-level
+    ``rebuild_start`` (stack engines, the policy engine): combined with
+    ``finish_same_shape`` their steady state never leaves the accelerator.
+    Valid when old/new share static shapes (same-capacity rebuilds)."""
     be = _be(d)
 
     def go(dd: DHashState):

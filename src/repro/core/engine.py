@@ -6,37 +6,37 @@ incremental progress — one extract or land transition per engine step, with
 the hazard window genuinely observable by the ops interleaved between the two
 halves.
 
-The steady state is **fully on-device**: the jitted step performs the op
-batch, one rebuild transition, the epoch swap (``finish_same_shape``, valid
-whenever old/new share static shapes — every default rebuild), and, in
-continuous-rebuild mode, the next rebuild start (``rebuild_autostart``, which
-reseeds the hash function on-device).  With a ``fused`` DHashState the whole
-surface inside that step is kernel-backed — lookup, insert, DELETE, the
-rebuild chunk extraction, and the hazard landing all run through the Pallas
-probe/claim/extract kernels, so a complete rebuild epoch (extract -> land ->
-swap) with interleaved reads and writes never leaves the device between
-polls ("fused reads, jnp writes" was PR 1; this is fully fused).  The
-rebuild-epoch ordered lookup/delete are single-pass for ALL THREE fused
-backends (linear probe2, its twochoice analogue, and the chain backend's
-arena-sorted chain_probe2), and the two-level tile map keeps them
-single-pass even when the rebuild target is a grown table — so a
-capacity-increasing rehash sustains the same step rate as a same-size one
-(see docs/KERNELS.md).  A fused chain state folds its arena maintenance
-into the same loop: inserts and hazard landings re-sort the arena
-(cond-gated ``chain_maybe_compact``) only when the dirty tail outgrows the
-dense window, and each epoch's ``rebuild_autostart`` freezes the old arena
-fully sorted before the cursor scan.  State
-buffers are **donated**
-(``donate_argnums``) so XLA updates tables in place instead of copying them
-every step, and the host polls ``rebuild_done`` only every ``poll_every``
-steps (default 32) — zero ``device_get`` round-trips on the other K-1 steps,
-so dispatch is never serialized on a device->host sync.
+The steady state is **on-device**: the jitted step performs the op batch
+and one rebuild transition, and holds nothing else.  With a ``fused``
+DHashState the whole surface inside that step is kernel-backed — lookup,
+insert, DELETE, the rebuild chunk extraction, and the hazard landing all
+run through the Pallas probe/claim/extract kernels.  The rebuild-epoch
+ordered lookup/delete are single-pass for ALL THREE fused backends (linear
+probe2, its twochoice analogue, and the chain backend's arena-sorted
+chain_probe2), and the two-level tile map keeps them single-pass even when
+the rebuild target is a grown table — so a capacity-increasing rehash
+sustains the same step rate as a same-size one (see docs/KERNELS.md).  A
+fused chain state folds its arena maintenance into the same loop: inserts
+and hazard landings re-sort the arena (cond-gated ``chain_maybe_compact``)
+only when the dirty tail outgrows the dense window, and ``rebuild_start``
+freezes the old arena fully sorted before each epoch's cursor scan.  State
+buffers are **donated** (``donate_argnums``) so XLA updates tables in
+place instead of copying them every step, and the host polls
+``rebuild_done`` only every ``poll_every`` steps (default 32) — zero
+``device_get`` round-trips on the other K-1 steps, so dispatch is never
+serialized on a device->host sync.
 
-Only a *shape-changing* rebuild (a user-supplied ``new_table`` with a
-different capacity) still needs the host: its epoch swap happens at the next
-poll via ``rebuild_finish`` — up to K-1 steps late, which is safe because a
-completed-but-unswapped rebuild still answers every op correctly through the
-ordered check.
+**The epoch swap runs at the poll** (the paper's Alg. 3 lines 41-46): when
+the poll reads ``rebuild_done``, ``dhash.epoch_swap`` swaps the two tables'
+pytrees on the host, an O(1) pointer swap whatever their shapes, and in
+continuous-rebuild mode ``rebuild_start`` opens the next epoch on a fresh
+hash function (the first one at a poll before the first step).  Neither
+adds a device read to the poll.  The swap is up to K-1 steps late, which
+is safe because a completed-but-unswapped rebuild still answers every op
+correctly through the ordered check; those steps run no migration.  A swap inside
+the step would cost whole-table passes on every step whether or not an
+epoch ends (``finish_same_shape`` selects every table array, and a
+``lax.cond`` around it makes XLA copy the tables into its branches).
 
 Ownership note: the engine donates its state buffers to the jitted step, so
 after the first ``step()`` the ``DHashState`` passed to the constructor must
@@ -50,6 +50,7 @@ step number as the span argument ``step``) holding ``dhash.engine.put``
 (the operands to the device), ``dhash.engine.dispatch`` (the jitted call)
 and, once every ``poll_every`` steps, ``dhash.engine.poll``; each
 ``lookup`` is ``dhash.engine.lookup`` holding ``put`` and ``dispatch``.
+A poll that swaps the epochs holds ``dhash.engine.swap``.
 They are ``jax.profiler.TraceAnnotation``s, so they land in a profiler
 trace on the device ops' clock and cost about a microsecond each
 when no profiler runs; nothing else records them.
@@ -57,7 +58,10 @@ when no profiler runs; nothing else records them.
 ``DHashStackEngine`` is the multi-table variant: it drives a
 ``dhash.make_stack`` state — T independent tables vmapped inside one jitted
 step, each with its OWN rebuild epoch (staggered live rehashes across
-tenants) — through the same donation + K-step polling treatment.
+tenants) — through the same donation + K-step polling treatment.  Its
+per-table swaps cannot be pointer swaps, so its step keeps
+``finish_same_shape`` and ``rebuild_autostart``; so does the policy engine's
+step for same-shape tables.
 """
 from __future__ import annotations
 
@@ -144,6 +148,17 @@ def _policy_engine_step(d, pol, lk, ik, iv, dk, imask, dmask, *,
     return d, pol, (found, vals, ok_i, ok_d)
 
 
+def _engine_step(d, lk, ik, iv, dk, imask, dmask):
+    """The plain engine step: the op batch and one rebuild transition.  It
+    holds no epoch swap and no rebuild start: both run at the host's poll
+    (``DHashEngine._poll``)."""
+    found, vals = dhash.lookup(d, lk)
+    d, ok_i = dhash.insert(d, ik, iv, imask)
+    d, ok_d = dhash.delete(d, dk, dmask)
+    d = dhash.rebuild_step(d)
+    return d, (found, vals, ok_i, ok_d)
+
+
 @dataclass
 class DHashEngine:
     """Drives a DHashState: user op batches + background rebuild progress."""
@@ -154,7 +169,7 @@ class DHashEngine:
     poll_every: int = DEFAULT_POLL_EVERY   # host polls 1 of every K steps
     policy: elastic.ElasticPolicy | None = None   # elastic capacity decisions
     _stats: EngineStats = field(default_factory=EngineStats, repr=False)
-    _step_fns: dict = field(default_factory=dict, init=False, repr=False)
+    _step_fn: Callable | None = field(default=None, init=False, repr=False)
     _poll_fn: Callable | None = field(default=None, init=False, repr=False)
     _lookup_fn: Callable | None = field(default=None, init=False, repr=False)
     _count_fn: Callable | None = field(default=None, init=False, repr=False)
@@ -176,35 +191,19 @@ class DHashEngine:
         else:
             self._poll_fn = jax.jit(
                 lambda d: (d.epoch, d.rebuilding, dhash.rebuild_done(d)))
+        # donate the state: tables update in place, no per-step copy (a jit
+        # of this engine's own, so its cache counts this engine's retraces)
+        self._step_fn = jax.jit(partial(_engine_step), donate_argnums=(0,))
         self._lookup_fn = jax.jit(dhash.lookup)
         self._count_fn = jax.jit(dhash.count_items)
         self._epoch0 = int(jax.device_get(self.state.epoch))
 
     # -- jitted step ---------------------------------------------------------
 
-    def _get_step_fn(self, swap_on_device: bool):
-        key = swap_on_device
-        if key not in self._step_fns:
-            autostart = swap_on_device and self.continuous_rebuild
-
-            def fused(d, lk, ik, iv, dk, imask, dmask):
-                found, vals = dhash.lookup(d, lk)
-                d, ok_i = dhash.insert(d, ik, iv, imask)
-                d, ok_d = dhash.delete(d, dk, dmask)
-                d = dhash.rebuild_step(d)
-                if swap_on_device:
-                    d = dhash.finish_same_shape(d)   # on-device epoch swap
-                    if autostart:
-                        d = dhash.rebuild_autostart(d)
-                return d, (found, vals, ok_i, ok_d)
-
-            # donate the state: tables update in place, no per-step copy
-            self._step_fns[key] = jax.jit(fused, donate_argnums=(0,))
-        return self._step_fns[key]
-
     def _swap_on_device(self) -> bool:
-        """True iff old/new share static shapes, so the epoch swap can run
-        inside the jitted step (host metadata only — no device sync)."""
+        """True iff old/new share static shapes, so the policy step can run
+        the epoch swap on the device (host metadata only — no device
+        sync)."""
         old, new = self.state.old, self.state.new
         if (jax.tree_util.tree_structure(old)
                 != jax.tree_util.tree_structure(new)):
@@ -218,6 +217,12 @@ class DHashEngine:
     def step(self, lookup_keys, ins_keys, ins_vals, del_keys,
              ins_mask=None, del_mask=None):
         with span("dhash.engine.step", step=self._stats.steps):
+            if self.continuous_rebuild and self._stats.steps == 0:
+                # the first epoch opens here, not in the constructor: the
+                # caller still holds the state there, so a fresh table
+                # would raise the peak memory by one table
+                with span("dhash.engine.poll"):
+                    self._poll()
             lk, ik, iv, dk, im, dm = _put(lookup_keys, ins_keys, ins_vals,
                                           del_keys, ins_mask, del_mask)
             with span("dhash.engine.dispatch"):
@@ -226,18 +231,18 @@ class DHashEngine:
                         self.state, self.policy, lk, ik, iv, dk, im, dm,
                         swap_on_device=self._swap_on_device())
                 else:
-                    fn = self._get_step_fn(self._swap_on_device())
-                    self.state, out = fn(self.state, lk, ik, iv, dk, im, dm)
+                    self.state, out = self._step_fn(self.state, lk, ik, iv,
+                                                    dk, im, dm)
             _count_and_poll(self, lk.size + ik.size + dk.size)
             return out
 
     # -- host-side polling (1 of every K steps) ------------------------------
 
     def _poll(self):
-        """One batched device_get: refresh stats; finish a shape-changing
-        rebuild; (re)start a rebuild in continuous mode if the on-device
-        autostart could not (shape-changing tables); apply the policy's
-        published resize plan (policy engines)."""
+        """One batched device_get: refresh stats; swap the epochs of a
+        completed rebuild (span ``dhash.engine.swap``); start the next
+        rebuild in continuous mode; apply the policy's published resize
+        plan (policy engines)."""
         if self.policy is not None:
             epoch, rebuilding, done, wg, ws, tgt = (
                 int(x) for x in
@@ -249,8 +254,11 @@ class DHashEngine:
         self._stats.host_syncs += 1
         self._last_poll_step = self._stats.steps
         if done:
-            # only reachable when the on-device swap wasn't applicable
-            self.state = dhash.rebuild_finish(self.state)
+            # the epoch swap: an O(1) pointer swap of the two tables (a
+            # policy step swaps same-shape tables on the device itself, so
+            # there this is a shape-changing rebuild)
+            with span("dhash.engine.swap"):
+                self.state = dhash.epoch_swap(self.state)
             epoch += 1
             rebuilding = False
             # the published plan predates the swap we just applied — drop
@@ -268,7 +276,7 @@ class DHashEngine:
                 self.rebuild_seed += 1
         self._stats.rebuilds_completed = epoch - self._epoch0
         if self.continuous_rebuild and not rebuilding:
-            self.request_rebuild()
+            self._start_rebuild()
         if self.policy is not None and not rebuilding and (wg or ws):
             self._apply_resize(grow=bool(wg), target_entries=tgt)
 
@@ -327,11 +335,16 @@ class DHashEngine:
             return False  # -EBUSY
         if new_table is not None:
             new_table = jax.tree_util.tree_map(jnp.copy, new_table)  # own it
+        self._start_rebuild(seed=seed, new_table=new_table)
+        return True
+
+    def _start_rebuild(self, *, seed: int | None = None, new_table=None):
+        """``rebuild_start`` on a state the caller knows is not
+        rebuilding (no device sync)."""
         self.state = dhash.rebuild_start(
             self.state, new_table,
             seed=self.rebuild_seed if seed is None else seed)
         self.rebuild_seed += 1
-        return True
 
     def lookup(self, keys):
         return _lookup(self._lookup_fn, self.state, keys)
@@ -341,8 +354,8 @@ class DHashEngine:
         return int(jax.device_get(self._count_fn(self.state)))
 
     def _step_cache_size(self) -> int:
-        """Total jit cache entries across step variants (retrace detector)."""
-        return sum(f._cache_size() for f in self._step_fns.values())
+        """Jit cache entries of the step (retrace detector)."""
+        return self._step_fn._cache_size()
 
 
 @dataclass

@@ -193,6 +193,18 @@ def probe_insert(tkey: jax.Array, tval: jax.Array, tstate: jax.Array,
 
 
 @partial(jax.jit, static_argnames=("max_probes",))
+def probe_insert_either(a, b, into_b: jax.Array, h0: jax.Array,
+                        keys: jax.Array, vals: jax.Array, mask: jax.Array, *,
+                        max_probes: tuple[int, int] = (64, 64)):
+    """``probe_insert`` into the (key, val, state) triple ``b`` where
+    ``into_b`` holds, else into ``a`` (``window.insert_either``: one claim
+    loop carries both tables, the claim kernel reads the target).  The
+    same caller contract.  Returns (a', b', ok[Q])."""
+    return window.insert_either(a, b, into_b, h0, keys, vals, mask,
+                                max_probes, claim_pass=_kernel_claim)
+
+
+@partial(jax.jit, static_argnames=("max_probes",))
 def insert_retry_share(tkey: jax.Array, tstate: jax.Array, h0: jax.Array,
                        keys: jax.Array, mask: jax.Array, *,
                        max_probes: int = 64):

@@ -13,7 +13,9 @@ the hash skew.
 Both op sets of the linear backend run this math: ``core/buckets.py``
 evaluates ``probe``/``claim`` as plain XLA, ``kernels/probe.py`` runs the
 same functions inside Pallas kernels.  ``insert`` is the insert protocol
-both share, with the claim pass passed in.
+both share, with the claim pass passed in; ``insert_either`` runs the same
+protocol on one of two tables chosen by a traced flag, with no whole table
+through a conditional.
 """
 from __future__ import annotations
 
@@ -123,21 +125,76 @@ def insert(tkey, tval, tstate, h0, keys, vals, mask, max_probes: int,
     c = tkey.shape[0]
 
     def body(carry):
-        k, v, s, pending, ok = carry
-        hit, free = claim_pass(k, s, h0, keys, max_probes)
-        want = pending & (hit < 0) & (free >= 0)
-        phys = jnp.where(want, slot(h0, free) % c, c)
-        won = settle(phys, free - h0 % LANES, c)
-        wp = jnp.where(won, phys, c)
-        return (k.at[wp].set(keys, mode="drop"),
-                v.at[wp].set(vals, mode="drop"),
-                s.at[wp].set(LIVE, mode="drop"),
-                want & ~won, ok | won)
+        t, pending, ok = carry
+        hit, free = claim_pass(t[0], t[2], h0, keys, max_probes)
+        want, won, wp = _round(hit, free, h0, pending, c, c)
+        return _put(t, wp, keys, vals), want & ~won, ok | won
 
-    k, v, s, _, ok = jax.lax.while_loop(
-        lambda carry: carry[3].any(), body,
-        (tkey, tval, tstate, mask, jnp.zeros(keys.shape, bool)))
-    return k, v, s, ok
+    t, _, ok = jax.lax.while_loop(
+        lambda carry: carry[1].any(), body,
+        ((tkey, tval, tstate), mask, jnp.zeros(keys.shape, bool)))
+    return (*t, ok)
+
+
+def insert_either(a, b, into_b, h0, keys, vals, mask, max_probes,
+                  claim_pass=dense_claim):
+    """``insert`` into table ``b`` where the scalar ``into_b`` holds, else
+    into ``a``: ``a`` and ``b`` are (key, val, state) triples, of sizes that
+    may differ, ``max_probes`` their pair of probe bounds and ``h0`` the
+    target's start slots.
+
+    One claim loop carries both tables.  Each round's claim pass reads the
+    target inside a ``lax.cond`` whose outputs are the [Q] lanes alone, and
+    each table's scatters sit in a loop of one round for the target and
+    none for the other (``_put_if``).  So no whole table passes through a
+    conditional branch that hands it on unwritten, which is where XLA
+    inserts whole-table copies, and the other table costs no scatter: on
+    the TPU a scatter whose indices are all out of range costs as much as
+    one that writes.  Returns (a', b', ok[Q])."""
+    ca, cb = a[0].shape[0], b[0].shape[0]
+    c = jnp.where(into_b, cb, ca)
+    none = max(ca, cb)
+
+    def body(carry):
+        a, b, pending, ok = carry
+        hit, free = jax.lax.cond(
+            into_b,
+            lambda: claim_pass(b[0], b[2], h0, keys, max_probes[1]),
+            lambda: claim_pass(a[0], a[2], h0, keys, max_probes[0]))
+        want, won, wp = _round(hit, free, h0, pending, c, none)
+        return (_put_if(~into_b, a, wp, keys, vals),
+                _put_if(into_b, b, wp, keys, vals), want & ~won, ok | won)
+
+    a, b, _, ok = jax.lax.while_loop(
+        lambda carry: carry[2].any(), body,
+        (a, b, mask, jnp.zeros(keys.shape, bool)))
+    return a, b, ok
+
+
+def _round(hit, free, h0, pending, c, none: int):
+    """One claim round: the pending keys absent from the table with a free
+    lane (``want``), those that keep their slot (``won``, ``settle``) and
+    the slots they write (``none``, out of range, elsewhere)."""
+    want = pending & (hit < 0) & (free >= 0)
+    phys = jnp.where(want, slot(h0, free) % c, none)
+    won = settle(phys, free - h0 % LANES, none)
+    return want, won, jnp.where(won, phys, none)
+
+
+def _put_if(on, t, idx, keys, vals):
+    """``_put`` where the scalar ``on`` holds, as a loop of at most one
+    round: the table stays in place either way, with no conditional."""
+    return jax.lax.while_loop(
+        lambda c: c[1], lambda c: (_put(c[0], idx, keys, vals), False),
+        (t, on))[0]
+
+
+def _put(t, idx, keys, vals):
+    """Write ``keys``/``vals`` LIVE into the (key, val, state) triple ``t``
+    at ``idx``; out-of-range indices are dropped."""
+    k, v, s = t
+    return (k.at[idx].set(keys, mode="drop"), v.at[idx].set(vals, mode="drop"),
+            s.at[idx].set(LIVE, mode="drop"))
 
 
 def retry_share(tkey, tstate, h0, keys, mask, max_probes: int,

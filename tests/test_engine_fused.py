@@ -1,11 +1,12 @@
 """Engine acceptance tests for the on-device steady state: K-step deferred
 polling, buffer donation without retraces, zero host syncs between polls,
-on-device epoch swap + continuous-rebuild autostart, and the fused
+the epoch swap and the next rebuild start at the poll, and the fused
 (Pallas-kernel) state driven end-to-end against a dict oracle."""
 from __future__ import annotations
 
 import jax
 import numpy as np
+import pytest
 
 from repro.core import dhash
 from repro.core.engine import DHashEngine
@@ -58,8 +59,8 @@ def test_donation_no_retrace():
 
 
 def test_deferred_poll_never_misses_epoch_swap():
-    """K-step deferred polling: the swap happens on-device the step the
-    rebuild completes; item counts are conserved and every key stays
+    """K-step deferred polling: the swap happens at the first poll after
+    the rebuild completes; item counts are conserved and every key stays
     readable through the whole rebuild window."""
     rng = np.random.default_rng(0)
     eng = DHashEngine(dhash.make("linear", capacity=512, chunk=32, seed=3),
@@ -79,7 +80,7 @@ def test_deferred_poll_never_misses_epoch_swap():
         assert bool((np.asarray(v) == keys[:64] * 2).all())
         steps += 1
         assert steps < 500
-    # swap happened on-device (possibly between host polls) and lost nothing
+    # the poll swapped the epochs and lost nothing
     assert int(jax.device_get(eng.state.epoch)) == epoch0 + 1
     # the host only polled every K steps during the whole rebuild
     assert eng._stats.host_syncs - syncs0 <= steps // eng.poll_every + 1
@@ -87,9 +88,51 @@ def test_deferred_poll_never_misses_epoch_swap():
     assert eng.stats.rebuilds_completed == 1
 
 
+@pytest.mark.parametrize("fused", [False, True], ids=["jnp", "fused"])
+def test_swap_lands_at_first_poll_after_done(fused):
+    """The epoch swap runs at the first poll after ``rebuild_done`` holds,
+    not before: through the steps between, every key stays readable (the
+    ordered check answers for the completed, unswapped rebuild) and inserts
+    keep landing; counts are conserved, and each epoch of continuous mode
+    runs on a fresh hash function."""
+    k = 12
+    eng = DHashEngine(dhash.make("linear", capacity=256, chunk=32, seed=5,
+                                 fused=fused),
+                      continuous_rebuild=True, poll_every=k)
+    rng = np.random.default_rng(8)
+    keys = rng.choice(100_000, 150, replace=False).astype(I32)
+    eng.step(keys, keys, keys * 2, _z1(), del_mask=np.zeros(1, bool))
+    done_fn = jax.jit(dhash.rebuild_done)
+    seeds = [np.asarray(jax.device_get(eng.state.new.hfn.seeds))]
+    swaps, done_at, waited = [], None, 0
+    for step in range(2, 100):
+        extra = np.array([200_000 + step], I32)
+        f, v, ok_i, _ = eng.step(keys, extra, extra * 2, _z1(),
+                                 del_mask=np.zeros(1, bool))
+        assert bool(np.asarray(f).all()), f"lookup missed at step {step}"
+        assert bool((np.asarray(v) == keys * 2).all())
+        assert bool(np.asarray(ok_i).all())
+        epoch = int(jax.device_get(eng.state.epoch))
+        if epoch > len(swaps):                   # the poll swapped
+            assert step % k == 0 and done_at is not None
+            assert step == -(-done_at // k) * k
+            swaps.append(step)
+            done_at = None
+            seeds.append(np.asarray(jax.device_get(eng.state.new.hfn.seeds)))
+        elif bool(jax.device_get(done_fn(eng.state))):
+            done_at = step if done_at is None else done_at
+            waited += 1
+    assert len(swaps) >= 2 and waited > 0
+    assert eng.stats.rebuilds_completed == len(swaps)
+    assert eng.count() == 150 + 98
+    assert len({s.tobytes() for s in seeds}) == len(seeds), \
+        "an epoch reused a hash function"
+
+
 def test_continuous_autostart_on_device_and_reseed():
     """Continuous mode cycles rebuilds with ZERO host involvement between
-    polls; each epoch gets a fresh on-device-derived hash function."""
+    polls (the poll swaps and starts the next rebuild); each epoch gets a
+    fresh hash function."""
     eng = DHashEngine(dhash.make("linear", capacity=256, chunk=64, seed=1),
                       continuous_rebuild=True, poll_every=32)
     keys = np.arange(1, 101, dtype=I32)
@@ -138,10 +181,11 @@ def test_fused_engine_matches_dict_oracle():
 
 def test_zero_host_sync_full_fused_write_epoch(monkeypatch):
     """Acceptance (PR 2): a FUSED state driving complete rebuild epochs —
-    extract kernel -> landing via the claim kernel -> on-device swap — with
-    interleaved lookup/insert/DELETE batches performs ZERO host syncs
+    extract kernel -> landing via the claim kernel -> swap at the poll —
+    with interleaved lookup/insert/DELETE batches performs ZERO host syncs
     between poll intervals: exactly one batched device_get per poll_every
-    steps, while at least one full epoch completes entirely on-device."""
+    steps (the swap and the next rebuild start add none), while at least
+    one full epoch completes."""
     eng = DHashEngine(dhash.make("linear", capacity=256, chunk=64, seed=9,
                                  fused=True),
                       continuous_rebuild=True, poll_every=8)
@@ -165,7 +209,7 @@ def test_zero_host_sync_full_fused_write_epoch(monkeypatch):
     monkeypatch.undo()
     # steps 2..25 -> polls at steps 8, 16, 24 only
     assert calls["n"] == 3, calls
-    # the epochs cycled on-device while the host stayed silent
+    # the epochs cycled while the host only polled
     assert eng.stats.rebuilds_completed >= 1
 
 

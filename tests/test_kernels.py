@@ -242,6 +242,42 @@ def test_linear_insert_tables_match_oracle(max_probes, fused):
     assert live.mean() > 0.95 and runs.max() > 64
 
 
+@pytest.mark.parametrize("fused", [False, True], ids=["jnp", "fused"])
+@pytest.mark.parametrize("to_new", [False, True],
+                         ids=["outside_rehash", "mid_rehash"])
+def test_insert_either_matches_oracle(to_new, fused):
+    """The linear backend's two-table insert writes its target exactly as
+    the step-by-step oracle does (tables slot for slot, the same ok flags:
+    duplicates in the batch, keys already in the target, masked-out keys)
+    and leaves the other table bit-identical; old and new differ in size."""
+    from repro.core import backend
+    rng = np.random.default_rng(17)
+    told, kold, _ = _table(1 << 10, 500, seed=31)
+    tnew, knew, _ = _table(1 << 11, 700, seed=32)
+    fresh = jnp.asarray(rng.choice(np.arange(20_000_000, 30_000_000), 300,
+                                   replace=False).astype(np.int32))
+    batch = jnp.concatenate([fresh, fresh[:60], kold[:40], knew[:40]])
+    vals = batch * 11
+    mask = jnp.ones(batch.shape, bool).at[-30:].set(False)
+    tgt, other = (tnew, told) if to_new else (told, tnew)
+    be = backend.get("linear")
+    hook = be.insert_either_fused if fused else be.insert_either
+    t_old, t_new, ok = jax.jit(hook)(told, tnew, jnp.asarray(to_new), batch,
+                                     vals, mask)
+    got, kept = (t_new, t_old) if to_new else (t_old, t_new)
+    h0 = hashing.bucket_of(tgt.hfn, batch, tgt.capacity)
+    *want, ok_ref = ref.probe_insert_ref(
+        tgt.key, tgt.val, tgt.state, h0, batch, vals,
+        buckets.batch_winners(batch, mask), tgt.max_probes)
+    np.testing.assert_array_equal(np.asarray(ok), np.asarray(ok_ref))
+    assert 0 < int(ok.sum()) < int(mask.sum())
+    for a, b in zip((got.key, got.val, got.state), want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip((kept.key, kept.val, kept.state),
+                    (other.key, other.val, other.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_ordered_lookup_fused_matches_ref():
     """The fused old->hazard->new kernel path == ordered_lookup_ref."""
     rng = np.random.default_rng(7)

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +120,54 @@ def test_kernel_entry_compiles_for_v5e(one_chip, on_tpu, name):
     compiled = jax.jit(fn).lower(
         *_args(one_chip, name)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _full_table_ops(compiled) -> dict:
+    """``{(computation, op): count}`` of the whole-table (``s32[C]``)
+    ``copy`` and ``select`` ops in a compiled program, fusions included."""
+    counts, comp = {}, None
+    for line in compiled.as_text().splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if head and not line.startswith(" "):
+            comp = head.group(1)
+            continue
+        op = re.search(rf"= s32\[{C}\]\{{[^}}]*\}} (copy|select)\(", line)
+        if op:
+            counts[comp, op.group(1)] = counts.get((comp, op.group(1)), 0) + 1
+    return counts
+
+
+def _deployment_state(sharding):
+    """The paper cell's table (2^26 slots, chunk 4096, the jnp op set) as
+    abstract operands on the described chip."""
+    st = jax.eval_shape(lambda: dhash.make("linear", capacity=C // 2,
+                                           chunk=CH, seed=1, fused=False))
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        st)
+
+
+@pytest.mark.parametrize("program", ["step", "step_continuous", "lookup"])
+def test_engine_programs_move_no_whole_table(one_chip, on_tpu, program):
+    """The census of whole-table work: the engine's step (8192 inserts and
+    deletes, 65536 lookups), with and without continuous rebuild, and the
+    jitted lookup hold no whole-table ``select`` (the step holds no epoch
+    swap) and no whole-table ``copy`` (no table passes through a
+    conditional branch that hands it on unwritten: the insert and the
+    landing run their claim loops outside any)."""
+    def s(n, dt=I32):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+    st, ins = _deployment_state(one_chip), 8192
+    if program == "lookup":
+        fn, args = jax.jit(dhash.lookup), (st, s(Q))
+    else:
+        from repro.core.engine import DHashEngine
+        eng = DHashEngine(dhash.make("linear", capacity=64, chunk=CH,
+                                     fused=False),
+                          continuous_rebuild=program == "step_continuous")
+        fn = eng._step_fn
+        args = (st, s(Q), s(ins), s(ins), s(ins), s(ins, B), s(ins, B))
+    assert _full_table_ops(fn.lower(*args).compile()) == {}
 
 
 @pytest.mark.parametrize("name", ["linear", "twochoice", "cuckoo", "chain"])

@@ -34,17 +34,39 @@ def _operands(shape):
     return k, k, k, k, m, m
 
 
+# the plain engine's step holds no epoch swap or rebuild start: the poll
+# runs them, under the host span ``dhash.engine.swap``
+STEP_SCOPES = SCOPES - {"dhash.finish_same_shape", "dhash.rebuild_autostart"}
+
+
 @pytest.mark.parametrize("kind,kw,want", [
-    ("jnp", {}, SCOPES),
-    ("fwd_hazard", {"fwd_hazard": True}, SCOPES),
+    ("jnp", {}, STEP_SCOPES),
+    ("fwd_hazard", {"fwd_hazard": True}, STEP_SCOPES),
     # the fused ordered probe and delete do the hazard check in-kernel
-    ("fused", {"fused": True}, SCOPES - {"dhash.hazard"}),
+    ("fused", {"fused": True}, STEP_SCOPES - {"dhash.hazard"}),
 ])
-def test_engine_step_hlo_names_every_operation(kind, kw, want):
-    eng = DHashEngine(dhash.make("linear", capacity=256, chunk=32, seed=3,
-                                 **kw), continuous_rebuild=True)
-    fn = eng._get_step_fn(eng._swap_on_device())
+def test_engine_step_hlo_names_every_operation(kind, kw, want, tmp_path):
+    keys = np.arange(64, dtype=I32)
+    d, _ = jax.jit(dhash.insert)(
+        dhash.make("linear", capacity=64, chunk=32, seed=3, **kw), keys, keys)
+    eng = DHashEngine(d, continuous_rebuild=True, poll_every=4)
+    fn = eng._step_fn
     assert _scopes(fn.lower(eng.state, *_operands((64,))).compile()) == want
+    # 128 slots in 4 chunks, each holding some of the 64 keys: the rebuild
+    # started at construction takes 8 transitions, so the poll of step 8
+    # swaps the epochs, and the next rebuild ends after step 16
+    off = np.zeros(64, bool)
+    eng.step(keys, keys, keys, keys, off, off)   # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(9):                    # steps 2..10: polls at 4, 8
+            eng.step(keys, keys, keys, keys, off, off)
+        jax.block_until_ready(eng.state)
+    spans = _host_spans(tmp_path)
+    polls = [s for s in spans if s[2] == "dhash.engine.poll"]
+    swaps = [s for s in spans if s[2] == "dhash.engine.swap"]
+    assert len(polls) == 2 and len(swaps) == 1
+    assert _inside(swaps[0], polls[1:]) == 1
+    assert eng.stats.rebuilds_completed == 1
 
 
 def test_lookup_hlo_names_lookup_and_hazard():
