@@ -99,16 +99,6 @@ ITERATIONS = [
                     "receives S*Q candidate slots though only Q/S are real "
                     "-> S x wasted probe work and wire bytes",
          fn=lambda: service_cell({})),
-    dict(cell="dhash-paper/service", name="route_cap4",
-         hypothesis="cap routing buffers at 4*Q/S: wire bytes and remote "
-                    "batch sizes drop S/4=4x; predict t_mem ~4x down "
-                    "(probe work scales with received batch)",
-         fn=lambda: service_cell({"route_cap_factor": 4.0})),
-    dict(cell="dhash-paper/service", name="route_cap2",
-         hypothesis="tighter cap 2*Q/S: another 2x on buffers; overflow "
-                    "probability still negligible for the uniform owner "
-                    "hash (binomial tail)",
-         fn=lambda: service_cell({"route_cap_factor": 2.0})),
 ]
 
 

@@ -32,15 +32,16 @@ batch wins).  It shares no code with ``core/buckets.py`` or
 ``kernels/ref.py``.  Keys and values are derived from ``--seed``.
 
 ``--chips 4`` runs only the sharded path instead: ``distributed.
-make_stacked`` over a mesh of the four chips, 2^25 keys per shard, the
-routed service step (``routed_service_step``) plus ``REBUILD_EXTRA`` more
-``routed_rebuild_step`` transitions per step (an odd total, so the ops meet
-a live hazard buffer on every other step) until a routed rehash completes
-on every shard, checked against the same model.  Each shard's tables are
-built on the chip that holds them; the smoke fails if a table array is not
-one shard per chip, or if a chip's peak memory after populate is above
-``PEAK_SLACK`` times one shard's tables plus the compiled insert's working
-memory.
+make_stacked`` over a mesh of the four chips, 2^25 keys per shard, filled
+through the routed insert, then driven by ``DHashEngine`` (its routed step
+per batch, the epoch swap of every shard at its poll) plus
+``REBUILD_EXTRA`` more transitions per step on every shard (an odd total,
+so the ops meet a live hazard buffer on every other step) until a rehash
+has swapped epochs on every shard and 64 steps more, checked against the
+same model.  Each shard's tables are built on the chip that holds them;
+the smoke fails if a table array is not one shard per chip, or if a chip's
+peak memory after populate is above ``PEAK_SLACK`` times one shard's
+tables plus the compiled insert's working memory.
 
 Earlier lines print one JSON object per phase (wall time, compilations,
 ``peak_bytes_in_use``, the share of queries recomputed); the last line is
@@ -368,6 +369,7 @@ def four_chips(jax, seed: int) -> None:
     from jax.sharding import PartitionSpec as P
 
     from repro.core import dhash, distributed, hashing
+    from repro.core.engine import DHashEngine
 
     devs = jax.devices()
     s = len(devs)
@@ -388,22 +390,18 @@ def four_chips(jax, seed: int) -> None:
                              out_specs=out, check_vma=False)
 
     def ins_body(st, k, v):
-        d, ok = distributed.routed_update(
+        # set-up routes at a tight cap with a compact slab: a key the slab
+        # cannot carry comes back refused, and the acks check catches it
+        d, ok, _ = distributed.routed_update(
             distributed.peel(st), k, v, jnp.ones(k.shape, bool), "shard",
-            owner, op=dhash.insert, cap=distributed.route_cap(2.0, k.size, s))
+            owner, op=dhash.insert,
+            cap=distributed.route_cap(1.25, k.size, s), spill_slack=1 / 64)
         return distributed.unpeel(d), ok
 
-    def start_body(st):
-        return distributed.unpeel(dhash.rebuild_autostart(distributed.peel(st)))
-
-    def step_body(st, lk, ik, iv, dk):
-        d, (f, v, _) = distributed.routed_service_step(
-            distributed.peel(st), lk, ik, iv, dk, "shard", owner,
-            cap_factor=2.0)
-        for _ in range(REBUILD_EXTRA):
-            d = distributed.routed_rebuild_step(d, "shard")
-        d = dhash.finish_same_shape(d)
-        return distributed.unpeel(d), f, v, d.epoch[None]
+    def more_body(st):
+        return distributed.unpeel(jax.lax.fori_loop(
+            0, REBUILD_EXTRA, lambda _, dd: dhash.rebuild_step(dd),
+            distributed.peel(st)))
 
     def per_key(x):
         return jax.device_put(x, shard)
@@ -447,36 +445,43 @@ def four_chips(jax, seed: int) -> None:
 
     epoch_notice(s, n)
     with Phase(jax, "traffic_sharded", chips=s) as ph:
-        st = jax.jit(smap(start_body, 1, 1), donate_argnums=0)(st)
-        fn = jax.jit(smap(step_body, 5, 4), donate_argnums=0)
-        step, done_at, checked, t0 = 0, None, 0, time.perf_counter()
-        while done_at is None or step < done_at + AFTER:
+        eng = DHashEngine(st, continuous_rebuild=True, rebuild_seed=seed + 7,
+                          owner=owner)
+        del st
+        more = jax.jit(smap(more_body, 1, 1), donate_argnums=0)
+        step, swapped_at, checked, t0 = 0, None, 0, time.perf_counter()
+        while swapped_at is None or step < swapped_at + AFTER:
             look, ins_ids, iv, del_ids = traffic.step()
-            sample = step % CHECK_EVERY == 0 or (done_at is not None
+            sample = step % CHECK_EVERY == 0 or (swapped_at is not None
                                                  and step % 8 == 0)
             if sample:
                 exp_f, exp_v = model.lookup(look)
-            model.insert(ins_ids, iv)
-            model.delete(del_ids)
-            st, f, v, ep = fn(st, *(per_key(a) for a in (
-                keys.key(look), keys.key(ins_ids), iv, keys.key(del_ids))))
+            exp_i = model.insert(ins_ids, iv)
+            exp_d = model.delete(del_ids)
+            out = eng.step(keys.key(look), keys.key(ins_ids), iv,
+                           keys.key(del_ids))
+            eng.state = more(eng.state)
             if sample:
-                check(f"step {step} found", jax.device_get(f), exp_f)
-                check(f"step {step} vals", jax.device_get(v), exp_v)
+                f, v, oi, od = jax.device_get(out)
+                check(f"step {step} found", f, exp_f)
+                check(f"step {step} vals", v, exp_v)
+                check(f"step {step} insert ok", oi, exp_i)
+                check(f"step {step} delete ok", od, exp_d)
                 checked += 1
             step += 1
-            if done_at is None and step % 32 == 0 \
-                    and int(np.min(jax.device_get(ep))) >= 1:
-                done_at = step
+            if swapped_at is None and step % eng.poll_every == 0 \
+                    and eng.stats.rebuilds_completed >= 1:
+                swapped_at = step
             if step >= max_steps:
                 raise AssertionError(f"no routed epoch swap in {step} steps")
-        live = jax.device_get(jax.jit(dhash.stack_count_items)(st))
-        check("live count", int(np.sum(live)), int(model.present.sum()))
-        ph.extra = {"steps": step, "swap_seen_at_step": done_at,
+        live = eng.count()
+        check("live count", live, int(model.present.sum()))
+        ph.extra = {"steps": step, "swap_seen_at_step": swapped_at,
                     "checked_steps": checked,
                     "steps_per_s": step / (time.perf_counter() - t0),
-                    "step_is": STEP_IS,
-                    "live_per_shard": np.asarray(live).tolist(),
+                    "step_is": STEP_IS, "live_keys": live,
+                    "routed_lanes": eng.stats.routed_lanes,
+                    "routed_peak_load": eng.stats.routed_peak_load,
                     "peak_bytes_per_device": peak_bytes(devs)}
 
 

@@ -13,8 +13,6 @@ class DHashServiceConfig:
     chunk: int = 4096                     # rebuild chunk (hazard buffer)
     lookups_per_step: int = 1 << 16       # per shard
     updates_per_step: int = 1 << 13       # per shard (insert + delete each)
-    route_cap_factor: float = 0.0         # 0 = overflow-proof cap=Q (baseline);
-                                          # >0: cap = factor*Q/S (see §Perf)
     fwd_hazard: bool = False              # hazard via MIGRATED-slot forwarding
 
 

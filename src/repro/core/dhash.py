@@ -491,11 +491,14 @@ def rebuild_finish(d: DHashState) -> DHashState:
 
 def epoch_swap(d: DHashState) -> DHashState:
     """``rebuild_finish`` for a caller that has read ``rebuild_done`` on the
-    host itself (the engine's poll): the swap alone, with no device sync."""
+    host itself (the engine's poll): the swap alone, with no device sync.
+    Every field keeps its shape and sharding, so it swaps one table or a
+    stack whose tables all finished (``distributed.make_stacked``)."""
     # probe telemetry is per-table-generation: a fresh epoch samples afresh
-    return replace(d, old=d.new, new=d.old, cursor=jnp.asarray(0, I32),
-                   rebuilding=jnp.asarray(False), epoch=d.epoch + 1,
-                   lookups=jnp.asarray(0, I32), expensive=jnp.asarray(0, I32))
+    return replace(d, old=d.new, new=d.old, cursor=jnp.zeros_like(d.cursor),
+                   rebuilding=jnp.zeros_like(d.rebuilding), epoch=d.epoch + 1,
+                   lookups=jnp.zeros_like(d.lookups),
+                   expensive=jnp.zeros_like(d.expensive))
 
 
 @jax.named_scope("dhash.finish_same_shape")
@@ -546,20 +549,24 @@ def rebuild_autostart(d: DHashState) -> DHashState:
     ``rebuild_start`` (stack engines, the policy engine): combined with
     ``finish_same_shape`` their steady state never leaves the accelerator.
     Valid when old/new share static shapes (same-capacity rebuilds)."""
+    return jax.lax.cond(d.rebuilding, lambda dd: dd,
+                        lambda dd: rebuild_reopen(dd, dd.epoch + 1), d)
+
+
+def rebuild_reopen(d: DHashState, salt: jax.Array) -> DHashState:
+    """Jitted rebuild start into the standby table ``d.new``, cleared and
+    reseeded on the device from ``salt`` (``hashing.reseed``): no table is
+    allocated.  Caller contract: not rebuilding, and old/new share static
+    shapes (the retired table of the last epoch is the standby)."""
     be = _be(d)
-
-    def go(dd: DHashState):
-        new = be.clear(dd.new)
-        new = be.reseed(new, dd.epoch + 1)
-        old = dd.old
-        if dd.fused and be.freeze_old is not None:
-            # same pre-epoch maintenance as the host-level rebuild_start:
-            # sort + reclaim once per epoch, before the cursor scan begins
-            old = be.freeze_old(old)
-        return replace(dd, old=old, new=new, cursor=jnp.asarray(0, I32),
-                       rebuilding=jnp.asarray(True))
-
-    return jax.lax.cond(d.rebuilding, lambda dd: dd, go, d)
+    new = be.reseed(be.clear(d.new), salt)
+    old = d.old
+    if d.fused and be.freeze_old is not None:
+        # same pre-epoch maintenance as the host-level rebuild_start:
+        # sort + reclaim once per epoch, before the cursor scan begins
+        old = be.freeze_old(old)
+    return replace(d, old=old, new=new, cursor=jnp.zeros_like(d.cursor),
+                   rebuilding=jnp.ones_like(d.rebuilding))
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,22 @@ table sharded (one leaf-shard per device along ``axis``) and queries sharded
 along their batch dim.  Every shard-local table op dispatches through the
 ``BucketBackend`` descriptor registry (core/backend.py), so any registered
 backend — fused or jnp — shards without changes here.
+
+**The served path is exact.**  ``routed_service_step`` (the step
+``DHashEngine`` runs on a sharded stack) routes every batch at the full
+width Q per owner row, so every key of any key set reaches its owner.  The
+batch is split into contiguous per-device slices and the exchange keeps
+source order (row j of the received buffer came from device j, each row
+in batch order), so the flattened buffer an owner serves is its keys in
+global batch order: the earlier of two copies of a key wins, as on one
+chip.  The price is padding: an owner probes S·Q lanes for about Q keys.
+A capped routed op rides the spill slab (overflow-proof by default, the
+same width Q); only a compact slab can drop keys, and the op returns its
+``Route`` so the caller sees ``dropped``.
+
+Named scopes: the layout work (histogram, ranks, the send-buffer scatters,
+the unroute gathers) runs under ``dhash.route``, the ``all_to_all``s under
+``dhash.exchange``, beside ``core/dhash.py``'s operation scopes.
 """
 from __future__ import annotations
 
@@ -74,6 +90,7 @@ class Route(NamedTuple):
     slab_owner: jax.Array  # [spill_cap] i32 explicit owner id of each slab
                            # column (-1: column empty this batch)
     dropped: jax.Array   # [S] i32 EXACT per-owner keys beyond primary+slab
+    hist: jax.Array      # [S] i32 EXACT keys per owner (the owner's load)
 
 
 def route_cap(cap_factor: float, q: int, nshards: int) -> int:
@@ -113,6 +130,7 @@ def route_spill_cap(q: int, cap: int, slack: float | None = None) -> int:
     return min(worst, math.ceil(slack * q))
 
 
+@jax.named_scope("dhash.route")
 def _route(keys: jax.Array, owner: jax.Array, nshards: int,
            cap: int | None = None, spill_cap: int = 0) -> Route:
     """Group keys by owner into a [S, cap + spill_cap] send buffer — a
@@ -164,7 +182,8 @@ def _route(keys: jax.Array, owner: jax.Array, nshards: int,
         jnp.where(spilled, spill_rank, spill_cap)].set(owner, mode="drop")
     dropped = (onehot * (spilled & ~served).astype(I32)[:, None]).sum(axis=0)
     return Route(send, smask, owner, rank, kept, overflow,
-                 cap, spill_cap, spill_rank, served, slab_owner, dropped)
+                 cap, spill_cap, spill_rank, served, slab_owner, dropped,
+                 hist)
 
 
 def _route_col(rt: Route) -> jax.Array:
@@ -173,6 +192,7 @@ def _route_col(rt: Route) -> jax.Array:
     return jnp.where(rt.kept, rt.rank, rt.cap + rt.spill_rank)
 
 
+@jax.named_scope("dhash.route")
 def _route_payload(payload: jax.Array, rt: Route) -> jax.Array:
     """Scatter a per-key payload (values, masks) into the
     [S, cap + spill_cap] layout of a ``Route`` computed for the same batch
@@ -184,6 +204,7 @@ def _route_payload(payload: jax.Array, rt: Route) -> jax.Array:
         rt.owner, _route_col(rt)].set(payload, mode="drop")
 
 
+@jax.named_scope("dhash.route")
 def _unroute(resp_local: jax.Array, rt: Route, fill=None) -> jax.Array:
     """Invert a ``Route`` for a [S, cap + spill_cap] response: gather each
     key's slot (primary or slab) back to batch order.  Dropped keys take
@@ -203,46 +224,71 @@ def shard_of(keys: jax.Array, nshards: int,
     return (hashing.hash_u32(owner_hfn, keys) % jnp.uint32(nshards)).astype(I32)
 
 
+def _owner_route(keys: jax.Array, nshards: int, owner_hfn: hashing.HashFn,
+                 cap: int | None, spill_slack: float | None) -> Route:
+    """The layout of a [Q] batch over its owner shards: full width without
+    ``cap``; with it, ``cap`` columns plus the spill slab of
+    ``route_spill_cap(Q, cap, spill_slack)``."""
+    spill = 0 if cap is None else route_spill_cap(keys.shape[0], cap,
+                                                  spill_slack)
+    return _route(keys, shard_of(keys, nshards, owner_hfn), nshards, cap,
+                  spill)
+
+
+@jax.named_scope("dhash.exchange")
+def _exchange(x: jax.Array, axis: str) -> jax.Array:
+    """Row j of ``x`` to device j along ``axis``; row j of the result came
+    from device j."""
+    return lax.all_to_all(x, axis, split_axis=0, concat_axis=0)
+
+
 def routed_lookup(d: dhash.DHashState, keys: jax.Array, axis: str,
-                  owner_hfn: hashing.HashFn, cap: int | None = None):
-    """DHash lookup across shards. Call inside shard_map."""
+                  owner_hfn: hashing.HashFn, cap: int | None = None,
+                  spill_slack: float | None = None):
+    """DHash lookup across shards.  Returns ``(found, vals, route)``.  Call
+    inside shard_map.
+
+    Without ``cap`` every owner row is the batch's full width.  With it,
+    keys past their owner's cap ride the spill slab: the default slab is
+    overflow-proof, so every key is answered.  Only a compact slab
+    (``spill_slack`` in (0, 1)) drops keys; they come back not found and
+    ``route.dropped`` counts them per owner."""
     s = lax.axis_size(axis)
-    owner = shard_of(keys, s, owner_hfn)
-    rt = _route(keys, owner, s, cap)
+    rt = _owner_route(keys, s, owner_hfn, cap, spill_slack)
     c = rt.send.shape[1]
-    rk = lax.all_to_all(rt.send, axis, split_axis=0, concat_axis=0)
-    rm = lax.all_to_all(rt.smask, axis, split_axis=0, concat_axis=0)
+    rk = _exchange(rt.send, axis)
+    rm = _exchange(rt.smask, axis)
     found, vals = dhash.lookup(d, rk.reshape(-1))
     found = found & rm.reshape(-1)
-    rf = lax.all_to_all(found.reshape(s, c), axis, split_axis=0, concat_axis=0)
-    rv = lax.all_to_all(vals.reshape(s, c), axis, split_axis=0, concat_axis=0)
-    return _unroute(rf, rt, fill=False).astype(bool), _unroute(rv, rt, fill=0)
+    rf = _exchange(found.reshape(s, c), axis)
+    rv = _exchange(vals.reshape(s, c), axis)
+    return (_unroute(rf, rt, fill=False).astype(bool),
+            _unroute(rv, rt, fill=0), rt)
 
 
 def routed_update(d: dhash.DHashState, keys: jax.Array, vals: jax.Array,
                   mask: jax.Array, axis: str, owner_hfn: hashing.HashFn,
-                  op: Callable = dhash.insert, cap: int | None = None):
-    """DHash insert/delete across shards. Returns (d', ok). Call inside shard_map."""
+                  op: Callable = dhash.insert, cap: int | None = None,
+                  spill_slack: float | None = None):
+    """DHash insert or delete (``op``) across shards.  Returns
+    ``(d', ok, route)``.  Call inside shard_map.
+
+    The layout is ``routed_lookup``'s: with the default slab every key is
+    applied; under a compact slab a dropped key comes back ``ok=False``
+    and ``route.dropped`` counts it.  ``vals`` travel for an insert
+    only."""
     s = lax.axis_size(axis)
-    owner = shard_of(keys, s, owner_hfn)
-    rt = _route(keys, owner, s, cap)
+    rt = _owner_route(keys, s, owner_hfn, cap, spill_slack)
     c = rt.send.shape[1]
-    sendv = _route_payload(vals, rt)
-    sm2 = _route_payload(mask, rt)
-    rk = lax.all_to_all(rt.send, axis, split_axis=0, concat_axis=0)
-    rv = lax.all_to_all(sendv, axis, split_axis=0, concat_axis=0)
-    rm = lax.all_to_all(sm2, axis, split_axis=0, concat_axis=0)
+    rk = _exchange(rt.send, axis).reshape(-1)
+    rm = _exchange(_route_payload(mask, rt), axis).reshape(-1)
     if op is dhash.insert:
-        d, ok = op(d, rk.reshape(-1), rv.reshape(-1), rm.reshape(-1))
+        rv = _exchange(_route_payload(vals, rt), axis).reshape(-1)
+        d, ok = op(d, rk, rv, rm)
     else:
-        d, ok = op(d, rk.reshape(-1), rm.reshape(-1))
-    rok = lax.all_to_all(ok.reshape(s, c), axis, split_axis=0, concat_axis=0)
-    return d, _unroute(rok, rt, fill=False).astype(bool)
-
-
-def routed_rebuild_step(d: dhash.DHashState, axis: str) -> dhash.DHashState:
-    """One rebuild transition on every shard (SPMD-synchronized epochs)."""
-    return dhash.rebuild_step(d)
+        d, ok = op(d, rk, rm)
+    rok = _exchange(ok.reshape(s, c), axis)
+    return d, _unroute(rok, rt, fill=False).astype(bool), rt
 
 
 # -- mesh x stack: the [S shards x T tenants] grid ---------------------------
@@ -267,8 +313,7 @@ def _grid_exchange(buf: jax.Array, axis: str, s: int, t: int, cap: int):
     """all_to_all a [S*T, cap] owner-major buffer and return it tenant-major
     [T, S*cap] for the stack op (each row = one tenant's queries from every
     source shard)."""
-    rx = lax.all_to_all(buf.reshape(s, t, cap), axis,
-                        split_axis=0, concat_axis=0)      # [src S, T, cap]
+    rx = _exchange(buf.reshape(s, t, cap), axis)          # [src S, T, cap]
     return rx.transpose(1, 0, 2).reshape(t, s * cap)
 
 
@@ -276,8 +321,7 @@ def _grid_return(resp: jax.Array, axis: str, s: int, t: int, cap: int):
     """Inverse of ``_grid_exchange`` for a [T, S*cap] response: back to the
     querying shards, owner-major [S*T, cap]."""
     tx = resp.reshape(t, s, cap).transpose(1, 0, 2)       # [src S, T, cap]
-    return lax.all_to_all(tx, axis, split_axis=0,
-                          concat_axis=0).reshape(s * t, cap)
+    return _exchange(tx, axis).reshape(s * t, cap)
 
 
 def routed_stack_lookup(d: dhash.DHashState, keys: jax.Array,
@@ -393,25 +437,21 @@ def unpeel(d):
 
 def routed_service_step(d: dhash.DHashState, lookup_keys: jax.Array,
                         ins_keys: jax.Array, ins_vals: jax.Array,
-                        del_keys: jax.Array, axis: str,
-                        owner_hfn: hashing.HashFn, cap_factor: float = 0.0):
-    """The paper's steady-state workload as one fused distributed step:
-    a lookup batch + insert batch + delete batch + one rebuild transition.
-    This is what the dry-run lowers for the dhash_paper 'architecture'.
+                        del_keys: jax.Array, ins_mask: jax.Array,
+                        del_mask: jax.Array, axis: str,
+                        owner_hfn: hashing.HashFn):
+    """The served step of a table hash-partitioned over ``axis``: this
+    device's slice of a lookup, an insert and a delete batch, each routed
+    exactly to its owners (full width, see the module docstring), then one
+    rebuild transition on this shard.  It holds no epoch swap: the engine
+    swaps at its poll.  Call inside shard_map.
 
-    cap_factor > 0 bounds the routing buffers at cap = ceil(cap_factor*Q/S)
-    (§Perf lever: S x fewer wire bytes and S x smaller remote batches)."""
-    s = lax.axis_size(axis)
-    capof = (lambda q: route_cap(cap_factor, q, s)) if cap_factor > 0 \
-        else (lambda q: None)
-    found, vals = routed_lookup(d, lookup_keys, axis, owner_hfn,
-                                cap=capof(lookup_keys.shape[0]))
-    d, ok_i = routed_update(d, ins_keys, ins_vals,
-                            jnp.ones(ins_keys.shape, bool), axis, owner_hfn,
-                            op=dhash.insert, cap=capof(ins_keys.shape[0]))
-    d, ok_d = routed_update(d, del_keys, del_keys,
-                            jnp.ones(del_keys.shape, bool), axis, owner_hfn,
-                            op=dhash.delete, cap=capof(del_keys.shape[0]))
+    Returns ``(d', (found, vals, ok_i, ok_d), load)``: ``load`` [S] counts
+    the keys this device sent to each owner over the three batches."""
+    found, vals, rl = routed_lookup(d, lookup_keys, axis, owner_hfn)
+    d, ok_i, ri = routed_update(d, ins_keys, ins_vals, ins_mask, axis,
+                                owner_hfn, op=dhash.insert)
+    d, ok_d, rd = routed_update(d, del_keys, del_keys, del_mask, axis,
+                                owner_hfn, op=dhash.delete)
     d = dhash.rebuild_step(d)
-    stats = jnp.stack([found.sum(dtype=I32), ok_i.sum(dtype=I32), ok_d.sum(dtype=I32)])
-    return d, (found, vals, stats)
+    return d, (found, vals, ok_i, ok_d), rl.hist + ri.hist + rd.hist

@@ -55,6 +55,24 @@ They are ``jax.profiler.TraceAnnotation``s, so they land in a profiler
 trace on the device ops' clock and cost about a microsecond each
 when no profiler runs; nothing else records them.
 
+**A table hash-partitioned over devices** is the same engine on a stack
+from ``distributed.make_stacked(S, ..., sharding=NamedSharding(mesh,
+P(axis)))``: one table per device.  The engine reads the mesh from the
+state's sharding and takes the fixed ``owner`` hash.  Its step
+(``routed_step_fn``) is one ``shard_map`` program:
+``distributed.routed_service_step`` routes each device's contiguous slice
+of the lookup, insert and delete batches to their owners at full width
+and back, then runs one rebuild transition on every shard.  ``put``
+places each operand one slice per device, straight from the host.  The
+poll reads every shard's epoch, rebuild flag and ``rebuild_done`` (they
+run the same transitions, so they agree, or the poll raises) and the last
+step's per-owner loads, in its one ``device_get``; the swap is the same
+pytree exchange, and the next epoch opens on every shard in its own
+standby table (``routed_start_fn``).  ``stats.routed_lanes`` and
+``stats.routed_peak_load`` count the lanes a shard probes per step and
+the busiest owner's keys.  A single table compiles to the same step as
+ever.
+
 ``DHashStackEngine`` is the multi-table variant: it drives a
 ``dhash.make_stack`` state — T independent tables vmapped inside one jitted
 step, each with its OWN rebuild epoch (staggered live rehashes across
@@ -73,9 +91,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation as span
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import backend as backends
-from repro.core import dhash
+from repro.core import dhash, distributed, hashing
 from repro.core import policy as elastic
 from repro.core.struct_utils import replace
 
@@ -84,9 +104,24 @@ I32 = jnp.int32
 DEFAULT_POLL_EVERY = 32
 
 
-def _put(lookup_keys, ins_keys, ins_vals, del_keys, ins_mask, del_mask):
-    """A step's six operands on the device."""
+def _put(lookup_keys, ins_keys, ins_vals, del_keys, ins_mask, del_mask,
+         sharding: NamedSharding | None = None):
+    """A step's six operands on the device, or with ``sharding`` one
+    contiguous slice of each per device along its axis (straight from the
+    host: nothing goes to one device first)."""
     with span("dhash.engine.put"):
+        if sharding is not None:
+            def ones(x):
+                return np.ones(np.shape(x), bool)
+            return tuple(jax.device_put(np.asarray(x, t), sharding)
+                         for x, t in ((lookup_keys, np.int32),
+                                      (ins_keys, np.int32),
+                                      (ins_vals, np.int32),
+                                      (del_keys, np.int32),
+                                      (ones(ins_keys) if ins_mask is None
+                                       else ins_mask, bool),
+                                      (ones(del_keys) if del_mask is None
+                                       else del_mask, bool)))
         lk = jnp.asarray(lookup_keys, I32)
         ik = jnp.asarray(ins_keys, I32)
         iv = jnp.asarray(ins_vals, I32)
@@ -107,11 +142,12 @@ def _count_and_poll(eng, ops: int) -> None:
             eng._poll()
 
 
-def _lookup(fn, state, keys):
+def _lookup(fn, state, keys, sharding: NamedSharding | None = None):
     """A lookup batch through the jitted ``fn``, under the engine's spans."""
     with span("dhash.engine.lookup"):
         with span("dhash.engine.put"):
-            k = jnp.asarray(keys, I32)
+            k = (jnp.asarray(keys, I32) if sharding is None
+                 else jax.device_put(np.asarray(keys, np.int32), sharding))
         with span("dhash.engine.dispatch"):
             return fn(state, k)
 
@@ -124,6 +160,83 @@ class EngineStats:
     host_syncs: int = 0         # engine-internal device_get round-trips
     grows: int = 0              # policy-applied capacity increases
     shrinks: int = 0            # policy-applied capacity decreases
+    routed_lanes: int = 0       # sharded: lanes each shard probes per step
+                                # (static: S x the per-device batch widths)
+    routed_peak_load: int = 0   # sharded: keys the busiest owner served in
+                                # the last step before the last poll
+
+
+def _agreed(x) -> int:
+    """A scalar the device read, or the one value every shard of a stack
+    read; shards that disagree are out of step, which is an error."""
+    x = np.asarray(x)
+    if (x != x.flat[0]).any():
+        raise RuntimeError(f"the shards are out of step: {x.tolist()}")
+    return int(x.flat[0])
+
+
+def _sharding_of(state: dhash.DHashState) -> NamedSharding | None:
+    """None for one table; for a stack laid one table per device along one
+    mesh axis (``distributed.make_stacked(sharding=...)``), its sharding."""
+    if state.cursor.ndim == 0:
+        return None
+    sh, n = state.cursor.sharding, state.cursor.shape[0]
+    if isinstance(sh, NamedSharding):
+        axis = sh.spec[0] if len(sh.spec) == 1 else None
+        if sh.mesh.size == 1 == n and len(sh.mesh.axis_names) == 1:
+            axis = sh.mesh.axis_names[0]    # one device: no axis to name
+        if isinstance(axis, str) and sh.mesh.shape[axis] == n:
+            return NamedSharding(sh.mesh, P(axis))
+    raise ValueError("DHashEngine drives one table, or a stack sharded one "
+                     "table per device along one mesh axis; got a "
+                     f"[{n}] stack laid out as {sh}")
+
+
+def _smap(f, sharding: NamedSharding, n_in: int, out_specs,
+          replicated_in: int = 0):
+    """``f`` under ``shard_map`` over ``sharding``'s mesh: ``n_in``
+    arguments split along its axis, then ``replicated_in`` whole ones."""
+    spec = P(sharding.spec[0])
+    return jax.shard_map(f, mesh=sharding.mesh,
+                         in_specs=(spec,) * n_in + (P(),) * replicated_in,
+                         out_specs=out_specs, check_vma=False)
+
+
+def routed_step_fn(sharding: NamedSharding, owner: hashing.HashFn):
+    """The jitted engine step of a stack sharded along ``sharding``'s axis:
+    ``distributed.routed_service_step`` on every shard, the state donated.
+    Returns ``(state', (found, vals, ok_i, ok_d), load)``, ``load`` [S, S]
+    the keys each device sent to each owner."""
+    axis = sharding.spec[0]
+    spec = P(axis)
+
+    def body(st, lk, ik, iv, dk, im, dm):
+        d, out, load = distributed.routed_service_step(
+            distributed.peel(st), lk, ik, iv, dk, im, dm, axis, owner)
+        # the barrier keeps XLA from sinking the unpeel's reshape into the
+        # rebuild's extract conditional, where it copies a table array
+        # into each branch (tests/test_tpu_compile.py counts them)
+        d = jax.lax.optimization_barrier(d)
+        return distributed.unpeel(d), out, load[None]
+
+    return jax.jit(_smap(body, sharding, 7, (spec, (spec,) * 4, spec)),
+                   donate_argnums=(0,))
+
+
+def routed_start_fn(sharding: NamedSharding):
+    """The jitted rebuild start of a stack sharded along ``sharding``'s
+    axis: ``dhash.rebuild_reopen`` on every shard, on its own device, into
+    the shard's own standby table (donated: no table is allocated)."""
+    spec = P(sharding.spec[0])
+
+    def body(st, salt):
+        return distributed.unpeel(dhash.rebuild_reopen(distributed.peel(st),
+                                                       salt))
+
+    # keep_unused: the cleared standby's buffers are never read, and only
+    # as parameters can they take its zeros in place
+    return jax.jit(_smap(body, sharding, 1, spec, replicated_in=1),
+                   donate_argnums=(0,), keep_unused=True)
 
 
 @partial(jax.jit, static_argnames=("swap_on_device",), donate_argnums=(0, 1))
@@ -161,18 +274,25 @@ def _engine_step(d, lk, ik, iv, dk, imask, dmask):
 
 @dataclass
 class DHashEngine:
-    """Drives a DHashState: user op batches + background rebuild progress."""
+    """Drives a DHashState: user op batches + background rebuild progress.
+    The state is one table, or a stack sharded one table per device (the
+    module docstring), whose keys ``owner`` partitions."""
 
     state: dhash.DHashState
     continuous_rebuild: bool = False   # paper Fig 2: rebuild forever
     rebuild_seed: int = 1234
     poll_every: int = DEFAULT_POLL_EVERY   # host polls 1 of every K steps
     policy: elastic.ElasticPolicy | None = None   # elastic capacity decisions
+    owner: hashing.HashFn | None = None   # a sharded stack's owner hash
     _stats: EngineStats = field(default_factory=EngineStats, repr=False)
     _step_fn: Callable | None = field(default=None, init=False, repr=False)
     _poll_fn: Callable | None = field(default=None, init=False, repr=False)
     _lookup_fn: Callable | None = field(default=None, init=False, repr=False)
     _count_fn: Callable | None = field(default=None, init=False, repr=False)
+    _start_fn: Callable | None = field(default=None, init=False, repr=False)
+    _sharding: NamedSharding | None = field(default=None, init=False,
+                                            repr=False)
+    _load: jax.Array | None = field(default=None, init=False, repr=False)
     _epoch0: int = field(default=0, init=False, repr=False)
     _last_poll_step: int = field(default=-1, init=False, repr=False)
 
@@ -180,6 +300,10 @@ class DHashEngine:
         if self.policy is not None and self.continuous_rebuild:
             raise ValueError("policy and continuous_rebuild are exclusive: "
                              "the policy decides when to rebuild")
+        self._sharding = sh = _sharding_of(self.state)
+        if sh is not None and (self.policy is not None or self.owner is None):
+            raise ValueError("a sharded stack takes the owner hash that "
+                             "partitions its keys, and no policy")
         # take ownership: copy so donation never sees aliased or shared
         # buffers (e.g. a caller-held reference or zeros reused across leaves)
         self.state = jax.tree_util.tree_map(jnp.copy, self.state)
@@ -189,14 +313,28 @@ class DHashEngine:
                 lambda d, p: (d.epoch, d.rebuilding, dhash.rebuild_done(d),
                               p.want_grow, p.want_shrink, p.target_capacity))
         else:
+            done = (dhash.rebuild_done if sh is None
+                    else dhash.stack_rebuild_done)
             self._poll_fn = jax.jit(
-                lambda d: (d.epoch, d.rebuilding, dhash.rebuild_done(d)))
-        # donate the state: tables update in place, no per-step copy (a jit
-        # of this engine's own, so its cache counts this engine's retraces)
-        self._step_fn = jax.jit(partial(_engine_step), donate_argnums=(0,))
-        self._lookup_fn = jax.jit(dhash.lookup)
-        self._count_fn = jax.jit(dhash.count_items)
-        self._epoch0 = int(jax.device_get(self.state.epoch))
+                lambda d: (d.epoch, d.rebuilding, done(d)))
+        if sh is None:
+            # donate the state: tables update in place, no per-step copy (a
+            # jit of this engine's own, so its cache counts its retraces)
+            self._step_fn = jax.jit(partial(_engine_step),
+                                    donate_argnums=(0,))
+            self._lookup_fn = jax.jit(dhash.lookup)
+            self._count_fn = jax.jit(dhash.count_items)
+        else:
+            axis, owner = sh.spec[0], self.owner
+            self._step_fn = routed_step_fn(sh, owner)
+            self._start_fn = routed_start_fn(sh)
+            self._lookup_fn = jax.jit(_smap(
+                lambda st, k: distributed.routed_lookup(
+                    distributed.peel(st), k, axis, owner)[:2],
+                sh, 2, (P(axis), P(axis))))
+            self._count_fn = jax.jit(
+                lambda d: dhash.stack_count_items(d).sum())
+        self._epoch0 = _agreed(jax.device_get(self.state.epoch))
 
     # -- jitted step ---------------------------------------------------------
 
@@ -224,12 +362,18 @@ class DHashEngine:
                 with span("dhash.engine.poll"):
                     self._poll()
             lk, ik, iv, dk, im, dm = _put(lookup_keys, ins_keys, ins_vals,
-                                          del_keys, ins_mask, del_mask)
+                                          del_keys, ins_mask, del_mask,
+                                          self._sharding)
             with span("dhash.engine.dispatch"):
                 if self.policy is not None:
                     self.state, self.policy, out = _policy_engine_step(
                         self.state, self.policy, lk, ik, iv, dk, im, dm,
                         swap_on_device=self._swap_on_device())
+                elif self._sharding is not None:
+                    self.state, out, self._load = self._step_fn(
+                        self.state, lk, ik, iv, dk, im, dm)
+                    # every owner row is as wide as the whole batch
+                    self._stats.routed_lanes = lk.size + ik.size + dk.size
                 else:
                     self.state, out = self._step_fn(self.state, lk, ik, iv,
                                                     dk, im, dm)
@@ -242,14 +386,20 @@ class DHashEngine:
         """One batched device_get: refresh stats; swap the epochs of a
         completed rebuild (span ``dhash.engine.swap``); start the next
         rebuild in continuous mode; apply the policy's published resize
-        plan (policy engines)."""
+        plan (policy engines).  A sharded stack's shards run the same
+        transitions, so they must read the same epoch, rebuild flag and
+        ``rebuild_done``; the poll raises rather than swap shards out of
+        step."""
         if self.policy is not None:
             epoch, rebuilding, done, wg, ws, tgt = (
                 int(x) for x in
                 jax.device_get(self._poll_fn(self.state, self.policy)))
         else:
-            epoch, rebuilding, done = (
-                int(x) for x in jax.device_get(self._poll_fn(self.state)))
+            polled, load = jax.device_get((self._poll_fn(self.state),
+                                           self._load))
+            epoch, rebuilding, done = (_agreed(x) for x in polled)
+            if load is not None:
+                self._stats.routed_peak_load = int(load.sum(axis=0).max())
             wg = ws = 0
         self._stats.host_syncs += 1
         self._last_poll_step = self._stats.steps
@@ -321,7 +471,7 @@ class DHashEngine:
         rebuild (those happen only on ``step()``'s K-step poll), and
         steady-state ``step()`` calls themselves stay sync-free."""
         if self._stats.steps != self._last_poll_step:
-            epoch = int(jax.device_get(self.state.epoch))
+            epoch = _agreed(jax.device_get(self.state.epoch))
             self._stats.host_syncs += 1
             self._last_poll_step = self._stats.steps
             self._stats.rebuilds_completed = epoch - self._epoch0
@@ -331,7 +481,7 @@ class DHashEngine:
         """Begin a live rebuild (fails like the paper's trylock if one is
         already in progress)."""
         self._stats.host_syncs += 1
-        if bool(jax.device_get(self.state.rebuilding)):
+        if _agreed(jax.device_get(self.state.rebuilding)):
             return False  # -EBUSY
         if new_table is not None:
             new_table = jax.tree_util.tree_map(jnp.copy, new_table)  # own it
@@ -340,14 +490,22 @@ class DHashEngine:
 
     def _start_rebuild(self, *, seed: int | None = None, new_table=None):
         """``rebuild_start`` on a state the caller knows is not
-        rebuilding (no device sync)."""
-        self.state = dhash.rebuild_start(
-            self.state, new_table,
-            seed=self.rebuild_seed if seed is None else seed)
+        rebuilding (no device sync).  A sharded stack opens the epoch on
+        every shard into its own standby table (``routed_start_fn``)."""
+        seed = self.rebuild_seed if seed is None else seed
+        if self._sharding is None:
+            self.state = dhash.rebuild_start(self.state, new_table,
+                                             seed=seed)
+        elif new_table is not None:
+            raise ValueError("a sharded stack rebuilds into its standby "
+                             "tables, not into a table from the caller")
+        else:
+            self.state = self._start_fn(self.state,
+                                        np.int32(seed % (1 << 31)))
         self.rebuild_seed += 1
 
     def lookup(self, keys):
-        return _lookup(self._lookup_fn, self.state, keys)
+        return _lookup(self._lookup_fn, self.state, keys, self._sharding)
 
     def count(self) -> int:
         self._stats.host_syncs += 1

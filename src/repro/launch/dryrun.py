@@ -115,9 +115,10 @@ def lower_dhash_service(mesh, scfg=None):
              out_specs=(tspec, P("model")))
     def service(dstack, lk, ik, iv, dk):
         d = dd.peel(dstack)
-        d, (found, vals, stats) = dd.routed_service_step(
-            d, lk, ik, iv, dk, "model", owner,
-            cap_factor=scfg.route_cap_factor)
+        on = jnp.ones(ik.shape, bool)
+        d, (found, _, ok_i, ok_d), _ = dd.routed_service_step(
+            d, lk, ik, iv, dk, on, on, "model", owner)
+        stats = jnp.stack([x.sum(dtype=jnp.int32) for x in (found, ok_i, ok_d)])
         return dd.unpeel(d), stats[None]
 
     with mesh:

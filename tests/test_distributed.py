@@ -37,28 +37,43 @@ vals = keys * 3
          out_specs=(tspec, P("model")))
 def service(dstack, lk, ik, iv, dk):
     d = dd.peel(dstack)
-    d, (found, _, stats) = dd.routed_service_step(d, lk, ik, iv, dk, "model", owner)
-    return dd.unpeel(d), stats[None]
+    d, (found, _, ok_i, ok_d), load = dd.routed_service_step(
+        d, lk, ik, iv, dk, jnp.ones(ik.shape, bool), jnp.ones(dk.shape, bool),
+        "model", owner)
+    stats = jnp.stack([x.sum(dtype=jnp.int32) for x in (found, ok_i, ok_d)])
+    return dd.unpeel(d), jnp.concatenate([stats, load])[None]
 
 # step 1: insert everything (lookups miss), step 2: all lookups hit
 z = jnp.zeros((8,), jnp.int32)
 stacked, stats = jax.jit(service)(stacked, keys, keys, vals, z)
+stats = np.asarray(stats)
+assert int(stats[:, 1].sum()) == 512, stats       # every insert acked
+# each device's load row counts its 64 lookups + 64 inserts + 1 delete
+np.testing.assert_array_equal(stats[:, 3:].sum(axis=1), 64 + 64 + 1)
 stacked, stats = jax.jit(service)(stacked, keys, z, z, z)
 found_total = int(np.asarray(stats)[:, 0].sum())
 assert found_total == 512, found_total
 
-# capped routing agrees with uncapped under uniform keys
-@partial(jax.shard_map, mesh=mesh, check_vma=False,
-         in_specs=(tspec, P("model")), out_specs=(P("model"), P("model")))
-def lookup_capped(dstack, lk):
-    d = dd.peel(dstack)
-    f, v = dd.routed_lookup(d, lk, "model", owner, cap=lk.shape[0] // 2)
-    return f, v
+# a capped lookup rides the overflow-proof slab: every key is answered;
+# a compact slab drops keys, and the route it returns counts each one
+def lookup_capped(cap, slack):
+    @partial(jax.shard_map, mesh=mesh, check_vma=False,
+             in_specs=(tspec, P("model")),
+             out_specs=(P("model"), P("model"), P("model")))
+    def fn(dstack, lk):
+        f, v, rt = dd.routed_lookup(dd.peel(dstack), lk, "model", owner,
+                                    cap=cap, spill_slack=slack)
+        return f, v, rt.dropped[None]
+    return jax.jit(fn)
 
-f, v = jax.jit(lookup_capped)(stacked, keys)
+f, v, dropped = lookup_capped(32, None)(stacked, keys)
 f, v = np.asarray(f), np.asarray(v)
-assert f.sum() >= 500, f.sum()        # a few may exceed per-owner cap
-assert (v[f] == np.asarray(keys)[f] * 3).all()
+assert f.all() and int(np.asarray(dropped).sum()) == 0
+assert (v == np.asarray(keys) * 3).all()
+f, v, dropped = lookup_capped(4, 0.01)(stacked, keys)
+f, dropped = np.asarray(f), int(np.asarray(dropped).sum())
+assert dropped > 0 and f.sum() == 512 - dropped, (f.sum(), dropped)
+assert (np.asarray(v)[f] == np.asarray(keys)[f] * 3).all()
 
 # shard-local rebuild with synchronized epochs: all data survives
 for _ in range(64):

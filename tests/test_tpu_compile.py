@@ -19,11 +19,13 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
-from repro.core import backend, dhash
+from repro.core import backend, dhash, engine, hashing
 from repro.kernels import ops, probe
 
 C, Q, CH = 1 << 26, 1 << 16, 4096
@@ -32,15 +34,26 @@ I32, B = jnp.int32, jnp.bool_
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this environment
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """One table per chip of the described host, along the axis ``shard``."""
+    return NamedSharding(Mesh(np.asarray(topo.devices), ("shard",)),
+                         P("shard"))
 
 
 @pytest.fixture
@@ -123,25 +136,31 @@ def test_kernel_entry_compiles_for_v5e(one_chip, on_tpu, name):
 
 
 def _full_table_ops(compiled) -> dict:
-    """``{(computation, op): count}`` of the whole-table (``s32[C]``)
-    ``copy`` and ``select`` ops in a compiled program, fusions included."""
+    """``{(computation, op): count}`` of the whole-table (``s32[C]``, or
+    ``s32[1,C]``: one shard of a stack) ``copy`` and ``select`` ops in a
+    compiled program, fusions included."""
     counts, comp = {}, None
     for line in compiled.as_text().splitlines():
         head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
         if head and not line.startswith(" "):
             comp = head.group(1)
             continue
-        op = re.search(rf"= s32\[{C}\]\{{[^}}]*\}} (copy|select)\(", line)
+        op = re.search(rf"= s32\[(?:1,)?{C}\]\{{[^}}]*\}} (copy|select)\(",
+                       line)
         if op:
             counts[comp, op.group(1)] = counts.get((comp, op.group(1)), 0) + 1
     return counts
 
 
-def _deployment_state(sharding):
+def _deployment_state(sharding, shards=None):
     """The paper cell's table (2^26 slots, chunk 4096, the jnp op set) as
-    abstract operands on the described chip."""
-    st = jax.eval_shape(lambda: dhash.make("linear", capacity=C // 2,
-                                           chunk=CH, seed=1, fused=False))
+    abstract operands on the described chip, or a stack of ``shards`` of
+    them laid one per chip."""
+    st = jax.eval_shape(
+        lambda: dhash.make("linear", capacity=C // 2, chunk=CH, seed=1,
+                           fused=False) if shards is None else
+        dhash.make_stack(shards, "linear", capacity=C // 2, chunk=CH,
+                         seed=1, fused=False))
     return jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
         st)
@@ -168,6 +187,35 @@ def test_engine_programs_move_no_whole_table(one_chip, on_tpu, program):
         fn = eng._step_fn
         args = (st, s(Q), s(ins), s(ins), s(ins), s(ins, B), s(ins, B))
     assert _full_table_ops(fn.lower(*args).compile()) == {}
+
+
+@pytest.mark.parametrize("program", ["step", "continuous_start"])
+def test_routed_programs_move_no_whole_table(four_chips, on_tpu, program):
+    """The census for the table hash-partitioned over the described
+    v5e:2x2 (four chips, 2^26 slots per shard, chunk 4096): the sharded
+    engine's step (65536 lookups and 8192 inserts and deletes per chip,
+    each routed at full width) holds no whole-table ``select`` or
+    ``copy``; nor does the start that continuous rebuild runs at the poll
+    to open each epoch on every shard, which clears the standby table in
+    place (it copies and allocates nothing).  The step is one program
+    with or without continuous rebuild."""
+    s = four_chips.mesh.size
+    st = _deployment_state(four_chips, shards=s)
+    if program == "step":
+        def op(n, dt=I32):
+            return jax.ShapeDtypeStruct((s * n,), dt, sharding=four_chips)
+        ins = 8192
+        fn = engine.routed_step_fn(four_chips, hashing.fresh("mix32", 1))
+        args = (st, op(Q), op(ins), op(ins), op(ins), op(ins, B), op(ins, B))
+    else:
+        fn = engine.routed_start_fn(four_chips)
+        args = (st, jax.ShapeDtypeStruct((), I32))
+    compiled = fn.lower(*args).compile()
+    assert _full_table_ops(compiled) == {}
+    if program == "continuous_start":
+        # every table array's output is its donated input: none is new
+        mem = compiled.memory_analysis()
+        assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 4 * C
 
 
 @pytest.mark.parametrize("name", ["linear", "twochoice", "cuckoo", "chain"])

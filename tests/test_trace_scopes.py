@@ -4,8 +4,12 @@ every DHash operation carry its ``jax.named_scope`` in their HLO
 transfers, dispatch and poll as children."""
 from __future__ import annotations
 
+import json
+import os
 import pathlib
 import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +71,47 @@ def test_engine_step_hlo_names_every_operation(kind, kw, want, tmp_path):
     assert len(polls) == 2 and len(swaps) == 1
     assert _inside(swaps[0], polls[1:]) == 1
     assert eng.stats.rebuilds_completed == 1
+
+
+ROUTED_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, re
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.core import distributed as dd, hashing
+from repro.core.engine import routed_step_fn
+
+sh = NamedSharding(Mesh(np.array(jax.devices()[:4]), ("shard",)), P("shard"))
+st = dd.make_stacked(4, "linear", capacity=64, chunk=32, seed=3, sharding=sh)
+k = jnp.arange(256, dtype=jnp.int32)
+m = jnp.ones(256, bool)
+txt = routed_step_fn(sh, hashing.fresh("mix32", 1)).lower(
+    st, k, k, k, k, m, m).compile().as_text()
+a2a = [l for l in txt.splitlines() if re.search(r"\ball-to-all\(", l)]
+print("NAMES " + json.dumps(sorted(set(re.findall(r'op_name="([^"]*)"', txt)))))
+print("A2A " + json.dumps([re.search(r'op_name="([^"]*)"', l).group(1)
+                           for l in a2a]))
+"""
+
+
+def test_routed_step_hlo_names_route_and_exchange():
+    """The sharded engine's step (four virtual devices, in a subprocess)
+    names the router's layout work ``dhash.route`` and its all-to-alls
+    ``dhash.exchange``, beside every operation of the plain step."""
+    root = pathlib.Path(__file__).resolve().parent.parent
+    r = subprocess.run([sys.executable, "-c", ROUTED_SCRIPT],
+                       capture_output=True, text=True, cwd=root,
+                       env=dict(os.environ, PYTHONPATH="src"))
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = dict(line.split(" ", 1) for line in r.stdout.splitlines()
+               if line.startswith(("NAMES ", "A2A ")))
+    routed = {"dhash.route", "dhash.exchange"}
+    got = {s for s in SCOPES | routed for n in json.loads(out["NAMES"])
+           if re.search(r"(^|[/(])" + re.escape(s) + r"($|[/)])", n)}
+    assert got == STEP_SCOPES | routed
+    a2a = json.loads(out["A2A"])
+    assert len(a2a) == 11 and all("dhash.exchange" in n for n in a2a)
 
 
 def test_lookup_hlo_names_lookup_and_hazard():
