@@ -480,6 +480,7 @@ def four_chips(jax, seed: int) -> None:
                     "checked_steps": checked,
                     "steps_per_s": step / (time.perf_counter() - t0),
                     "step_is": STEP_IS, "live_keys": live,
+                    "routed_lookup_rounds": eng.stats.routed_lookup_rounds,
                     "routed_lanes": eng.stats.routed_lanes,
                     "routed_peak_load": eng.stats.routed_peak_load,
                     "peak_bytes_per_device": peak_bytes(devs)}

@@ -42,13 +42,20 @@ along their batch dim.  Every shard-local table op dispatches through the
 backend — fused or jnp — shards without changes here.
 
 **The served path is exact.**  ``routed_service_step`` (the step
-``DHashEngine`` runs on a sharded stack) routes every batch at the full
-width Q per owner row, so every key of any key set reaches its owner.  The
-batch is split into contiguous per-device slices and the exchange keeps
-source order (row j of the received buffer came from device j, each row
-in batch order), so the flattened buffer an owner serves is its keys in
-global batch order: the earlier of two copies of a key wins, as on one
-chip.  The price is padding: an owner probes S·Q lanes for about Q keys.
+``DHashEngine`` runs on a sharded stack) routes every key of any key set
+to its owner.  Its inserts and deletes go at the full width Q per owner
+row.  The batch is split into contiguous per-device slices and the
+exchange keeps source order (row j of the received buffer came from
+device j, each row in batch order), so the flattened buffer an owner
+serves is its keys in global batch order: the earlier of two copies of a
+key wins, as on one chip.  The price is padding: an owner probes S·Q
+insert or delete lanes for about Q keys.  The lookup writes nothing and
+its answers do not depend on the order of its probes, so it goes in
+**rounds** of ``lookup_cap(Q, S)`` columns per owner row (a share plus a
+binomial margin): round r carries the keys ranked ``[r·cap, (r+1)·cap)``
+within their owner, and every device runs the round count that the
+busiest (sender, owner) pair needs.  Balanced owners take one round of
+S·cap lanes; a batch all owned by one shard takes ``ceil(Q / cap)``.
 A capped routed op rides the spill slab (overflow-proof by default, the
 same width Q); only a compact slab can drop keys, and the op returns its
 ``Route`` so the caller sees ``dropped``.
@@ -104,6 +111,15 @@ def route_cap(cap_factor: float, q: int, nshards: int) -> int:
     if cap_factor <= 0:
         return q
     return min(q, max(1, math.ceil(cap_factor * q / nshards)))
+
+
+def lookup_cap(q: int, nshards: int) -> int:
+    """Columns per owner row of a lookup round: a sender's share ``Q/S``
+    plus eight standard deviations of a uniform owner hash's count
+    (``8·sqrt(Q/S)``, a binomial margin), clamped to [1, Q].  Balanced
+    batches fit one round; the rounds serve any overflow."""
+    share = q / nshards
+    return min(q, max(1, math.ceil(share + 8 * math.sqrt(share))))
 
 
 def route_spill_cap(q: int, cap: int, slack: float | None = None) -> int:
@@ -245,16 +261,22 @@ def _exchange(x: jax.Array, axis: str) -> jax.Array:
 def routed_lookup(d: dhash.DHashState, keys: jax.Array, axis: str,
                   owner_hfn: hashing.HashFn, cap: int | None = None,
                   spill_slack: float | None = None):
-    """DHash lookup across shards.  Returns ``(found, vals, route)``.  Call
-    inside shard_map.
+    """DHash lookup across shards.  Returns ``(found, vals, route,
+    rounds)``.  Call inside shard_map.
 
-    Without ``cap`` every owner row is the batch's full width.  With it,
-    keys past their owner's cap ride the spill slab: the default slab is
-    overflow-proof, so every key is answered.  Only a compact slab
-    (``spill_slack`` in (0, 1)) drops keys; they come back not found and
-    ``route.dropped`` counts them per owner."""
+    Without ``cap`` the lookup runs in rounds of ``lookup_cap(Q, S)``
+    columns per owner row until every device has served every key
+    (``rounds``, the same on every device); ``route`` is the batch's
+    full-width route, whose owner ranks place each key in its round.
+    With ``cap``, one exchange: keys past their owner's cap ride the
+    spill slab, and the default slab is overflow-proof, so every key is
+    answered.  Only a compact slab (``spill_slack`` in (0, 1)) drops keys;
+    they come back not found and ``route.dropped`` counts them per
+    owner."""
     s = lax.axis_size(axis)
     rt = _owner_route(keys, s, owner_hfn, cap, spill_slack)
+    if cap is None:
+        return _lookup_rounds(d, keys, rt, axis)
     c = rt.send.shape[1]
     rk = _exchange(rt.send, axis)
     rm = _exchange(rt.smask, axis)
@@ -263,7 +285,44 @@ def routed_lookup(d: dhash.DHashState, keys: jax.Array, axis: str,
     rf = _exchange(found.reshape(s, c), axis)
     rv = _exchange(vals.reshape(s, c), axis)
     return (_unroute(rf, rt, fill=False).astype(bool),
-            _unroute(rv, rt, fill=0), rt)
+            _unroute(rv, rt, fill=0), rt, jnp.int32(1))
+
+
+def _lookup_rounds(d: dhash.DHashState, keys: jax.Array, rt: Route,
+                   axis: str):
+    """``routed_lookup`` without a cap: round r sends each owner the keys
+    ranked ``[r·cap, (r+1)·cap)`` within it, at column ``rank - r·cap`` of
+    an [S, cap] buffer, and gathers their answers into the [Q] outputs.
+    The round count is agreed over ``axis`` (one ``pmax``), so every
+    device runs the same collectives; a lookup writes nothing, so the
+    answers are the full-width layout's."""
+    s, q = rt.hist.shape[0], keys.shape[0]
+    c = lookup_cap(q, s)
+    with jax.named_scope("dhash.route"):
+        rounds = lax.pmax((rt.hist.max() + c - 1) // c, axis)
+
+    def one_round(r, out):
+        with jax.named_scope("dhash.route"):
+            col = rt.rank - r * c
+            here = (col >= 0) & (col < c)
+            at = jnp.where(here, col, c)       # out of bounds: dropped
+            send = jnp.zeros((s, c), keys.dtype).at[rt.owner, at].set(
+                keys, mode="drop")
+            smask = jnp.zeros((s, c), bool).at[rt.owner, at].set(
+                True, mode="drop")
+        rk = _exchange(send, axis)
+        rm = _exchange(smask, axis)
+        found, vals = dhash.lookup(d, rk.reshape(-1))
+        rf = _exchange((found & rm.reshape(-1)).reshape(s, c), axis)
+        rv = _exchange(vals.reshape(s, c), axis)
+        with jax.named_scope("dhash.route"):
+            at = jnp.where(here, col, 0)
+            return (jnp.where(here, rf[rt.owner, at], out[0]),
+                    jnp.where(here, rv[rt.owner, at], out[1]))
+
+    found, vals = lax.fori_loop(0, rounds, one_round,
+                                (jnp.zeros((q,), bool), jnp.zeros((q,), I32)))
+    return found, vals, rt, rounds
 
 
 def routed_update(d: dhash.DHashState, keys: jax.Array, vals: jax.Array,
@@ -442,16 +501,22 @@ def routed_service_step(d: dhash.DHashState, lookup_keys: jax.Array,
                         owner_hfn: hashing.HashFn):
     """The served step of a table hash-partitioned over ``axis``: this
     device's slice of a lookup, an insert and a delete batch, each routed
-    exactly to its owners (full width, see the module docstring), then one
-    rebuild transition on this shard.  It holds no epoch swap: the engine
-    swaps at its poll.  Call inside shard_map.
+    exactly to its owners (the lookup in rounds, the insert and the delete
+    at full width: see the module docstring), then one rebuild transition
+    on this shard.  It holds no epoch swap: the engine swaps at its poll.
+    Call inside shard_map.
 
-    Returns ``(d', (found, vals, ok_i, ok_d), load)``: ``load`` [S] counts
-    the keys this device sent to each owner over the three batches."""
-    found, vals, rl = routed_lookup(d, lookup_keys, axis, owner_hfn)
+    Returns ``(d', (found, vals, ok_i, ok_d), load, rounds)``: ``load``
+    [S] counts the keys this device sent to each owner over the three
+    batches, ``rounds`` the lookup's rounds (``routed_lookup``)."""
+    found, vals, rl, rounds = routed_lookup(d, lookup_keys, axis, owner_hfn)
+    # the insert writes the tables in place only after the lookup's loop
+    # has read them: without the order the barrier gives, XLA copies each
+    # table for the loop (tests/test_tpu_compile.py counts them)
+    d, found, vals = lax.optimization_barrier((d, found, vals))
     d, ok_i, ri = routed_update(d, ins_keys, ins_vals, ins_mask, axis,
                                 owner_hfn, op=dhash.insert)
     d, ok_d, rd = routed_update(d, del_keys, del_keys, del_mask, axis,
                                 owner_hfn, op=dhash.delete)
     d = dhash.rebuild_step(d)
-    return d, (found, vals, ok_i, ok_d), rl.hist + ri.hist + rd.hist
+    return d, (found, vals, ok_i, ok_d), rl.hist + ri.hist + rd.hist, rounds

@@ -61,17 +61,18 @@ P(axis)))``: one table per device.  The engine reads the mesh from the
 state's sharding and takes the fixed ``owner`` hash.  Its step
 (``routed_step_fn``) is one ``shard_map`` program:
 ``distributed.routed_service_step`` routes each device's contiguous slice
-of the lookup, insert and delete batches to their owners at full width
-and back, then runs one rebuild transition on every shard.  ``put``
-places each operand one slice per device, straight from the host.  The
-poll reads every shard's epoch, rebuild flag and ``rebuild_done`` (they
-run the same transitions, so they agree, or the poll raises) and the last
-step's per-owner loads, in its one ``device_get``; the swap is the same
-pytree exchange, and the next epoch opens on every shard in its own
-standby table (``routed_start_fn``).  ``stats.routed_lanes`` and
-``stats.routed_peak_load`` count the lanes a shard probes per step and
-the busiest owner's keys.  A single table compiles to the same step as
-ever.
+of the lookup, insert and delete batches to their owners and back (the
+lookup in capped rounds, the insert and the delete at full width), then
+runs one rebuild transition on every shard.  ``put`` places each operand
+one slice per device, straight from the host.  The poll reads every
+shard's epoch, rebuild flag and ``rebuild_done`` (they run the same
+transitions, so they agree, or the poll raises) and the last step's
+per-owner loads and lookup rounds, in its one ``device_get``; the swap is
+the same pytree exchange, and the next epoch opens on every shard in its
+own standby table (``routed_start_fn``).  ``stats.routed_lookup_rounds``,
+``stats.routed_lanes`` and ``stats.routed_peak_load`` count the lookup's
+rounds, the lanes a shard probed and the busiest owner's keys in the last
+step before the poll.  A single table compiles to the same step as ever.
 
 ``DHashStackEngine`` is the multi-table variant: it drives a
 ``dhash.make_stack`` state — T independent tables vmapped inside one jitted
@@ -160,10 +161,10 @@ class EngineStats:
     host_syncs: int = 0         # engine-internal device_get round-trips
     grows: int = 0              # policy-applied capacity increases
     shrinks: int = 0            # policy-applied capacity decreases
-    routed_lanes: int = 0       # sharded: lanes each shard probes per step
-                                # (static: S x the per-device batch widths)
-    routed_peak_load: int = 0   # sharded: keys the busiest owner served in
-                                # the last step before the last poll
+    # a sharded stack, in the last step before the last poll:
+    routed_lookup_rounds: int = 0   # the lookup's rounds
+    routed_lanes: int = 0           # lanes each shard probed
+    routed_peak_load: int = 0       # keys the busiest owner served
 
 
 def _agreed(x) -> int:
@@ -205,21 +206,23 @@ def _smap(f, sharding: NamedSharding, n_in: int, out_specs,
 def routed_step_fn(sharding: NamedSharding, owner: hashing.HashFn):
     """The jitted engine step of a stack sharded along ``sharding``'s axis:
     ``distributed.routed_service_step`` on every shard, the state donated.
-    Returns ``(state', (found, vals, ok_i, ok_d), load)``, ``load`` [S, S]
-    the keys each device sent to each owner."""
+    Returns ``(state', (found, vals, ok_i, ok_d), (load, rounds))``,
+    ``load`` [S, S] the keys each device sent to each owner, ``rounds``
+    [S] the lookup's rounds (every device ran the same count)."""
     axis = sharding.spec[0]
     spec = P(axis)
 
     def body(st, lk, ik, iv, dk, im, dm):
-        d, out, load = distributed.routed_service_step(
+        d, out, load, rounds = distributed.routed_service_step(
             distributed.peel(st), lk, ik, iv, dk, im, dm, axis, owner)
         # the barrier keeps XLA from sinking the unpeel's reshape into the
         # rebuild's extract conditional, where it copies a table array
         # into each branch (tests/test_tpu_compile.py counts them)
         d = jax.lax.optimization_barrier(d)
-        return distributed.unpeel(d), out, load[None]
+        return distributed.unpeel(d), out, (load[None], rounds[None])
 
-    return jax.jit(_smap(body, sharding, 7, (spec, (spec,) * 4, spec)),
+    return jax.jit(_smap(body, sharding, 7,
+                         (spec, (spec,) * 4, (spec, spec))),
                    donate_argnums=(0,))
 
 
@@ -292,7 +295,8 @@ class DHashEngine:
     _start_fn: Callable | None = field(default=None, init=False, repr=False)
     _sharding: NamedSharding | None = field(default=None, init=False,
                                             repr=False)
-    _load: jax.Array | None = field(default=None, init=False, repr=False)
+    _routed: tuple | None = field(default=None, init=False, repr=False)
+    _lanes: tuple = field(default=(0, 0), init=False, repr=False)
     _epoch0: int = field(default=0, init=False, repr=False)
     _last_poll_step: int = field(default=-1, init=False, repr=False)
 
@@ -370,10 +374,14 @@ class DHashEngine:
                         self.state, self.policy, lk, ik, iv, dk, im, dm,
                         swap_on_device=self._swap_on_device())
                 elif self._sharding is not None:
-                    self.state, out, self._load = self._step_fn(
+                    self.state, out, self._routed = self._step_fn(
                         self.state, lk, ik, iv, dk, im, dm)
-                    # every owner row is as wide as the whole batch
-                    self._stats.routed_lanes = lk.size + ik.size + dk.size
+                    # a shard's lanes: S x the lookup cap in each round,
+                    # and inserts and deletes as wide as the whole batch
+                    s = self.state.cursor.shape[0]
+                    self._lanes = (s * distributed.lookup_cap(lk.size // s,
+                                                              s),
+                                   ik.size + dk.size)
                 else:
                     self.state, out = self._step_fn(self.state, lk, ik, iv,
                                                     dk, im, dm)
@@ -395,11 +403,16 @@ class DHashEngine:
                 int(x) for x in
                 jax.device_get(self._poll_fn(self.state, self.policy)))
         else:
-            polled, load = jax.device_get((self._poll_fn(self.state),
-                                           self._load))
+            polled, routed = jax.device_get((self._poll_fn(self.state),
+                                             self._routed))
             epoch, rebuilding, done = (_agreed(x) for x in polled)
-            if load is not None:
-                self._stats.routed_peak_load = int(load.sum(axis=0).max())
+            if routed is not None:
+                load, rounds = routed
+                per_round, updates = self._lanes
+                st = self._stats
+                st.routed_peak_load = int(load.sum(axis=0).max())
+                st.routed_lookup_rounds = _agreed(rounds)
+                st.routed_lanes = per_round * st.routed_lookup_rounds + updates
             wg = ws = 0
         self._stats.host_syncs += 1
         self._last_poll_step = self._stats.steps

@@ -116,7 +116,7 @@ def lower_dhash_service(mesh, scfg=None):
     def service(dstack, lk, ik, iv, dk):
         d = dd.peel(dstack)
         on = jnp.ones(ik.shape, bool)
-        d, (found, _, ok_i, ok_d), _ = dd.routed_service_step(
+        d, (found, _, ok_i, ok_d), _, _ = dd.routed_service_step(
             d, lk, ik, iv, dk, on, on, "model", owner)
         stats = jnp.stack([x.sum(dtype=jnp.int32) for x in (found, ok_i, ok_d)])
         return dd.unpeel(d), stats[None]

@@ -1,10 +1,12 @@
-"""Distributed DHash: routed ops on an 8-device host mesh (subprocess, so
-the 8-device XLA flag never leaks into other tests)."""
+"""Distributed DHash: routed ops on an 8-device and a 4-device host mesh
+(subprocesses, so the device-count flag never leaks into other tests)."""
 from __future__ import annotations
 
 import os
 import subprocess
 import sys
+
+import pytest
 
 SCRIPT = r"""
 import os
@@ -37,7 +39,7 @@ vals = keys * 3
          out_specs=(tspec, P("model")))
 def service(dstack, lk, ik, iv, dk):
     d = dd.peel(dstack)
-    d, (found, _, ok_i, ok_d), load = dd.routed_service_step(
+    d, (found, _, ok_i, ok_d), load, _ = dd.routed_service_step(
         d, lk, ik, iv, dk, jnp.ones(ik.shape, bool), jnp.ones(dk.shape, bool),
         "model", owner)
     stats = jnp.stack([x.sum(dtype=jnp.int32) for x in (found, ok_i, ok_d)])
@@ -61,8 +63,8 @@ def lookup_capped(cap, slack):
              in_specs=(tspec, P("model")),
              out_specs=(P("model"), P("model"), P("model")))
     def fn(dstack, lk):
-        f, v, rt = dd.routed_lookup(dd.peel(dstack), lk, "model", owner,
-                                    cap=cap, spill_slack=slack)
+        f, v, rt, _ = dd.routed_lookup(dd.peel(dstack), lk, "model", owner,
+                                       cap=cap, spill_slack=slack)
         return f, v, rt.dropped[None]
     return jax.jit(fn)
 
@@ -327,3 +329,136 @@ def test_routed_stack_grid_8dev():
     assert "GRID-CAP-OK" in r.stdout
     assert "GRID-DROP-OK" in r.stdout
     assert "GRID-JAXPR-OK" in r.stdout
+
+
+# -- the routed lookup's rounds: exact under any owner load -----------------
+SCRIPT_ROUNDS = r"""
+import os, json
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, jax.numpy as jnp, numpy as np
+from functools import partial
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.core import distributed as dd, hashing
+
+S, Q, N, B = 4, 512, 3072, 256      # per device: lookups; live keys; fill
+CAP = dd.lookup_cap(Q, S)
+assert CAP < Q // 2, CAP
+owner = hashing.fresh("mix32", 11)
+sh = NamedSharding(Mesh(np.array(jax.devices()[:S]), ("shard",)), P("shard"))
+smap = partial(jax.shard_map, mesh=sh.mesh, check_vma=False)
+
+
+@jax.jit
+@partial(smap, in_specs=(P("shard"),) * 3,
+         out_specs=(P("shard"), P("shard")))
+def insert(st, k, v):
+    d, ok, _ = dd.routed_update(dd.peel(st), k, v, jnp.ones(k.shape, bool),
+                                "shard", owner)
+    return dd.unpeel(d), ok
+
+
+@jax.jit
+@partial(smap, in_specs=(P("shard"), P("shard")),
+         out_specs=(P("shard"),) * 5)
+def lookup(st, k):
+    d = dd.peel(st)
+    f, v, _, rounds = dd.routed_lookup(d, k, "shard", owner)
+    wf, wv, _, _ = dd.routed_lookup(d, k, "shard", owner, cap=Q)  # full width
+    return f, v, wf, wv, rounds[None]
+
+
+rng = np.random.default_rng(5)
+universe = np.arange(1, 2 * N + 1, dtype=np.int32)
+own = np.asarray(dd.shard_of(jnp.asarray(universe), S, owner))
+live = rng.permutation(universe)[:N]
+oracle = dict(zip(live.tolist(), (live * 7 + 1).tolist()))
+st = dd.make_stacked(S, "linear", capacity=2 * N, chunk=64, seed=3,
+                     sharding=sh)
+for i in range(0, N, S * B):
+    k = live[i:i + S * B]
+    st, ok = insert(st, k, (k * 7 + 1).astype(np.int32))
+    assert np.asarray(ok).all()
+
+
+def owned(o, n):
+    return rng.choice(universe[own == o], n)
+
+
+def others(o, n):
+    # n keys spread round robin over the owners other than o
+    rest = [x for x in range(S) if x != o]
+    return np.concatenate([owned(x, len(range(j, n, len(rest))))
+                           for j, x in enumerate(rest)])
+
+
+def balanced():
+    return rng.choice(universe, Q)
+
+
+def with_one_owner_at(n):
+    # device 1 sends n keys to owner 2, every other pair stays below CAP
+    slice1 = rng.permutation(np.concatenate([owned(2, n), others(2, Q - n)]))
+    return [balanced(), slice1, balanced(), balanced()]
+
+
+CASES = {
+    "balanced": (lambda: [balanced() for _ in range(S)], 1),
+    "one_owner_at_cap": (lambda: with_one_owner_at(CAP), 1),
+    "one_owner_past_cap": (lambda: with_one_owner_at(CAP + 1), 2),
+    "half_to_one_owner": (lambda: [rng.permutation(np.concatenate(
+        [owned(0, Q // 2), others(0, Q // 2)])) for _ in range(S)],
+        -(-(Q // 2) // CAP)),
+    "all_to_one_owner": (lambda: [owned(3, Q) for _ in range(S)],
+                         -(-Q // CAP)),
+    "repeated_across_slices": (lambda: [rng.permutation(base)
+                                        for base in [balanced()] * S], 1),
+}
+for name, (make, want_rounds) in CASES.items():
+    keys = np.concatenate(make()).astype(np.int32)
+    counts = np.stack([np.bincount(own[keys[i * Q:(i + 1) * Q] - 1],
+                                   minlength=S) for i in range(S)])
+    f, v, wf, wv, rounds = (np.asarray(x) for x in lookup(st, keys))
+    want_f = np.array([k in oracle for k in keys.tolist()])
+    want_v = np.array([oracle.get(k, 0) for k in keys.tolist()])
+    print("ROUNDS " + json.dumps({
+        "case": name, "rounds": rounds.tolist(), "want_rounds": want_rounds,
+        "busiest_pair": int(counts.max()), "cap": CAP,
+        "found_vs_full_width": int((f != wf).sum()),
+        "vals_vs_full_width": int((v != wv).sum()),
+        "found_vs_oracle": int((f != want_f).sum()),
+        "vals_vs_oracle": int((v != want_v)[want_f].sum()),
+        "found": int(f.sum())}))
+"""
+
+ROUND_CASES = ["balanced", "one_owner_at_cap", "one_owner_past_cap",
+               "half_to_one_owner", "all_to_one_owner",
+               "repeated_across_slices"]
+
+
+@pytest.fixture(scope="module")
+def rounds_results():
+    """Every case of ``SCRIPT_ROUNDS`` in one four-device subprocess."""
+    import json
+    env = dict(os.environ, PYTHONPATH="src")
+    r = subprocess.run([sys.executable, "-c", SCRIPT_ROUNDS],
+                       capture_output=True, text=True, env=env,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = [json.loads(x[len("ROUNDS "):]) for x in r.stdout.splitlines()
+           if x.startswith("ROUNDS ")]
+    return {x["case"]: x for x in out}
+
+
+@pytest.mark.parametrize("case", ROUND_CASES)
+def test_routed_lookup_rounds_are_exact(rounds_results, case):
+    """The lookup without a cap, in rounds of ``lookup_cap(Q, S)`` columns,
+    answers every key as a dict oracle does and as the full-width layout
+    does, bit for bit, and takes ``ceil(busiest pair / cap)`` rounds on
+    every device: one while a pair holds at most the cap."""
+    r = rounds_results[case]
+    assert r["rounds"] == [r["want_rounds"]] * 4, r
+    assert r["want_rounds"] == -(-r["busiest_pair"] // r["cap"]), r
+    for k in ("found_vs_full_width", "vals_vs_full_width", "found_vs_oracle",
+              "vals_vs_oracle"):
+        assert r[k] == 0, (k, r)
+    assert 0 < r["found"] < 4 * 512, r

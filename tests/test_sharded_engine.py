@@ -12,7 +12,9 @@ case checks the engine's answers batch by batch:
 * ``cross_chip_duplicates``: a key twice in one insert (and one delete)
   batch, the copies in the slices of different devices: the earlier wins;
 * ``full_skew``: a batch whose keys all belong to one shard is served
-  whole, and the poll reads that owner's load;
+  whole, its lookup in ``ceil(Q / cap)`` rounds, and the poll reads that
+  owner's load, the rounds and the lanes probed;
+* balanced batches: the poll reads one lookup round;
 * the live count against the oracle's size, in every case.
 """
 from __future__ import annotations
@@ -34,6 +36,7 @@ from repro.core.engine import DHashEngine
 CASE = sys.argv[1]
 S, KEYS, CHUNK, POLL = 4, 1024, 64, 4
 Q, U = 64, 16                      # per device: lookups, inserts / deletes
+CAP = dd.lookup_cap(Q, S)          # a lookup round's columns per owner row
 rng = np.random.default_rng(7)
 owner = hashing.fresh("mix32", 11)
 sharding = NamedSharding(Mesh(np.array(jax.devices()[:S]), ("shard",)),
@@ -97,10 +100,19 @@ def check_count(tag):
     assert eng.count() == len(oracle.d), (tag, eng.count(), len(oracle.d))
 
 
+def check_balanced(tag):
+    # the step before the poll: one lookup round of S x CAP lanes per
+    # shard, inserts and deletes at full width
+    st = eng.stats
+    assert st.routed_lookup_rounds == 1, (tag, st)
+    assert st.routed_lanes == S * CAP + 2 * S * U, (tag, st)
+
+
 # a first fill, with its answers checked like every later batch's
 for i in range(8):
     run(batch(), f"fill {i}")
 check_count("fill")
+check_balanced("fill")
 
 if CASE == "swaps":
     step = 8
@@ -113,6 +125,7 @@ if CASE == "swaps":
     assert int(np.asarray(jax.device_get(eng.state.epoch))[0]) >= 2
     assert eng.state.cursor.sharding.spec == P("shard")
     check_count("swaps")
+    check_balanced("swaps")
 
 elif CASE == "cross_chip_duplicates":
     prev = []
@@ -138,9 +151,13 @@ elif CASE == "full_skew":
         dels = rng.choice(owned_by(universe, s), S * U, replace=False)
         assert ins.size == S * U
         run(batch(look, ins, dels), f"skew {s}")
-        # the step before the poll sent every key to owner s
+        # the step before the poll sent every key to owner s: the lookup
+        # took ceil(Q / CAP) rounds of S x CAP lanes
+        rounds = -(-Q // CAP)
+        assert rounds > 1, (Q, CAP)
         assert eng.stats.routed_peak_load == S * (Q + 2 * U), eng.stats
-        assert eng.stats.routed_lanes == S * (Q + 2 * U), eng.stats
+        assert eng.stats.routed_lookup_rounds == rounds, eng.stats
+        assert eng.stats.routed_lanes == S * (CAP * rounds + 2 * U), eng.stats
         check_count(f"skew {s}")
     run(batch(), "after skew")
     check_count("after skew")

@@ -193,9 +193,10 @@ def test_engine_programs_move_no_whole_table(one_chip, on_tpu, program):
 def test_routed_programs_move_no_whole_table(four_chips, on_tpu, program):
     """The census for the table hash-partitioned over the described
     v5e:2x2 (four chips, 2^26 slots per shard, chunk 4096): the sharded
-    engine's step (65536 lookups and 8192 inserts and deletes per chip,
-    each routed at full width) holds no whole-table ``select`` or
-    ``copy``; nor does the start that continuous rebuild runs at the poll
+    engine's step (65536 lookups per chip, routed in capped rounds inside
+    a loop that reads the shard's tables, and 8192 inserts and deletes
+    per chip, each routed at full width) holds no whole-table ``select``
+    or ``copy``; nor does the start that continuous rebuild runs at the poll
     to open each epoch on every shard, which clears the standby table in
     place (it copies and allocates nothing).  The step is one program
     with or without continuous rebuild."""
